@@ -126,12 +126,12 @@ class JaxprReport:
 
 def _is_var(v) -> bool:
     """True for real jaxpr Vars (Literals are unhashable constants)."""
-    import jax.core as jc
+    import jax.extend.core as jc
     return isinstance(v, jc.Var)
 
 
 def _sub_jaxprs(eqn):
-    import jax.core as jc
+    import jax.extend.core as jc
     for v in eqn.params.values():
         if isinstance(v, jc.ClosedJaxpr):
             yield v.jaxpr
@@ -156,13 +156,15 @@ def iter_eqns(jaxpr):
 
 def _aval_bytes(aval) -> int:
     shape = getattr(aval, "shape", None)
-    dtype = getattr(aval, "dtype", None)
-    if shape is None or dtype is None:
+    # a Pallas semaphore ref has a shape and a dtype that is no array
+    # dtype (no itemsize): it occupies no bytes of the budget
+    itemsize = getattr(getattr(aval, "dtype", None), "itemsize", None)
+    if shape is None or itemsize is None:
         return 0
     n = 1
     for d in shape:
         n *= int(d)
-    return n * dtype.itemsize
+    return n * itemsize
 
 
 def _eqn_axes(eqn) -> Tuple[str, ...]:
@@ -576,7 +578,7 @@ _J7_CALL_PRIMS = {"pjit", "closed_call", "core_call", "shard_map"}
 
 
 def _j7_sub_jaxpr(eqn):
-    import jax.core as jc
+    import jax.extend.core as jc
     sub = eqn.params.get("jaxpr")
     if isinstance(sub, jc.ClosedJaxpr):
         return sub.jaxpr

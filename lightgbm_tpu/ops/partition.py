@@ -51,8 +51,9 @@ def partition_rows(
     The choice is made at trace time — both paths are pure functions of
     the same inputs with identical outputs.  The v2 kernel is
     HBM-resident with per-chunk DMA staging, so it is taken at ANY row
-    count (v1's >650k silent XLA fallback is gone; only
-    ``LGBMTPU_PARTITION_PALLAS=0`` and the degradation registry opt out).
+    count (v1's >650k silent XLA fallback is gone).  No grower passes
+    ``use_pallas=True`` today: Mosaic refuses the kernel on the chip
+    (ops/partition_pallas.py, "Validation status").
     """
     if use_pallas or interpret:
         from ..utils import degrade as _degrade

@@ -18,7 +18,7 @@ budget capped the kernel at ``_MAX_VMEM_ROWS = 650_000`` rows with a
 silent XLA fallback above — exactly the regime the Higgs-11M target
 lives in (ROADMAP "Uncap N").  v2 removes the cap:
 
-* ``order``/``go_left``/``out`` live in HBM (``pltpu.ANY`` refs — no
+* ``order``/``go_left``/``out`` live in HBM (``pl.ANY`` refs — no
   BlockSpec staging at all); the kernel streams fixed-size chunks
   through a small double-buffered VMEM scratch via
   ``pltpu.make_async_copy`` DMA, starting chunk c+1's copy-in while
@@ -48,16 +48,18 @@ lives in (ROADMAP "Uncap N").  v2 removes the cap:
 
 With staging gone the dispatcher no longer needs a row cap:
 ``partition_rows`` takes this kernel at ANY N (the 650k fallback is
-deleted; ``LGBMTPU_PARTITION_PALLAS=0`` and the degradation registry
-remain the only opt-outs).
+deleted).
 
-Validation status (honest): equivalence vs ``stable_partition_ranges``
-is pinned in ``tests/test_partition.py`` through Mosaic INTERPRET mode —
-this container has no TPU — including a slow-marked >650k-row case that
-v1 could not reach.  The DMA constructs follow the accelerator guide's
-double-buffering pattern; on-chip the expected ceiling is the scalar
-compaction stores plus the serialized RMW DMA chain (4 DMAs on boundary
-chunks, 2 on interior ones since the round-16 read-half skip), untuned.
+Validation status: equivalence vs ``stable_partition_ranges`` is pinned in
+``tests/test_partition.py`` through Mosaic INTERPRET mode, including a
+slow-marked >650k-row case that v1 could not reach.  ON THE CHIP THE
+KERNEL DOES NOT COMPILE (PR 21, TPU v5e, jax 0.9.0 / libtpu 0.0.34, 1M
+rows x 8 segments): Pallas's Mosaic lowering raises ``ValueError: Cannot
+store scalars to VMEM`` at ``lc_ref[0, s] = n_left`` and, in the move
+sweep, at the compaction stores ``dbuf[0, 0, k] = obuf[slot, 0, i]``.  The
+growers therefore no longer select it (``treegrow_windowed.
+PALLAS_PARTITION``); ROADMAP.md Design item 1 decides between a repair
+(compaction through SMEM, or a vector formulation) and deletion.
 """
 
 from __future__ import annotations
@@ -259,11 +261,11 @@ def partition_pallas_segments(
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # order: HBM, DMA-chunked
-            pl.BlockSpec(memory_space=pltpu.ANY),  # go_left: HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # order: HBM, DMA-chunked
+            pl.BlockSpec(memory_space=pl.ANY),  # go_left: HBM
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # out: HBM, run-wise DMA
+            pl.BlockSpec(memory_space=pl.ANY),  # out: HBM, run-wise DMA
             # jaxlint: disable=R11 (left counts are O(S) segments — a few KB — not row-proportional; staging whole is the point)
             pl.BlockSpec((1, S), lambda s, *_: (0, 0),
                          memory_space=pltpu.VMEM),
@@ -282,7 +284,7 @@ def partition_pallas_segments(
             jax.ShapeDtypeStruct((1, n_pad), order.dtype),
             jax.ShapeDtypeStruct((1, S), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -11,7 +11,7 @@ so those are three full window-sweeps where one suffices (ROADMAP "round
 megakernel"; docs/PERF_NOTES.md round 16).
 
 This module fuses them into a single Pallas kernel with an HBM-resident
-grid (``pltpu.ANY`` refs throughout — the jaxlint R11 discipline; nothing
+grid (``pl.ANY`` refs throughout — the jaxlint R11 discipline; nothing
 row- or bin-proportional is ever staged whole in VMEM):
 
 * **partition phase** — the round-12 ``make_async_copy`` chunk-DMA move
@@ -50,17 +50,19 @@ tests/test_megakernel.py pins the megakernel round bitwise-equal to the
 three-pass round across the equivalence matrix (float / int8-quantized /
 categorical, interpret mode on CPU).
 
-Validation status (honest): this container has no TPU; the kernel is
-validated through Mosaic INTERPRET mode, like partition_pallas v2 was.
-The DMA constructs (per-chunk double buffering, per-row column gather —
-the paged-attention-style pattern) follow the accelerator guide; the
-scatter accumulate and the on-core gain reduction (argsort in the
-categorical scan) are the two pieces Mosaic is expected to reject on
-chip until the MXU one-hot accumulate variant lands (the hist_pallas
-bf16x2 lanes, queued in docs/NEXT.md) — the utils/degrade.py registry
-turns that into a logged permanent fallback to the three-pass round, not
-a dead run.  Expected on-chip ceiling once landed: one bin-matrix sweep
-per round (J7 pins ``<= 1`` statically) vs the three-pass round's three.
+Validation status: the kernel is validated through Mosaic INTERPRET mode,
+like partition_pallas v2 was.  ON THE CHIP IT DOES NOT COMPILE (PR 21, TPU
+v5e, jax 0.9.0 / libtpu 0.0.34, 100k x 28, 31 leaves, 256 bins, leaf tile
+8): Pallas's Mosaic lowering raises ``ValueError: Cannot store scalars to
+VMEM`` at the partition phase's compaction store
+(partition_pallas.emit_move_sweep, ``dbuf[0, 0, k] = obuf[slot, 0, i]``)
+and stops there, so the scatter accumulate and the on-core gain reduction
+(argsort in the categorical scan), which were the two pieces expected to
+be refused, were not reached.  ``megakernel_mode``'s ``auto`` therefore
+selects nothing (ops/treegrow_windowed.py); ``1`` still forces the kernel,
+and on the chip that raises: this refusal's text names neither Mosaic nor
+Pallas, so utils/degrade.py does not take it for a kernel failure.
+ROADMAP.md Design item 1 decides between a redesign and deletion.
 """
 
 from __future__ import annotations
@@ -328,9 +330,9 @@ def round_megakernel(
                     has_cat=has_cat, has_contri=feature_contri is not None)
 
     tensor_in = [bins_t, order_p, go_p, pay]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 4
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 4
     out_shape = [jax.ShapeDtypeStruct((1, n_pad), jnp.int32)]
-    out_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     if fuse_tail:
         ftab_i = jnp.stack([
             jnp.asarray(num_bins_pf, jnp.int32),
@@ -344,7 +346,7 @@ def round_megakernel(
               else jnp.zeros((1, f), jnp.float32))
         tensor_in += [parent_hists, cand_tab, ftab_i, fc]
         in_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY),  # parent hists: HBM, DMA
+            pl.BlockSpec(memory_space=pl.ANY),  # parent hists: HBM, DMA
             # jaxlint: disable=R11 (O(tile) candidate scalars — a few hundred bytes, not row-proportional)
             pl.BlockSpec((5, 2 * T), lambda i, *_: (0, 0),
                          memory_space=pltpu.VMEM),
@@ -366,13 +368,13 @@ def round_megakernel(
             jax.ShapeDtypeStruct((2 * T, f), jnp.float32),  # left_h
             jax.ShapeDtypeStruct((2 * T, f), jnp.float32),  # left_c
         ]
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2 + [
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2 + [
             # jaxlint: disable=R11 (O(tile x F) REDUCED per-feature bests — the point of the on-core reduction; not row- or bin-proportional)
             pl.BlockSpec((2 * T, f), lambda i, *_: (0, 0),
                          memory_space=pltpu.VMEM)] * 7
     else:
         out_shape += [jax.ShapeDtypeStruct((T, 3, f, B), jnp.float32)]
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY)]
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -398,7 +400,7 @@ def round_megakernel(
         functools.partial(_mk_kernel, st=st, params=params),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -71,17 +71,16 @@ collectives vs. the single-model round, donation is consumed on the
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..utils import degrade as _degrade
 from .split import SplitParams
 from .treegrow import TreeArrays
-from .treegrow_windowed import (_round_fused, _run_fused_rounds, _w_finalize,
-                                _w_init, _window_size)
+from .treegrow_windowed import (PALLAS_PARTITION, _round_fused,
+                                _run_fused_rounds, _w_finalize, _w_init,
+                                _window_size)
 
 _INT32_MAX = 2 ** 31 - 1
 
@@ -266,19 +265,13 @@ def grow_fleet_windowed(
         hist_precision=hist_precision,
         stochastic_rounding=stochastic_rounding, **common)
 
-    # same degradation-aware gate as the solo grower: the Pallas segment
-    # partition is the TPU default, env/registry drop to the XLA path
-    pallas_partition = use_pallas and (
-        os.environ.get("LGBMTPU_PARTITION_PALLAS", "1") != "0") and (
-        _degrade.available(_degrade.PARTITION))
-
     def round_fn(st, W):
         st, info = _fleet_round(
             st, bins_t, g_d, h_d, gq, hq, qs, row_mask,
             num_bins_pf, missing_bin_pf, feature_mask,
             max_depth=max_depth, W=W, use_pallas=use_pallas,
             quantize_bins=quantize_bins, hist_precision=hist_precision,
-            pallas_partition=pallas_partition, **common)
+            pallas_partition=PALLAS_PARTITION, **common)
         return st, info
 
     # the solo async ladder drives the fleet UNCHANGED — same rungs, same

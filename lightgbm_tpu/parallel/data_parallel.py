@@ -34,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.split import SplitParams
 from ..ops.treegrow import TreeArrays, grow_tree
-from .compat import shard_map
+from jax import shard_map
 from .mesh import DATA_AXIS, data_axis_size
 
 
@@ -489,8 +489,6 @@ def grow_tree_windowed_data_parallel(
     deterministic replicated admission — it is refused with per-node
     feature sampling (feature_fraction_bynode/extra_trees), whose sampled
     set must span the full feature axis on every rank."""
-    import os as _os
-
     from ..ops import treegrow_windowed as _tw
     from ..utils import degrade as _degrade
 
@@ -517,9 +515,6 @@ def grow_tree_windowed_data_parallel(
     fcontri = _pad_features(feature_contri, f_pad, 1.0, rep)
 
     use_pallas = bool(use_pallas and _degrade.available(_degrade.HIST))
-    pallas_partition = use_pallas and (
-        _os.environ.get("LGBMTPU_PARTITION_PALLAS", "1") != "0") and (
-        _degrade.available(_degrade.PARTITION))
     # round megakernel (ops/round_pallas.py) under SPMD: each rank's
     # partition + window histogram is one fused kernel; the leaf-histogram
     # merge stays the round's single in-dispatch collective (psum /
@@ -550,7 +545,7 @@ def grow_tree_windowed_data_parallel(
             common, max_depth=max_depth, use_pallas=use_pallas,
             quantize_bins=quantize_bins, hist_precision=hist_precision,
             has_cat=categorical_mask is not None,
-            pallas_partition=pallas_partition,
+            pallas_partition=_tw.PALLAS_PARTITION,
             megakernel=megakernel, mk_interpret=mk_interpret).items()))
         round_opt = {"gq": gq, "hq": hq, "quant_scale": qs,
                      "rng_key": rng_key, "feature_contri": fcontri,

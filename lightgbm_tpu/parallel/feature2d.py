@@ -44,7 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.split import SplitParams
 from ..ops.treegrow import TreeArrays
-from .compat import shard_map
+from jax import shard_map
 from .data_parallel import _WOPT_SPECS, _pad_features
 from .mesh import DATA_AXIS, FEATURE_AXIS
 

@@ -33,7 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.split import SplitParams
 from ..ops.treegrow import TreeArrays, grow_tree
-from .compat import shard_map
+from jax import shard_map
 from .mesh import DATA_AXIS
 
 

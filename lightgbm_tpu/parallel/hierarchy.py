@@ -55,7 +55,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.split import (BestSplit, KMIN_SCORE, SplitParams, find_best_split,
                          gain_plane)
 from ..ops.treegrow import TreeArrays
-from .compat import shard_map
+from jax import shard_map
 from .mesh import DCN_AXIS, ICI_AXIS, slice_axis_sizes
 
 

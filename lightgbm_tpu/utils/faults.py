@@ -3,7 +3,7 @@ test drives (docs/ROBUSTNESS.md).
 
 A production boosting run dies in a handful of well-understood ways: the
 host process is preempted mid-round, a snapshot write is cut short, a
-remote Mosaic/Pallas compile fails, an SPMD worker dies, or a custom
+Mosaic/Pallas compile fails, an SPMD worker dies, or a custom
 objective emits NaN gradients.  Each of those failure classes has an
 injection SITE wired into the runtime; arming a site is purely
 environmental, so the library code under test is byte-identical to
@@ -46,7 +46,7 @@ Sites (see docs/ROBUSTNESS.md for the exact trigger points):
                     ``LIGHTGBM_TPU_RANK``).
 ``pallas_hist``     the histogram dispatcher (ops/histogram.py) — raises
                     :class:`InjectedFault` at trace time, modelling a
-                    remote Mosaic kernel-compile failure.  <round> counts
+                    Mosaic kernel-compile failure.  <round> counts
                     dispatcher CALLS (0 = first).
 ``pallas_partition``ops/partition.py::partition_rows — same semantics.
 ``pallas_round``    ops/treegrow_windowed.py::grow_tree_windowed's round-

@@ -30,6 +30,36 @@ def test_classifier_recognizes_kernel_failures_only():
         faults.InjectedFault("worker_death", 1))
 
 
+def test_python_level_errors_never_degrade():
+    """API drift reads like a kernel failure ("module
+    'jax.experimental.pallas.tpu' has no attribute ...") and is a bug in
+    this repo: it propagates through the net and disables nothing, while
+    a Mosaic-style runtime error still degrades."""
+    drift = AttributeError(
+        "module 'jax.experimental.pallas.tpu' has no attribute "
+        "'RenamedParams'")
+    for exc in (drift, TypeError("pallas_call() got an unexpected keyword"),
+                ImportError("cannot import name 'pallas'"),
+                NameError("name 'pltpu' is not defined (mosaic)")):
+        assert not degrade.is_pallas_failure(exc)
+
+    def primary():
+        raise drift
+
+    with pytest.raises(AttributeError, match="RenamedParams"):
+        degrade.run_with_fallback(degrade.PARTITION, primary, lambda: "xla")
+    assert degrade.available(degrade.PARTITION)
+    assert degrade.disabled_reason(degrade.PARTITION) is None
+
+    def mosaic():
+        raise RuntimeError("Mosaic failed to compile TPU kernel: scoped "
+                           "vmem limit exceeded")
+
+    assert degrade.run_with_fallback(
+        degrade.PARTITION, mosaic, lambda: "xla") == "xla"
+    assert "Mosaic" in degrade.disabled_reason(degrade.PARTITION)
+
+
 def test_disable_logs_once(caplog):
     logger = logging.getLogger("lgbm_degrade_test")
     import lightgbm_tpu as lgb
@@ -148,10 +178,10 @@ def test_grower_level_retry_catches_execute_time_failures(monkeypatch):
     """A Pallas failure that escapes the trace-time dispatchers (compile/
     execute time) is caught by the grower wrapper: disable + regrow on
     the XLA path from the original inputs.  Since round 16 the net is
-    LAYERED: with the megakernel active (the use_pallas default), the
-    first failure is attributed to the ROUND kernel (retry on the
-    three-pass round, Pallas hist still on); a second failure degrades
-    HIST and lands on the XLA path."""
+    LAYERED: with the megakernel forced on (``auto`` no longer selects
+    it), the first failure is attributed to the ROUND kernel (retry on
+    the three-pass round, Pallas hist still on); a second failure
+    degrades HIST and lands on the XLA path."""
     from lightgbm_tpu.ops import treegrow_windowed as tw
 
     calls = []
@@ -168,7 +198,7 @@ def test_grower_level_retry_catches_execute_time_failures(monkeypatch):
     from tests.test_nonfinite import _windowed_inputs
 
     bins_t, grad, hess, kw, static = _windowed_inputs(seed=9)
-    static = dict(static, use_pallas=True)
+    static = dict(static, use_pallas=True, megakernel_opt="1")
     tree, leaf = tw.grow_tree_windowed(bins_t, grad, hess, **kw, **static)
     assert calls == [True, True, False]
     assert int(tree.num_leaves) > 1

@@ -1425,9 +1425,9 @@ def test_r11_negative_hbm_ref_and_grid_blocking_and_fixed_tiles(tmp_path):
         from jax.experimental.pallas import tpu as pltpu
 
         def specs(n, row_tile, nc):
-            hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+            hbm = pl.BlockSpec(memory_space=pl.ANY)
             hbm2 = pl.BlockSpec((1, n), lambda s: (0, 0),
-                                memory_space=pltpu.ANY)
+                                memory_space=pl.ANY)
             grid_blocked = pl.BlockSpec((row_tile, nc), lambda j, i: (i, 0),
                                         memory_space=pltpu.VMEM)
             fixed = pl.BlockSpec((1, 512), lambda s: (0, 0),

@@ -181,7 +181,7 @@ def _loopback_shard_map(body, n_out=1):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from lightgbm_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from lightgbm_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(min(4, len(jax.devices())))
@@ -221,7 +221,7 @@ def test_j1_undeclared_axis_fails():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from lightgbm_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("rows",))
     fn = jax.jit(shard_map(
@@ -286,7 +286,7 @@ def test_j3_f64_leak_fails():
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         f = jax.jit(lambda x: x.astype(jnp.float64).sum())
         c = _fixture_contract(
             "fixture_f64_leak",
@@ -501,7 +501,7 @@ def test_dcn_bytes_fixture_full_histogram_over_dcn_fails():
     from jax.sharding import PartitionSpec as P
 
     from lightgbm_tpu.analysis.jaxpr_audit import dcn_axis_bytes
-    from lightgbm_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from lightgbm_tpu.parallel.mesh import make_mesh_hierarchical
 
     mesh = make_mesh_hierarchical(2, min(2, max(1, jax.device_count() // 2)))

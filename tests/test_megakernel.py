@@ -291,6 +291,9 @@ def test_megakernel_envelope_quantized_pallas_falls_back_loudly():
                for e in obs.events("megakernel_fallback"))
     assert megakernel_mode(False, quantize_bins=16, mode="interpret")[0]
     assert megakernel_mode(True, quantize_bins=0, mode="1")[0]
+    # auto selects nothing, even on the Pallas hot path of a healthy
+    # process: Mosaic refuses the kernel (ROADMAP.md Design item 1)
+    assert megakernel_mode(True, mode="auto") == (False, False)
 
 
 def test_megakernel_interpret_ignores_degraded_registry():

@@ -263,7 +263,7 @@ def test_obs_cli_dumps_snapshot(tmp_path, capsys):
 def test_timed_section_routes_through_registry():
     with timed_section("unit_section"):
         pass
-    with timed_section("unit_section", sync=True):  # host-pull sync path
+    with timed_section("unit_section", sync=True):  # queue-drain path
         pass
     h = obs.histogram(obs.SECTION_PREFIX + "unit_section")
     assert h.count == 2
