@@ -34,9 +34,9 @@ from ..ops.split import SplitParams
 from ..ops.treegrow import grow_tree
 from ..ops import predict as predict_ops
 from ..utils import faults as _faults
-from ..utils import profiling as _profiling  # noqa: F401 — importing
-# installs the jax.profiler span-annotation bridge when
-# LGBMTPU_JAX_PROFILER=1 (obs/ itself must stay jax-free)
+from ..utils import profiling as _profiling  # the phase scopes; importing
+# also installs the jax.profiler bridge of the spans: a step per boost_round
+# always, every span under LGBMTPU_JAX_PROFILER=1 (obs/ itself stays jax-free)
 from ..utils import locktrace as _lt
 from ..utils import sanitizer as _san
 from .tree import Tree, tree_from_device
@@ -302,6 +302,21 @@ class GBDT:
                 tree = tree_from_device(arrays, self.binner, linear=linear_fit)
                 tree.apply_shrinkage(shrink)
                 self._models.append(tree)
+                if arrays.hist_passes is not None:
+                    self._count_hist_passes(int(arrays.hist_passes), tree)
+
+    def _count_hist_passes(self, passes: int, tree: Tree) -> None:
+        """What the tree's histogram passes read against what the tree
+        needed, from arrays the flush has on the host already (no pull on
+        the hot path).  Every pass of the rounds grower streams all rows of
+        the ``Dataset``; a leaf-wise learner with histogram subtraction
+        needs the rows once for the root and then the smaller child of
+        every split."""
+        n_rows = int(self.train_set.num_data())
+        _obs.counter("train_hist_passes_total").inc(passes)
+        _obs.counter("train_hist_rows_streamed_total").inc(passes * n_rows)
+        _obs.counter("train_hist_rows_needed_total").inc(
+            n_rows + tree.smaller_child_rows())
 
     # -- non-finite guard rail (docs/ROBUSTNESS.md) --------------------
     def _guard_accumulate(self, arrays) -> None:
@@ -1281,8 +1296,9 @@ class GBDT:
         # jaxlint: disable=R2 (cached in self._fused_step; rebuilt only when _fused_bake_key changes)
         def step(score, row_mask, sample_weight, feature_mask, shrinkage,
                  goss_key, goss_warm, obj_state):
-            g, h, new_obj_state = obj.fused_gradients(
-                score, label, weight, obj_state)
+            with _profiling.phase_scope("gbdt.gradients"):
+                g, h, new_obj_state = obj.fused_gradients(
+                    score, label, weight, obj_state)
             if use_goss:
                 # GOSS in-trace (reference: goss.hpp): the mask depends on
                 # THIS iteration's gradients, so it must live inside the
@@ -1322,11 +1338,12 @@ class GBDT:
                     fs[2] if fs else None,
                     **grow_kwargs,
                 )
-                row_delta = (arrays.leaf_value * shrinkage)[leaf_id]
-                if k == 1:
-                    new_score = new_score + row_delta
-                else:
-                    new_score = new_score.at[:, c].add(row_delta)
+                with _profiling.phase_scope("gbdt.score_update"):
+                    row_delta = (arrays.leaf_value * shrinkage)[leaf_id]
+                    if k == 1:
+                        new_score = new_score + row_delta
+                    else:
+                        new_score = new_score.at[:, c].add(row_delta)
                 arrays_all.append(arrays)
                 leaf_all.append(leaf_id)
             return (tuple(arrays_all), tuple(leaf_all), new_score, g, h,
@@ -1348,9 +1365,9 @@ class GBDT:
         and an unsynced timer would be the jaxlint-R9 mistiming
         anti-pattern.  The ``boost_round`` SPAN around the impl carries the
         same ledger deltas; its duration is host-causal by design (spans
-        never add a sync — jaxlint R10), and with LGBMTPU_JAX_PROFILER=1
-        it mirrors into jax.profiler.StepTraceAnnotation so profiler steps
-        line up with boosting iterations."""
+        never add a sync — jaxlint R10), and it mirrors into
+        jax.profiler.StepTraceAnnotation (utils/profiling.py, no switch), so
+        any profiler trace has a step per boosting iteration."""
         if not _obs.enabled():
             return self._train_one_iter_impl(grad, hess)
         c0 = _san.compile_totals()
@@ -1458,7 +1475,9 @@ class GBDT:
                 return all(bool(a.num_leaves <= 1) for a in arrays_all)
             return False
         if grad is None:
-            g, h = self.objective.get_gradients(self._score, self._label, self._weight)
+            with _profiling.phase_scope("gbdt.gradients"):
+                g, h = self.objective.get_gradients(
+                    self._score, self._label, self._weight)
         else:
             g = jnp.asarray(grad, jnp.float32).reshape(self._score.shape)
             h = jnp.asarray(hess, jnp.float32).reshape(self._score.shape)
@@ -1889,14 +1908,18 @@ class GBDT:
                     jnp.asarray(all_const, dtype=bool), arrays.num_leaves <= 1
                 )
                 self._pending.append((arrays, shrinkage, linear_fit))
-                if linear_fit is not None:
-                    row_delta = lin_pred * jnp.float32(shrinkage)
-                else:
-                    row_delta = (arrays.leaf_value * jnp.float32(shrinkage))[leaf_id]
-                if k == 1:
-                    self._score = self._score + row_delta
-                else:
-                    self._score = self._score.at[:, c].add(row_delta)
+                # eager operations: a cached primitive is not traced again,
+                # so the scope may be missing from these; the phase reduction
+                # files them under outside_grower by their module
+                with _profiling.phase_scope("gbdt.score_update"):
+                    if linear_fit is not None:
+                        row_delta = lin_pred * jnp.float32(shrinkage)
+                    else:
+                        row_delta = (arrays.leaf_value * jnp.float32(shrinkage))[leaf_id]
+                    if k == 1:
+                        self._score = self._score + row_delta
+                    else:
+                        self._score = self._score.at[:, c].add(row_delta)
                 for vi, vs in enumerate(self.valid_sets):
                     from ..ops.treegrow_fast import predict_leaf_arrays
 

@@ -59,6 +59,24 @@ class Tree:
     def is_categorical_node(self) -> np.ndarray:
         return (self.decision_type & K_CATEGORICAL_MASK) != 0
 
+    def smaller_child_rows(self) -> int:
+        """Sum over the splits of the smaller child's row count: the rows a
+        leaf-wise learner with histogram subtraction visits after the root
+        (children encode a leaf as ``~leaf``)."""
+        m = int(self.num_leaves) - 1
+        if m <= 0:
+            return 0
+        node_rows = np.asarray(self.internal_count[:m], np.int64)
+        leaf_rows = np.asarray(self.leaf_count[:m + 1], np.int64)
+
+        def rows(child):
+            c = np.asarray(child[:m], np.int64)
+            return np.where(c >= 0, node_rows[np.maximum(c, 0)],
+                            leaf_rows[np.maximum(-c - 1, 0)])
+
+        return int(np.minimum(rows(self.left_child),
+                              rows(self.right_child)).sum())
+
     def cat_decision_left(self, node: int, value: float) -> bool:
         """reference: Tree::CategoricalDecision — value in bitset -> left;
         NaN / negative / not-found -> right."""

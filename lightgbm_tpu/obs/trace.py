@@ -40,12 +40,15 @@ round-trips through the CLI while chrome://tracing and ui.perfetto.dev
 read the standard ``traceEvents`` list.
 
 On-chip correlation: :func:`set_annotation_factory` accepts a callable
-``(name, attrs) -> context manager`` entered for the body of every
-context-manager span.  ``utils/profiling.py`` installs a
-``jax.profiler.TraceAnnotation``/``StepTraceAnnotation`` factory when
-``LGBMTPU_JAX_PROFILER=1``, lining host spans up with XLA device traces —
-the jax bridge lives in that (jax-importing) layer, never here: this
-module stays stdlib-only like the rest of ``lightgbm_tpu/obs``.
+``(name, attrs) -> context manager`` (or None for a span it does not
+mirror) entered for the body of a context-manager span.
+``utils/profiling.py`` installs one when it is imported: a
+``jax.profiler.StepTraceAnnotation`` for every span that carries
+``iteration``/``step`` (``boost_round``), and under
+``LGBMTPU_JAX_PROFILER=1`` a ``TraceAnnotation`` for every other span too,
+lining host spans up with XLA device traces — the jax bridge lives in that
+(jax-importing) layer, never here: this module stays stdlib-only like the
+rest of ``lightgbm_tpu/obs``.
 
 Enablement follows the metrics registry (``telemetry=false`` /
 ``LGBMTPU_TELEMETRY=0`` silences spans too); a disabled span is a cheap
@@ -105,7 +108,7 @@ _ring: "collections.deque" = collections.deque(maxlen=TRACE_RING_CAP)
 _ids = itertools.count(1)
 _tls = threading.local()
 _annotation_factory: Optional[
-    Callable[[str, Dict[str, Any]], ContextManager]] = None
+    Callable[[str, Dict[str, Any]], Optional[ContextManager]]] = None
 _spill_fh = None
 _spill_path: Optional[str] = None
 _spill_bytes = 0
@@ -185,12 +188,13 @@ def _handle_eviction(evicted: Dict[str, Any]) -> None:
 
 
 def set_annotation_factory(
-        fn: Optional[Callable[[str, Dict[str, Any]], ContextManager]]
+        fn: Optional[Callable[[str, Dict[str, Any]], Optional[ContextManager]]]
 ) -> None:
     """Install (or clear, with None) the device-annotation mirror used by
     context-manager spans.  The factory must be cheap and must not raise;
-    utils/profiling.py installs the jax.profiler one behind
-    ``LGBMTPU_JAX_PROFILER=1``."""
+    it returns None for a span it does not mirror.  utils/profiling.py
+    installs the jax.profiler one (steps always, every span behind
+    ``LGBMTPU_JAX_PROFILER=1``)."""
     global _annotation_factory
     _annotation_factory = fn
 
@@ -410,7 +414,8 @@ class Span:
         if fac is not None:
             try:
                 self._annotation = fac(self.name, self.attrs)
-                self._annotation.__enter__()
+                if self._annotation is not None:  # None: not mirrored
+                    self._annotation.__enter__()
             except Exception:  # noqa: BLE001 — a broken profiler bridge
                 self._annotation = None  # must never take training down
         return self

@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.profiling import phase_scope
+
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -175,24 +177,28 @@ def _hist_pallas_raw(
         # shrinks with total F (round 2 clamped row_tile to 512 and leaf
         # tile to ~5 at 2000x255; in-trace per-op launches cost no host
         # dispatch)
-        outs = [
-            _hist_pallas_raw(
-                bins[:, j0:j0 + _FEAT_BLOCK], payload,
-                num_bins=num_bins, row_tile=row_tile,
-                matmul_dtype=matmul_dtype,
-            )
-            for j0 in range(0, f, _FEAT_BLOCK)
-        ]
-        return jnp.concatenate(outs, axis=0)
+        outs = []
+        for j0 in range(0, f, _FEAT_BLOCK):
+            with phase_scope("hist.rowpad"):  # the chunk's copy of the bins
+                chunk = bins[:, j0:j0 + _FEAT_BLOCK]
+            outs.append(_hist_pallas_raw(
+                chunk, payload, num_bins=num_bins, row_tile=row_tile,
+                matmul_dtype=matmul_dtype))
+        with phase_scope("hist.unpack"):
+            return jnp.concatenate(outs, axis=0)
 
     FB = f  # narrow data: one feature block (wide F recursed above)
     n_pad = _round_up(n, row_tile)
     if n_pad != n:
-        bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)))
-        payload = jnp.pad(payload, ((0, n_pad - n), (0, 0)))
+        with phase_scope("hist.rowpad"):
+            bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)))
+            payload = jnp.pad(payload, ((0, n_pad - n), (0, 0)))
     grid = (1, n_pad // row_tile)
 
     out_dims = (f, nc, B)
+    # no scope and no name= here: XLA names the custom call after the
+    # innermost component of its op_name, and the benchmark's kernel metrics
+    # find it in a device trace as ``_hist_pallas_raw.N`` (_kernel_pass)
     out = pl.pallas_call(
         functools.partial(_direct_kernel, FB=FB, B=B, NC=nc, dtype=matmul_dtype),
         grid=grid,
@@ -209,6 +215,16 @@ def _hist_pallas_raw(
         ),
     )(bins, payload)
     return out
+
+
+def _kernel_pass(bins: jnp.ndarray, payload: jnp.ndarray, **kw):
+    """:func:`_hist_pallas_raw` under the ``hist.kernel`` scope.  The scope
+    sits outside the jitted function, so the custom call's op_name still ends
+    ``jit(_hist_pallas_raw)/pallas_call`` and its name in a trace stays
+    ``_hist_pallas_raw.N``; the pads inside carry ``hist.rowpad``, which as
+    the inner scope is the one the phase reduction takes."""
+    with phase_scope("hist.kernel"):
+        return _hist_pallas_raw(bins, payload, **kw)
 
 
 def _split_bf16x2(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -233,30 +249,33 @@ def histogram_pallas(
     MXU cost as bf16; ~17-bit-mantissa products — see module docstring);
     'bf16' uses rounded payloads in 4 lanes (~8-bit mantissa).
     """
-    m = mask.astype(jnp.float32)
-    g = grad.astype(jnp.float32) * m
-    h = hess.astype(jnp.float32) * m
-    if precision == "f32":
-        g_hi, g_lo = _split_bf16x2(g)
-        h_hi, h_lo = _split_bf16x2(h)
-        pay = jnp.stack([g_hi, h_hi, m, jnp.zeros_like(m), g_lo, h_lo,
-                         jnp.zeros_like(m), jnp.zeros_like(m)], axis=-1)
-    elif precision == "bf16":
-        pay = jnp.stack([g, h, m, jnp.zeros_like(m)], axis=-1)
-    else:
-        raise ValueError(precision)
-    out = _hist_pallas_raw(
+    with phase_scope("hist.payload"):
+        m = mask.astype(jnp.float32)
+        g = grad.astype(jnp.float32) * m
+        h = hess.astype(jnp.float32) * m
+        if precision == "f32":
+            g_hi, g_lo = _split_bf16x2(g)
+            h_hi, h_lo = _split_bf16x2(h)
+            pay = jnp.stack([g_hi, h_hi, m, jnp.zeros_like(m), g_lo, h_lo,
+                             jnp.zeros_like(m), jnp.zeros_like(m)], axis=-1)
+        elif precision == "bf16":
+            pay = jnp.stack([g, h, m, jnp.zeros_like(m)], axis=-1)
+        else:
+            raise ValueError(precision)
+    out = _kernel_pass(
         bins, pay, num_bins=num_bins, row_tile=row_tile,
         matmul_dtype=jnp.bfloat16,
     )  # (F, NC, B)
-    if precision == "f32":
-        out3 = jnp.stack(
-            [out[:, 0] + out[:, 4], out[:, 1] + out[:, 5], out[:, 2]], axis=0
-        )  # (3, F, B)
-    else:
-        out3 = out[:, :3, :].transpose(1, 0, 2)
-    if out3.shape[2] != num_bins:
-        out3 = out3[:, :, :num_bins]
+    with phase_scope("hist.unpack"):
+        if precision == "f32":
+            out3 = jnp.stack(
+                [out[:, 0] + out[:, 4], out[:, 1] + out[:, 5], out[:, 2]],
+                axis=0,
+            )  # (3, F, B)
+        else:
+            out3 = out[:, :3, :].transpose(1, 0, 2)
+        if out3.shape[2] != num_bins:
+            out3 = out3[:, :, :num_bins]
     return out3
 
 
@@ -281,46 +300,50 @@ def histogram_pallas_multi(
     This is the TPU replacement for per-leaf row-index histogramming
     (reference: Dataset::ConstructHistograms over DataPartition indices).
     """
-    m = mask.astype(jnp.float32)
-    g = grad.astype(jnp.float32) * m
-    h = hess.astype(jnp.float32) * m
-    if precision == "f32":
-        g_hi, g_lo = _split_bf16x2(g)
-        h_hi, h_lo = _split_bf16x2(h)
-        chans = [g_hi, h_hi, m, g_lo, h_lo, jnp.zeros_like(m)]
-    elif precision == "bf16":
-        chans = [g, h, m]
-    else:
-        raise ValueError(precision)
-    ncl = len(chans)
-    base = jnp.stack(chans, axis=-1)  # (N, ncl)
-    lid = leaf_id.astype(jnp.int32) - leaf_base
-    onehot = (
-        lid[:, None] == jnp.arange(num_leaves_tile, dtype=jnp.int32)[None, :]
-    ).astype(jnp.float32)  # (N, L_tile)
-    pay = (onehot[:, :, None] * base[:, None, :]).reshape(
-        bins.shape[0], num_leaves_tile * ncl
-    )
-    nc_pad = _round_up(num_leaves_tile * ncl, 4)
-    if nc_pad != pay.shape[1]:
-        pay = jnp.pad(pay, ((0, 0), (0, nc_pad - pay.shape[1])))
-    out = _hist_pallas_raw(
+    with phase_scope("hist.payload"):
+        m = mask.astype(jnp.float32)
+        g = grad.astype(jnp.float32) * m
+        h = hess.astype(jnp.float32) * m
+        if precision == "f32":
+            g_hi, g_lo = _split_bf16x2(g)
+            h_hi, h_lo = _split_bf16x2(h)
+            chans = [g_hi, h_hi, m, g_lo, h_lo, jnp.zeros_like(m)]
+        elif precision == "bf16":
+            chans = [g, h, m]
+        else:
+            raise ValueError(precision)
+        ncl = len(chans)
+        base = jnp.stack(chans, axis=-1)  # (N, ncl)
+        lid = leaf_id.astype(jnp.int32) - leaf_base
+        onehot = (
+            lid[:, None]
+            == jnp.arange(num_leaves_tile, dtype=jnp.int32)[None, :]
+        ).astype(jnp.float32)  # (N, L_tile)
+        pay = (onehot[:, :, None] * base[:, None, :]).reshape(
+            bins.shape[0], num_leaves_tile * ncl
+        )
+        nc_pad = _round_up(num_leaves_tile * ncl, 4)
+        if nc_pad != pay.shape[1]:
+            pay = jnp.pad(pay, ((0, 0), (0, nc_pad - pay.shape[1])))
+    out = _kernel_pass(
         bins, pay, num_bins=num_bins, row_tile=row_tile,
         matmul_dtype=jnp.bfloat16,
     )  # (F, nc_pad, B)
-    out = out[:, : num_leaves_tile * ncl, :].reshape(
-        bins.shape[1], num_leaves_tile, ncl, -1
-    )
-    if precision == "f32":
-        out3 = jnp.stack(
-            [out[:, :, 0] + out[:, :, 3], out[:, :, 1] + out[:, :, 4], out[:, :, 2]],
-            axis=2,
-        )  # (F, L_tile, 3, B)
-    else:
-        out3 = out[:, :, :3, :]
-    out3 = jnp.transpose(out3, (1, 2, 0, 3))  # (L_tile, 3, F, B)
-    if out3.shape[3] != num_bins:
-        out3 = out3[:, :, :, :num_bins]
+    with phase_scope("hist.unpack"):
+        out = out[:, : num_leaves_tile * ncl, :].reshape(
+            bins.shape[1], num_leaves_tile, ncl, -1
+        )
+        if precision == "f32":
+            out3 = jnp.stack(
+                [out[:, :, 0] + out[:, :, 3], out[:, :, 1] + out[:, :, 4],
+                 out[:, :, 2]],
+                axis=2,
+            )  # (F, L_tile, 3, B)
+        else:
+            out3 = out[:, :, :3, :]
+        out3 = jnp.transpose(out3, (1, 2, 0, 3))  # (L_tile, 3, F, B)
+        if out3.shape[3] != num_bins:
+            out3 = out3[:, :, :, :num_bins]
     return out3
 
 
@@ -359,21 +382,23 @@ def histogram_pallas_multi_quantized(
     (L_tile, 3, F, B) int32: exact integer accumulation on the int8 MXU
     (reference: gradient_discretizer.cpp + per-leaf ConstructHistograms).
     Lanes are leaf-onehot x (grad_q, hess_q, count) int8 payload."""
-    pay = quantized_leaf_payload(grad_q, hess_q, mask, leaf_id, leaf_base,
-                                 num_leaves_tile)
     ncl = 3
-    nc_pad = _round_up(num_leaves_tile * ncl, 4)
-    if nc_pad != pay.shape[1]:
-        pay = jnp.pad(pay, ((0, 0), (0, nc_pad - pay.shape[1])))
-    out = _hist_pallas_raw(
+    with phase_scope("hist.payload"):
+        pay = quantized_leaf_payload(grad_q, hess_q, mask, leaf_id,
+                                     leaf_base, num_leaves_tile)
+        nc_pad = _round_up(num_leaves_tile * ncl, 4)
+        if nc_pad != pay.shape[1]:
+            pay = jnp.pad(pay, ((0, 0), (0, nc_pad - pay.shape[1])))
+    out = _kernel_pass(
         bins, pay, num_bins=num_bins, row_tile=row_tile, matmul_dtype=jnp.int8
     )  # (F, nc_pad, B) int32
-    out = out[:, : num_leaves_tile * ncl, :].reshape(
-        bins.shape[1], num_leaves_tile, ncl, -1
-    )
-    out = jnp.transpose(out, (1, 2, 0, 3))  # (L_tile, 3, F, B)
-    if out.shape[3] != num_bins:
-        out = out[:, :, :, :num_bins]
+    with phase_scope("hist.unpack"):
+        out = out[:, : num_leaves_tile * ncl, :].reshape(
+            bins.shape[1], num_leaves_tile, ncl, -1
+        )
+        out = jnp.transpose(out, (1, 2, 0, 3))  # (L_tile, 3, F, B)
+        if out.shape[3] != num_bins:
+            out = out[:, :, :, :num_bins]
     return out
 
 
@@ -389,15 +414,17 @@ def histogram_pallas_quantized(
     """Quantized histogram -> (3, F, B) int32 (grad_sum, hess_sum, count):
     exact int32 accumulation on the int8 MXU (reference:
     src/treelearner/gradient_discretizer.cpp quantized-training path)."""
-    m8 = mask.astype(jnp.int8)
-    pay = jnp.stack(
-        [grad_q.astype(jnp.int8) * m8, hess_q.astype(jnp.int8) * m8, m8,
-         jnp.zeros_like(m8)],
-        axis=-1,
-    )
-    out = _hist_pallas_raw(bins, pay, num_bins=num_bins, row_tile=row_tile,
+    with phase_scope("hist.payload"):
+        m8 = mask.astype(jnp.int8)
+        pay = jnp.stack(
+            [grad_q.astype(jnp.int8) * m8, hess_q.astype(jnp.int8) * m8, m8,
+             jnp.zeros_like(m8)],
+            axis=-1,
+        )
+    out = _kernel_pass(bins, pay, num_bins=num_bins, row_tile=row_tile,
                            matmul_dtype=jnp.int8)
-    out = out[:, :3, :].transpose(1, 0, 2)
-    if out.shape[2] != num_bins:
-        out = out[:, :, :num_bins]
+    with phase_scope("hist.unpack"):
+        out = out[:, :3, :].transpose(1, 0, 2)
+        if out.shape[2] != num_bins:
+            out = out[:, :, :num_bins]
     return out

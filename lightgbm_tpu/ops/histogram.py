@@ -27,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..utils.profiling import phase_scope
+
 NUM_CHANNELS = 3
 
 
@@ -117,34 +119,39 @@ def histogram_onehot_multi(
     from .hist_pallas import _split_bf16x2
 
     n, f = bins.shape
-    m = mask.astype(jnp.float32)
-    g = grad.astype(jnp.float32) * m
-    h = hess.astype(jnp.float32) * m
-    if precision == "f32":
-        g_hi, g_lo = _split_bf16x2(g)
-        h_hi, h_lo = _split_bf16x2(h)
-        base = jnp.stack([g_hi, h_hi, m, g_lo, h_lo, jnp.zeros_like(m)], axis=-1)
-    elif precision == "bf16":
-        base = jnp.stack([g, h, m], axis=-1)
-    else:
-        raise ValueError(precision)
-    ncl = base.shape[-1]
-    lid = leaf_id.astype(jnp.int32) - leaf_base
-    onehot_l = (
-        lid[:, None] == jnp.arange(num_leaves_tile, dtype=jnp.int32)[None, :]
-    ).astype(jnp.float32)  # (N, L_tile)
-    payload = (onehot_l[:, :, None] * base[:, None, :]).reshape(
-        n, num_leaves_tile * ncl
-    )
+    with phase_scope("hist.payload"):
+        m = mask.astype(jnp.float32)
+        g = grad.astype(jnp.float32) * m
+        h = hess.astype(jnp.float32) * m
+        if precision == "f32":
+            g_hi, g_lo = _split_bf16x2(g)
+            h_hi, h_lo = _split_bf16x2(h)
+            base = jnp.stack(
+                [g_hi, h_hi, m, g_lo, h_lo, jnp.zeros_like(m)], axis=-1)
+        elif precision == "bf16":
+            base = jnp.stack([g, h, m], axis=-1)
+        else:
+            raise ValueError(precision)
+        ncl = base.shape[-1]
+        lid = leaf_id.astype(jnp.int32) - leaf_base
+        onehot_l = (
+            lid[:, None]
+            == jnp.arange(num_leaves_tile, dtype=jnp.int32)[None, :]
+        ).astype(jnp.float32)  # (N, L_tile)
+        payload = (onehot_l[:, :, None] * base[:, None, :]).reshape(
+            n, num_leaves_tile * ncl
+        )
     c = payload.shape[1]
 
     pad = (-n) % row_tile
     if pad:
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        payload = jnp.pad(payload, ((0, pad), (0, 0)))
+        with phase_scope("hist.rowpad"):
+            bins = jnp.pad(bins, ((0, pad), (0, 0)))
+            payload = jnp.pad(payload, ((0, pad), (0, 0)))
     nt = (n + pad) // row_tile
     bins_t = bins.reshape(nt, row_tile, f)
-    pay_t = payload.astype(jnp.bfloat16).reshape(nt, row_tile, c)
+    with phase_scope("hist.payload"):
+        pay_t = payload.astype(jnp.bfloat16).reshape(nt, row_tile, c)
 
     def body(acc, inp):
         b_tile, p_tile = inp
@@ -155,18 +162,21 @@ def histogram_onehot_multi(
                         preferred_element_type=jnp.float32)
         return acc + hh, None
 
-    init = jnp.zeros((f, num_bins, c), jnp.float32)
-    hist, _ = jax.lax.scan(body, init, (bins_t, pay_t))
-    # one transpose per pass to the package's channel-first layout
-    hist = jnp.transpose(hist, (2, 0, 1)).reshape(
-        num_leaves_tile, ncl, f, num_bins)
-    if precision == "f32":
-        out3 = jnp.stack(
-            [hist[:, 0] + hist[:, 3], hist[:, 1] + hist[:, 4], hist[:, 2]],
-            axis=1,
-        )  # (L_tile, 3, F, B)
-    else:
-        out3 = hist
+    with phase_scope("hist.kernel"):
+        init = jnp.zeros((f, num_bins, c), jnp.float32)
+        hist, _ = jax.lax.scan(body, init, (bins_t, pay_t))
+    with phase_scope("hist.unpack"):
+        # one transpose per pass to the package's channel-first layout
+        hist = jnp.transpose(hist, (2, 0, 1)).reshape(
+            num_leaves_tile, ncl, f, num_bins)
+        if precision == "f32":
+            out3 = jnp.stack(
+                [hist[:, 0] + hist[:, 3], hist[:, 1] + hist[:, 4],
+                 hist[:, 2]],
+                axis=1,
+            )  # (L_tile, 3, F, B)
+        else:
+            out3 = hist
     return out3
 
 
@@ -195,14 +205,16 @@ def histogram_onehot_multi_quantized(
 
     n, f = bins.shape
     ncl = 3
-    payload = quantized_leaf_payload(grad_q, hess_q, mask, leaf_id,
-                                     leaf_base, num_leaves_tile)
+    with phase_scope("hist.payload"):
+        payload = quantized_leaf_payload(grad_q, hess_q, mask, leaf_id,
+                                         leaf_base, num_leaves_tile)
     c = payload.shape[1]
 
     pad = (-n) % row_tile
     if pad:
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        payload = jnp.pad(payload, ((0, pad), (0, 0)))
+        with phase_scope("hist.rowpad"):
+            bins = jnp.pad(bins, ((0, pad), (0, 0)))
+            payload = jnp.pad(payload, ((0, pad), (0, 0)))
     nt = (n + pad) // row_tile
     bins_t = bins.reshape(nt, row_tile, f)
     pay_t = payload.reshape(nt, row_tile, c)
@@ -215,10 +227,12 @@ def histogram_onehot_multi_quantized(
                         preferred_element_type=jnp.int32)
         return acc + hh, None
 
-    init = jnp.zeros((f, num_bins, c), jnp.int32)
-    hist, _ = jax.lax.scan(body, init, (bins_t, pay_t))
-    return jnp.transpose(hist, (2, 0, 1)).reshape(
-        num_leaves_tile, ncl, f, num_bins)  # (L_tile, 3, F, B)
+    with phase_scope("hist.kernel"):
+        init = jnp.zeros((f, num_bins, c), jnp.int32)
+        hist, _ = jax.lax.scan(body, init, (bins_t, pay_t))
+    with phase_scope("hist.unpack"):
+        return jnp.transpose(hist, (2, 0, 1)).reshape(
+            num_leaves_tile, ncl, f, num_bins)  # (L_tile, 3, F, B)
 
 
 def histogram_multi(

@@ -62,6 +62,8 @@ class TreeArrays(NamedTuple):
     is_cat: jnp.ndarray  # (L-1,) bool — node is a categorical (bitset) split
     cat_mask: jnp.ndarray  # (L-1, B) bool — bins going left at cat nodes
     path_features: Optional[jnp.ndarray] = None  # (L, F) bool (linear trees)
+    hist_passes: Optional[jnp.ndarray] = None  # i32 scalar — full passes over
+    # the rows this tree took (the rounds grower counts them; others: None)
 
 
 class GrowState(NamedTuple):

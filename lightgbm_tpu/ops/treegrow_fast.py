@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils import degrade as _degrade
+from ..utils.profiling import phase_scope
 from .histogram import (histogram, histogram_multi, histogram_multi_quantized,
                         histogram_onehot_multi,
                         histogram_onehot_multi_quantized, unbundle_hists)
@@ -108,6 +109,8 @@ class FastState(NamedTuple):
     slot_right: jnp.ndarray  # (tile,) i32 — right-child leaf per slot (-1)
     slot_small_left: jnp.ndarray  # (tile,) bool — slot's small child is left
     progress: jnp.ndarray  # bool — this round applied at least one split
+    hist_passes: jnp.ndarray  # i32 — full passes over the rows so far: the
+    # root's, plus one for every hist_and_eval taken
     tree: TreeArrays
     anc: jnp.ndarray = False  # (L, L-1) bool ancestor masks, or () placeholder
     aside: jnp.ndarray = False  # (L, L-1) bool — leaf on the RIGHT side of m
@@ -227,8 +230,9 @@ def _grow_fast_impl(
     n, f = bins.shape
     # bins stay in their storage dtype (int16 on device — half the HBM of
     # int32 at Epsilon scale); kernels and column slices upcast per tile
-    grad = grad.astype(jnp.float32) * sample_weight
-    hess = hess.astype(jnp.float32) * sample_weight
+    with phase_scope("grow.root"):
+        grad = grad.astype(jnp.float32) * sample_weight
+        hess = hess.astype(jnp.float32) * sample_weight
     grad_true, hess_true = grad, hess
     L = num_leaves
 
@@ -236,34 +240,35 @@ def _grow_fast_impl(
         return jax.lax.psum(x, axis_name) if axis_name is not None else x
 
     if quantize_bins:
-        # discretize: grad in [-half, half], hess in [0, quantize_bins]
-        # (reference: GradientDiscretizer::DiscretizeGradients)
-        half = max(quantize_bins // 2, 1)
-        inbag = row_mask.astype(jnp.float32)
+        with phase_scope("grow.root"):
+            # discretize: grad in [-half, half], hess in [0, quantize_bins]
+            # (reference: GradientDiscretizer::DiscretizeGradients)
+            half = max(quantize_bins // 2, 1)
+            inbag = row_mask.astype(jnp.float32)
 
-        def pmax(x):
-            return jax.lax.pmax(x, axis_name) if axis_name is not None else x
+            def pmax(x):
+                return jax.lax.pmax(x, axis_name) if axis_name is not None else x
 
-        g_scale = jnp.maximum(pmax(jnp.max(jnp.abs(grad) * inbag)) / half, 1e-30)
-        h_scale = jnp.maximum(pmax(jnp.max(hess * inbag)) / quantize_bins, 1e-30)
-        gs = grad / g_scale
-        hs = hess / h_scale
-        if stochastic_rounding:
-            if quant_key is None:
-                quant_key = jax.random.PRNGKey(0)
-            kg, kh = jax.random.split(quant_key)
-            gq = jnp.floor(gs + jax.random.uniform(kg, gs.shape))
-            hq = jnp.floor(hs + jax.random.uniform(kh, hs.shape))
-        else:
-            gq = jnp.round(gs)
-            hq = jnp.round(hs)
-        gq = jnp.clip(gq, -127, 127).astype(jnp.int8)
-        hq = jnp.clip(hq, 0, 127).astype(jnp.int8)
-        # everything downstream sees the dequantized values so leaf stats,
-        # subtraction and split eval are consistent with the int histograms
-        grad = gq.astype(jnp.float32) * g_scale
-        hess = hq.astype(jnp.float32) * h_scale
-        quant_scale = jnp.stack([g_scale, h_scale, jnp.float32(1.0)])
+            g_scale = jnp.maximum(pmax(jnp.max(jnp.abs(grad) * inbag)) / half, 1e-30)
+            h_scale = jnp.maximum(pmax(jnp.max(hess * inbag)) / quantize_bins, 1e-30)
+            gs = grad / g_scale
+            hs = hess / h_scale
+            if stochastic_rounding:
+                if quant_key is None:
+                    quant_key = jax.random.PRNGKey(0)
+                kg, kh = jax.random.split(quant_key)
+                gq = jnp.floor(gs + jax.random.uniform(kg, gs.shape))
+                hq = jnp.floor(hs + jax.random.uniform(kh, hs.shape))
+            else:
+                gq = jnp.round(gs)
+                hq = jnp.round(hs)
+            gq = jnp.clip(gq, -127, 127).astype(jnp.int8)
+            hq = jnp.clip(hq, 0, 127).astype(jnp.int8)
+            # everything downstream sees the dequantized values so leaf stats,
+            # subtraction and split eval are consistent with the int histograms
+            grad = gq.astype(jnp.float32) * g_scale
+            hess = hq.astype(jnp.float32) * h_scale
+            quant_scale = jnp.stack([g_scale, h_scale, jnp.float32(1.0)])
 
     hist_bins = bins if efb_bins is None else efb_bins
 
@@ -279,16 +284,15 @@ def _grow_fast_impl(
                 # same measured strategy selection as the float path: XLA's
                 # fused one-hot (here int8 x int8 -> int32) wins at narrow
                 # bins; exactness is identical
-                hi = histogram_onehot_multi_quantized(
+                h = histogram_onehot_multi_quantized(
                     hist_bins, gq, hq, row_mask & (leaf_slot >= 0),
                     jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 )
             else:
-                hi = histogram_multi_quantized(
+                h = histogram_multi_quantized(
                     hist_bins, gq, hq, row_mask & (leaf_slot >= 0),
                     jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 )
-            h = unbundle(hi).astype(jnp.float32) * quant_scale[:, None, None]
         elif use_pallas and num_bins <= 64:
             # measured strategy selection (ops/histogram.py docstring): at
             # narrow bins XLA's fused one-hot einsum beats the Pallas kernel
@@ -297,14 +301,12 @@ def _grow_fast_impl(
                 jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 precision=hist_precision,
             )
-            h = unbundle(h)
         elif use_pallas:
             h = histogram_multi(
                 hist_bins, grad, hess, row_mask & (leaf_slot >= 0),
                 jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 precision=hist_precision,
             )
-            h = unbundle(h)
         else:
             # CPU/test fallback: per-slot masked scatter histograms (uses the
             # dequantized grad/hess, so results match the int path's scaling)
@@ -312,13 +314,19 @@ def _grow_fast_impl(
                 m = row_mask & (leaf_slot == s)
                 return histogram(hist_bins, grad, hess, m.astype(jnp.float32),
                                  num_bins, strategy="scatter")
-            h = unbundle(jax.vmap(one)(jnp.arange(tile, dtype=jnp.int32)))
-        return psum(h)
+            with phase_scope("hist.kernel"):
+                h = jax.vmap(one)(jnp.arange(tile, dtype=jnp.int32))
+        with phase_scope("hist.unpack"):
+            h = unbundle(h)
+            if use_pallas and quantize_bins:  # int32 sums back to floats
+                h = h.astype(jnp.float32) * quant_scale[:, None, None]
+            return psum(h)
 
     # ---- root ----
-    hist0 = multi_hist(jnp.where(row_mask, 0, -1).astype(jnp.int32), 1)[0]
-    sum0 = jnp.sum(hist0[:, 0, :], axis=1)  # totals from feature 0: (3,)
-    g0, h0, c0 = sum0[0], sum0[1], sum0[2]
+    with phase_scope("grow.root"):
+        hist0 = multi_hist(jnp.where(row_mask, 0, -1).astype(jnp.int32), 1)[0]
+        sum0 = jnp.sum(hist0[:, 0, :], axis=1)  # totals from feature 0: (3,)
+        g0, h0, c0 = sum0[0], sum0[1], sum0[2]
 
     tree0 = TreeArrays(
         num_leaves=jnp.asarray(1, jnp.int32),
@@ -361,60 +369,63 @@ def _grow_fast_impl(
         lazy_counts0 = jnp.einsum(
             "n,nf->f", row_mask.astype(jnp.float32),
             (~lazy_used0).astype(jnp.float32))
-    best0 = _set_best(
-        _empty_best(L, num_bins), jnp.asarray(0),
-        jax.tree.map(
-            lambda a: a[0],
-            _batched_best(
-                hist0[None], jnp.asarray([g0]), jnp.asarray([h0]),
-                jnp.asarray([c0]), num_bins_per_feature,
-                missing_bin_per_feature, params, feature_mask,
-                categorical_mask, monotone_constraints, interaction_sets,
-                jnp.asarray([-jnp.inf], jnp.float32),
-                jnp.asarray([jnp.inf], jnp.float32),
-                used0[:1] if interaction_sets is not None else None,
-                jnp.asarray([0], jnp.int32), rng_key,
-                depth=jnp.asarray([0.0], jnp.float32),
-                parent_out=jnp.asarray([leaf_out0]),
-                cegb_pen=cegb_pen0,
-                feature_contri=feature_contri,
-                lazy_pen=cegb_lazy_penalty if use_lazy else None,
-                lazy_counts=lazy_counts0[None] if use_lazy else None,
+    with phase_scope("grow.split_search"):
+        best0 = _set_best(
+            _empty_best(L, num_bins), jnp.asarray(0),
+            jax.tree.map(
+                lambda a: a[0],
+                _batched_best(
+                    hist0[None], jnp.asarray([g0]), jnp.asarray([h0]),
+                    jnp.asarray([c0]), num_bins_per_feature,
+                    missing_bin_per_feature, params, feature_mask,
+                    categorical_mask, monotone_constraints, interaction_sets,
+                    jnp.asarray([-jnp.inf], jnp.float32),
+                    jnp.asarray([jnp.inf], jnp.float32),
+                    used0[:1] if interaction_sets is not None else None,
+                    jnp.asarray([0], jnp.int32), rng_key,
+                    depth=jnp.asarray([0.0], jnp.float32),
+                    parent_out=jnp.asarray([leaf_out0]),
+                    cegb_pen=cegb_pen0,
+                    feature_contri=feature_contri,
+                    lazy_pen=cegb_lazy_penalty if use_lazy else None,
+                    lazy_counts=lazy_counts0[None] if use_lazy else None,
+                ),
             ),
-        ),
-    )
+        )
 
-    state = FastState(
-        leaf_id=jnp.zeros((n,), jnp.int32),
-        hist=jnp.zeros((L, 3, f, num_bins), jnp.float32).at[0].set(hist0),
-        best=best0,
-        leaf_sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
-        leaf_sum_h=jnp.zeros((L,), jnp.float32).at[0].set(h0),
-        leaf_count=jnp.zeros((L,), jnp.float32).at[0].set(c0),
-        leaf_depth=jnp.zeros((L,), jnp.int32),
-        leaf_parent=jnp.full((L,), -1, jnp.int32),
-        leaf_side=jnp.zeros((L,), jnp.int32),
-        num_leaves_cur=jnp.asarray(1, jnp.int32),
-        leaf_out_lo=jnp.full((L,), -jnp.inf, jnp.float32),
-        leaf_out_hi=jnp.full((L,), jnp.inf, jnp.float32),
-        leaf_out=jnp.zeros((L,), jnp.float32).at[0].set(leaf_out0),
-        cegb_used=cegb_used0,
-        used_features=used0,
-        fresh=jnp.zeros((L,), bool),
-        small_slot=jnp.full((L,), -1, jnp.int32),
-        slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
-        slot_right=jnp.full((leaf_tile,), -1, jnp.int32),
-        slot_small_left=jnp.zeros((leaf_tile,), bool),
-        progress=jnp.asarray(True),
-        tree=tree0,
-        anc=(jnp.zeros((L, L - 1), bool) if use_intermediate
-             else jnp.zeros((), bool)),
-        aside=(jnp.zeros((L, L - 1), bool) if use_intermediate
-               else jnp.zeros((), bool)),
-        lazy_used=(lazy_used0 if use_lazy else jnp.zeros((), bool)),
-        lazy_counts=(jnp.zeros((L, f), jnp.float32).at[0].set(lazy_counts0)
-                     if use_lazy else jnp.zeros((), bool)),
-    )
+    with phase_scope("grow.root"):  # the state the loop carries
+        state = FastState(
+            leaf_id=jnp.zeros((n,), jnp.int32),
+            hist=jnp.zeros((L, 3, f, num_bins), jnp.float32).at[0].set(hist0),
+            best=best0,
+            leaf_sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
+            leaf_sum_h=jnp.zeros((L,), jnp.float32).at[0].set(h0),
+            leaf_count=jnp.zeros((L,), jnp.float32).at[0].set(c0),
+            leaf_depth=jnp.zeros((L,), jnp.int32),
+            leaf_parent=jnp.full((L,), -1, jnp.int32),
+            leaf_side=jnp.zeros((L,), jnp.int32),
+            num_leaves_cur=jnp.asarray(1, jnp.int32),
+            leaf_out_lo=jnp.full((L,), -jnp.inf, jnp.float32),
+            leaf_out_hi=jnp.full((L,), jnp.inf, jnp.float32),
+            leaf_out=jnp.zeros((L,), jnp.float32).at[0].set(leaf_out0),
+            cegb_used=cegb_used0,
+            used_features=used0,
+            fresh=jnp.zeros((L,), bool),
+            small_slot=jnp.full((L,), -1, jnp.int32),
+            slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
+            slot_right=jnp.full((leaf_tile,), -1, jnp.int32),
+            slot_small_left=jnp.zeros((leaf_tile,), bool),
+            progress=jnp.asarray(True),
+            hist_passes=jnp.asarray(1, jnp.int32),  # the root's
+            tree=tree0,
+            anc=(jnp.zeros((L, L - 1), bool) if use_intermediate
+                 else jnp.zeros((), bool)),
+            aside=(jnp.zeros((L, L - 1), bool) if use_intermediate
+                   else jnp.zeros((), bool)),
+            lazy_used=(lazy_used0 if use_lazy else jnp.zeros((), bool)),
+            lazy_counts=(jnp.zeros((L, f), jnp.float32).at[0].set(lazy_counts0)
+                         if use_lazy else jnp.zeros((), bool)),
+        )
 
     eps = KMIN_SCORE / 2
 
@@ -729,6 +740,7 @@ def _grow_fast_impl(
             slot_right=slot_right,
             slot_small_left=slot_small_left,
             progress=k_acc > 0,
+            hist_passes=state.hist_passes,
             tree=tree,
             anc=anc,
             aside=aside,
@@ -740,110 +752,119 @@ def _grow_fast_impl(
         # ---------- phase 2: one pass for all small children ----------
         # slot per row (small_slot[leaf_id]) via a static slot loop — small
         # table gathers at (N,) lower poorly on TPU (see partition above)
-        lid = state.leaf_id
-        leaf_slot = jnp.full((n,), -1, jnp.int32)
-        for r in range(leaf_tile):
-            has_r = state.small_slot == r  # (L,)
-            leaf_r = jnp.argmax(has_r).astype(jnp.int32)
-            exists = jnp.any(has_r)
-            leaf_slot = jnp.where(exists & (lid == leaf_r), r, leaf_slot)
+        with phase_scope("grow.slots"):
+            lid = state.leaf_id
+            leaf_slot = jnp.full((n,), -1, jnp.int32)
+            for r in range(leaf_tile):
+                has_r = state.small_slot == r  # (L,)
+                leaf_r = jnp.argmax(has_r).astype(jnp.int32)
+                exists = jnp.any(has_r)
+                leaf_slot = jnp.where(exists & (lid == leaf_r), r, leaf_slot)
         fresh_hists = multi_hist(leaf_slot, leaf_tile)  # (leaf_tile, 3, F, B)
-        idx = jnp.arange(L, dtype=jnp.int32)
-        # COMPACT sibling recovery (round 5): parent hists live in the left
-        # children's slots; gather the <= tile parents, subtract, and
-        # scatter both children once — O(tile) state traffic instead of the
-        # full-(L,...) scatter/subtract/where chain (measured 57 ms/round
-        # at Epsilon shape; benchmarks/probe_r5_fixed.py)
-        active = state.slot_left >= 0  # (tile,)
-        sl = jnp.clip(state.slot_left, 0, L - 1)
-        sr = jnp.clip(state.slot_right, 0, L - 1)
-        parent_hists = state.hist[sl]  # (tile, 3, F, B)
-        big_hists = parent_hists - fresh_hists
-        sml = state.slot_small_left[:, None, None, None]
-        left_hists = jnp.where(sml, fresh_hists, big_hists)
-        right_hists = jnp.where(sml, big_hists, fresh_hists)
-        lpos = jnp.where(active, sl, 2 * L)
-        rpos = jnp.where(active, sr, 2 * L)
-        hist = state.hist.at[lpos].set(left_hists, mode="drop").at[rpos].set(
-            right_hists, mode="drop")
+        with phase_scope("grow.sibling"):
+            idx = jnp.arange(L, dtype=jnp.int32)
+            # COMPACT sibling recovery (round 5): parent hists live in the left
+            # children's slots; gather the <= tile parents, subtract, and
+            # scatter both children once — O(tile) state traffic instead of the
+            # full-(L,...) scatter/subtract/where chain (measured 57 ms/round
+            # at Epsilon shape; benchmarks/probe_r5_fixed.py)
+            active = state.slot_left >= 0  # (tile,)
+            sl = jnp.clip(state.slot_left, 0, L - 1)
+            sr = jnp.clip(state.slot_right, 0, L - 1)
+            parent_hists = state.hist[sl]  # (tile, 3, F, B)
+            big_hists = parent_hists - fresh_hists
+            sml = state.slot_small_left[:, None, None, None]
+            left_hists = jnp.where(sml, fresh_hists, big_hists)
+            right_hists = jnp.where(sml, big_hists, fresh_hists)
+            lpos = jnp.where(active, sl, 2 * L)
+            rpos = jnp.where(active, sr, 2 * L)
+            hist = state.hist.at[lpos].set(left_hists, mode="drop").at[rpos].set(
+                right_hists, mode="drop")
 
         # ---------- phase 3: evaluate fresh leaves (one vmapped search) ----------
-        node_ids = jnp.clip(state.leaf_parent, 0, None) * 2 + state.leaf_side + 1
-        cegb_pen = (
-            jnp.where(state.cegb_used, 0.0, cegb_feature_penalty)
-            if cegb_feature_penalty is not None else None
-        )
-        if use_intermediate:
-            # bounds of EVERY leaf may have moved this round (their opposite
-            # subtrees changed), so cached best splits are stale — re-search
-            # all live leaves (reference: IntermediateLeafConstraints'
-            # leaves_to_update set; recompute-all is the vectorized exact
-            # equivalent, same trade as the strict grower makes)
+        with phase_scope("grow.split_search"):
+            node_ids = jnp.clip(state.leaf_parent, 0, None) * 2 + state.leaf_side + 1
+            cegb_pen = (
+                jnp.where(state.cegb_used, 0.0, cegb_feature_penalty)
+                if cegb_feature_penalty is not None else None
+            )
+            if use_intermediate:
+                # bounds of EVERY leaf may have moved this round (their opposite
+                # subtrees changed), so cached best splits are stale — re-search
+                # all live leaves (reference: IntermediateLeafConstraints'
+                # leaves_to_update set; recompute-all is the vectorized exact
+                # equivalent, same trade as the strict grower makes)
+                bb = _batched_best(
+                    hist, state.leaf_sum_g, state.leaf_sum_h, state.leaf_count,
+                    num_bins_per_feature, missing_bin_per_feature, params,
+                    feature_mask, categorical_mask, monotone_constraints,
+                    interaction_sets, state.leaf_out_lo, state.leaf_out_hi,
+                    state.used_features if interaction_sets is not None else None,
+                    node_ids, rng_key,
+                    depth=state.leaf_depth, parent_out=state.leaf_out,
+                    cegb_pen=cegb_pen,
+                    feature_contri=feature_contri,
+                    lazy_pen=cegb_lazy_penalty if use_lazy else None,
+                    lazy_counts=state.lazy_counts if use_lazy else None,
+                )
+                live = idx < state.num_leaves_cur
+                best = bb._replace(gain=jnp.where(live, bb.gain, KMIN_SCORE))
+                return state._replace(
+                    hist=hist, best=best,
+                    hist_passes=state.hist_passes + 1,
+                    fresh=jnp.zeros((L,), bool),
+                    small_slot=jnp.full((L,), -1, jnp.int32),
+                    slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
+                    slot_right=jnp.full((leaf_tile,), -1, jnp.int32),
+                    slot_small_left=jnp.zeros((leaf_tile,), bool))
+            # only the fresh children need evaluation, and their hists are
+            # ALREADY compact (left_hists/right_hists above): feed the search
+            # directly instead of re-gathering (2*tile, 3, F, B) from the state
+            # (that gather measured 18 ms/round at Epsilon shape)
+            cand = jnp.concatenate([sl, sr])  # (2*tile,) candidate leaf ids
+            cand_ok = jnp.concatenate([active, active])
+            cand_hists = jnp.concatenate([left_hists, right_hists], axis=0)
+            ci = jnp.where(cand_ok, cand, 0)
             bb = _batched_best(
-                hist, state.leaf_sum_g, state.leaf_sum_h, state.leaf_count,
+                cand_hists, state.leaf_sum_g[ci], state.leaf_sum_h[ci],
+                state.leaf_count[ci],
                 num_bins_per_feature, missing_bin_per_feature, params,
                 feature_mask, categorical_mask, monotone_constraints,
-                interaction_sets, state.leaf_out_lo, state.leaf_out_hi,
-                state.used_features if interaction_sets is not None else None,
-                node_ids, rng_key,
-                depth=state.leaf_depth, parent_out=state.leaf_out,
+                interaction_sets, state.leaf_out_lo[ci], state.leaf_out_hi[ci],
+                state.used_features[ci] if interaction_sets is not None else None,
+                node_ids[ci], rng_key,
+                depth=state.leaf_depth[ci], parent_out=state.leaf_out[ci],
                 cegb_pen=cegb_pen,
                 feature_contri=feature_contri,
                 lazy_pen=cegb_lazy_penalty if use_lazy else None,
-                lazy_counts=state.lazy_counts if use_lazy else None,
+                lazy_counts=state.lazy_counts[ci] if use_lazy else None,
             )
-            live = idx < state.num_leaves_cur
-            best = bb._replace(gain=jnp.where(live, bb.gain, KMIN_SCORE))
+            scatter_pos = jnp.where(cand_ok, cand, 2 * L)  # drop inactive slots
+
+            def merge(old, new):
+                return old.at[scatter_pos].set(new, mode="drop")
+
+            best = BestSplit(*[merge(o, nw) for o, nw in zip(state.best, bb)])
             return state._replace(
                 hist=hist, best=best,
+                hist_passes=state.hist_passes + 1,
                 fresh=jnp.zeros((L,), bool),
                 small_slot=jnp.full((L,), -1, jnp.int32),
                 slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
                 slot_right=jnp.full((leaf_tile,), -1, jnp.int32),
                 slot_small_left=jnp.zeros((leaf_tile,), bool))
-        # only the fresh children need evaluation, and their hists are
-        # ALREADY compact (left_hists/right_hists above): feed the search
-        # directly instead of re-gathering (2*tile, 3, F, B) from the state
-        # (that gather measured 18 ms/round at Epsilon shape)
-        cand = jnp.concatenate([sl, sr])  # (2*tile,) candidate leaf ids
-        cand_ok = jnp.concatenate([active, active])
-        cand_hists = jnp.concatenate([left_hists, right_hists], axis=0)
-        ci = jnp.where(cand_ok, cand, 0)
-        bb = _batched_best(
-            cand_hists, state.leaf_sum_g[ci], state.leaf_sum_h[ci],
-            state.leaf_count[ci],
-            num_bins_per_feature, missing_bin_per_feature, params,
-            feature_mask, categorical_mask, monotone_constraints,
-            interaction_sets, state.leaf_out_lo[ci], state.leaf_out_hi[ci],
-            state.used_features[ci] if interaction_sets is not None else None,
-            node_ids[ci], rng_key,
-            depth=state.leaf_depth[ci], parent_out=state.leaf_out[ci],
-            cegb_pen=cegb_pen,
-            feature_contri=feature_contri,
-            lazy_pen=cegb_lazy_penalty if use_lazy else None,
-            lazy_counts=state.lazy_counts[ci] if use_lazy else None,
-        )
-        scatter_pos = jnp.where(cand_ok, cand, 2 * L)  # drop inactive slots
-
-        def merge(old, new):
-            return old.at[scatter_pos].set(new, mode="drop")
-
-        best = BestSplit(*[merge(o, nw) for o, nw in zip(state.best, bb)])
-        return state._replace(
-            hist=hist, best=best,
-            fresh=jnp.zeros((L,), bool),
-            small_slot=jnp.full((L,), -1, jnp.int32),
-            slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
-            slot_right=jnp.full((leaf_tile,), -1, jnp.int32),
-            slot_small_left=jnp.zeros((leaf_tile,), bool))
 
     def cond(state: FastState):
         more_leaves = state.num_leaves_cur < L
         any_gain = jnp.max(state.best.gain) > eps
         return state.progress & more_leaves & any_gain
 
+    def partition(state: FastState, forced=None) -> FastState:
+        with phase_scope("grow.partition"):
+            return round_body(state, forced)
+
     def body(state: FastState):
-        state = round_body(state)
+        state = partition(state)
         return jax.lax.cond(
             state.progress, hist_and_eval, lambda st: st, state
         )
@@ -883,7 +904,7 @@ def _grow_fast_impl(
             fl, s_f, valid = forced_candidate(state, i)
             valid = valid & forced_ok
             forced_ok = valid
-            state = round_body(state, forced=(fl, s_f, valid))
+            state = partition(state, forced=(fl, s_f, valid))
             state = jax.lax.cond(state.progress, hist_and_eval,
                                  lambda st: st, state)
         # a rejected forced entry leaves progress=False; free growth still runs
@@ -891,37 +912,39 @@ def _grow_fast_impl(
 
     state = jax.lax.while_loop(cond, body, state)
 
-    if quant_renew and quantize_bins and not use_intermediate:
-        # recompute leaf outputs from the TRUE f32 gradients (reference:
-        # GBDT::Train -> RenewIntGradTreeOutput after quantized growth)
-        mrow = row_mask.astype(jnp.float32)
-        Gt = psum(jnp.zeros((L,), jnp.float32).at[state.leaf_id].add(grad_true * mrow))
-        Ht = psum(jnp.zeros((L,), jnp.float32).at[state.leaf_id].add(hess_true * mrow))
-        leaf_value = leaf_output(Gt, Ht, params)
-        if monotone_constraints is not None:
-            leaf_value = jnp.clip(leaf_value, state.leaf_out_lo, state.leaf_out_hi)
-    elif params.path_smooth > 0 or use_intermediate:
-        # smoothed / monotone-clipped AT CREATION.  Under intermediate
-        # bounds this is required for correctness: bounds keep evolving
-        # after a leaf is created, and re-clipping recomputed outputs to
-        # the FINAL bounds can cross a monotone split (see treegrow.py) —
-        # which is also why quantized renewal is skipped above when
-        # intermediate is active.
-        leaf_value = state.leaf_out
-    else:
-        leaf_value = leaf_output(state.leaf_sum_g, state.leaf_sum_h, params)
-        if monotone_constraints is not None:
-            leaf_value = jnp.clip(leaf_value, state.leaf_out_lo, state.leaf_out_hi)
-    active = jnp.arange(L, dtype=jnp.int32) < state.num_leaves_cur
-    tree = state.tree._replace(
-        num_leaves=state.num_leaves_cur,
-        leaf_value=jnp.where(active, leaf_value, 0.0),
-        leaf_weight=jnp.where(active, state.leaf_sum_h, 0.0),
-        leaf_count=jnp.where(active, state.leaf_count, 0.0),
-        leaf_sum_g=jnp.where(active, state.leaf_sum_g, 0.0),
-        leaf_depth=state.leaf_depth,
-        path_features=(state.used_features if track_path else None),
-    )
+    with phase_scope("grow.leaf_values"):
+        if quant_renew and quantize_bins and not use_intermediate:
+            # recompute leaf outputs from the TRUE f32 gradients (reference:
+            # GBDT::Train -> RenewIntGradTreeOutput after quantized growth)
+            mrow = row_mask.astype(jnp.float32)
+            Gt = psum(jnp.zeros((L,), jnp.float32).at[state.leaf_id].add(grad_true * mrow))
+            Ht = psum(jnp.zeros((L,), jnp.float32).at[state.leaf_id].add(hess_true * mrow))
+            leaf_value = leaf_output(Gt, Ht, params)
+            if monotone_constraints is not None:
+                leaf_value = jnp.clip(leaf_value, state.leaf_out_lo, state.leaf_out_hi)
+        elif params.path_smooth > 0 or use_intermediate:
+            # smoothed / monotone-clipped AT CREATION.  Under intermediate
+            # bounds this is required for correctness: bounds keep evolving
+            # after a leaf is created, and re-clipping recomputed outputs to
+            # the FINAL bounds can cross a monotone split (see treegrow.py) —
+            # which is also why quantized renewal is skipped above when
+            # intermediate is active.
+            leaf_value = state.leaf_out
+        else:
+            leaf_value = leaf_output(state.leaf_sum_g, state.leaf_sum_h, params)
+            if monotone_constraints is not None:
+                leaf_value = jnp.clip(leaf_value, state.leaf_out_lo, state.leaf_out_hi)
+        active = jnp.arange(L, dtype=jnp.int32) < state.num_leaves_cur
+        tree = state.tree._replace(
+            num_leaves=state.num_leaves_cur,
+            leaf_value=jnp.where(active, leaf_value, 0.0),
+            leaf_weight=jnp.where(active, state.leaf_sum_h, 0.0),
+            leaf_count=jnp.where(active, state.leaf_count, 0.0),
+            leaf_sum_g=jnp.where(active, state.leaf_sum_g, 0.0),
+            leaf_depth=state.leaf_depth,
+            path_features=(state.used_features if track_path else None),
+            hist_passes=state.hist_passes,
+        )
     if use_lazy:
         # hand the cross-tree charge state back (reference: the
         # feature_used_in_data bitset persists across trees)

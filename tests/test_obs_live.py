@@ -287,8 +287,8 @@ def test_span_exception_close_and_mismatched_exit():
 
 def test_annotation_factory_mirrors_spans():
     """The jax.profiler bridge contract (utils/profiling.py installs the
-    real one behind LGBMTPU_JAX_PROFILER=1): the factory's context
-    manager wraps every context-manager span body."""
+    one that mirrors every span behind LGBMTPU_JAX_PROFILER=1): the
+    factory's context manager wraps the context-manager span's body."""
     entered, exited = [], []
 
     class _Cm:
@@ -314,6 +314,49 @@ def test_annotation_factory_mirrors_spans():
     assert isinstance(cm, jax.profiler.StepTraceAnnotation)
     cm2 = _jax_annotation_factory("train", {})
     assert isinstance(cm2, jax.profiler.TraceAnnotation)
+
+
+def test_boost_round_opens_a_step_annotation_without_a_switch(monkeypatch):
+    """The bridge utils/profiling.py installs when it is imported, with
+    LGBMTPU_JAX_PROFILER unset: a step per ``boost_round`` (so any profiler
+    trace has one per tree), nothing for the other spans."""
+    import jax
+
+    from lightgbm_tpu.utils import profiling
+
+    monkeypatch.delenv("LGBMTPU_JAX_PROFILER", raising=False)
+    step = profiling._step_annotation_factory("boost_round", {"iteration": 3})
+    assert isinstance(step, jax.profiler.StepTraceAnnotation)
+    assert profiling._step_annotation_factory("train", {}) is None
+
+    opened = []
+
+    class _Step:
+        def __init__(self, name, **kw):
+            opened.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            opened.append("closed")
+
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Step)
+    profiling.install_step_annotations()
+    rng = np.random.RandomState(5)
+    x = rng.randn(600, 4)
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 7,
+                       "verbosity": -1},
+                      lgb.Dataset(x, label=(x[:, 0] > 0).astype(float)))
+    bst.update()
+    bst.update()
+    assert opened == [("boost_round", {"step_num": 0}), "closed",
+                      ("boost_round", {"step_num": 1}), "closed"]
+    # a span the bridge does not mirror is recorded all the same
+    with obs_trace.span("not_a_step") as sp:
+        assert sp._annotation is None
+    assert [s["name"] for s in obs_trace.spans()].count("boost_round") == 2
+    assert obs_trace.spans("not_a_step")
 
 
 # ---------------------------------------------------------------------------
