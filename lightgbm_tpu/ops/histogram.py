@@ -93,6 +93,18 @@ def histogram_onehot_matmul(
     return hist
 
 
+def _leaf_lanes(base: jnp.ndarray, leaf_id: jnp.ndarray, leaf_base: int,
+                num_leaves_tile: int) -> jnp.ndarray:
+    """(N, ncl) channels -> the einsum routes' (N, L_tile * ncl) payload:
+    lane l*ncl + c holds channel c of the rows in leaf leaf_base + l."""
+    lid = leaf_id.astype(jnp.int32) - leaf_base
+    onehot = (
+        lid[:, None] == jnp.arange(num_leaves_tile, dtype=jnp.int32)[None, :]
+    ).astype(base.dtype)  # (N, L_tile)
+    return (onehot[:, :, None] * base[:, None, :]).reshape(
+        base.shape[0], num_leaves_tile * base.shape[1])
+
+
 def histogram_onehot_multi(
     bins: jnp.ndarray,  # (N, F) int
     grad: jnp.ndarray,
@@ -133,14 +145,7 @@ def histogram_onehot_multi(
         else:
             raise ValueError(precision)
         ncl = base.shape[-1]
-        lid = leaf_id.astype(jnp.int32) - leaf_base
-        onehot_l = (
-            lid[:, None]
-            == jnp.arange(num_leaves_tile, dtype=jnp.int32)[None, :]
-        ).astype(jnp.float32)  # (N, L_tile)
-        payload = (onehot_l[:, :, None] * base[:, None, :]).reshape(
-            n, num_leaves_tile * ncl
-        )
+        payload = _leaf_lanes(base, leaf_id, leaf_base, num_leaves_tile)
     c = payload.shape[1]
 
     pad = (-n) % row_tile
@@ -201,13 +206,15 @@ def histogram_onehot_multi_quantized(
     for the float path (measured, see histogram_onehot_multi) and the same
     selection applies to the int path — int8 x int8 dots accumulate in
     int32 on the MXU, so exactness is preserved."""
-    from .hist_pallas import quantized_leaf_payload
-
     n, f = bins.shape
     ncl = 3
     with phase_scope("hist.payload"):
-        payload = quantized_leaf_payload(grad_q, hess_q, mask, leaf_id,
-                                         leaf_base, num_leaves_tile)
+        m8 = mask.astype(jnp.int8)
+        base = jnp.stack(
+            [grad_q.astype(jnp.int8) * m8, hess_q.astype(jnp.int8) * m8, m8],
+            axis=-1,
+        )  # (N, 3)
+        payload = _leaf_lanes(base, leaf_id, leaf_base, num_leaves_tile)
     c = payload.shape[1]
 
     pad = (-n) % row_tile
@@ -246,6 +253,7 @@ def histogram_multi(
     num_bins: int,
     *,
     precision: str = "f32",
+    base: jnp.ndarray = None,  # hist_pallas.payload_base, built once a tree
 ) -> jnp.ndarray:
     """Multi-leaf histogram DISPATCHER for the Pallas-eligible growers ->
     (L_tile, 3, F, B).
@@ -264,7 +272,7 @@ def histogram_multi(
 
         return histogram_pallas_multi(
             bins, grad, hess, mask, leaf_id, leaf_base, num_leaves_tile,
-            num_bins, precision=precision)
+            num_bins, precision=precision, base=base)
 
     return _degrade.run_with_fallback(
         _degrade.HIST, _pallas,
@@ -283,6 +291,8 @@ def histogram_multi_quantized(
     leaf_base: int,
     num_leaves_tile: int,
     num_bins: int,
+    *,
+    base: jnp.ndarray = None,  # hist_pallas.payload_base_quantized
 ) -> jnp.ndarray:
     """Quantized sibling of :func:`histogram_multi` — same
     catch-once/degrade-forever dispatch over the int8 kernels."""
@@ -293,7 +303,7 @@ def histogram_multi_quantized(
 
         return histogram_pallas_multi_quantized(
             bins, grad_q, hess_q, mask, leaf_id, leaf_base,
-            num_leaves_tile, num_bins)
+            num_leaves_tile, num_bins, base=base)
 
     return _degrade.run_with_fallback(
         _degrade.HIST, _pallas,

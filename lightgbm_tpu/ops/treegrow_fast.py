@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from ..utils import degrade as _degrade
 from ..utils.profiling import phase_scope
+from .hist_pallas import payload_base, payload_base_quantized
 from .histogram import (histogram, histogram_multi, histogram_multi_quantized,
                         histogram_onehot_multi,
                         histogram_onehot_multi_quantized, unbundle_hists)
@@ -277,6 +278,17 @@ def _grow_fast_impl(
             return h
         return unbundle_hists(h, efb_gather, efb_default, f, num_bins)
 
+    # the Pallas kernel's per-tree input (ops/hist_pallas.py): the rows'
+    # channels depend on grad, hess and row_mask alone, so they are laid out
+    # once here and every pass of the tree, the root's too, takes the same
+    # array.  XLA does not hoist a loop-invariant N-sized build by itself.
+    hist_base = None
+    if use_pallas and num_bins > 64:
+        with phase_scope("hist.payload"):
+            hist_base = (payload_base_quantized(gq, hq, row_mask)
+                         if quantize_bins else
+                         payload_base(grad, hess, row_mask, hist_precision))
+
     def multi_hist(leaf_slot, tile):
         """(N,)-slot -> (tile, 3, F, B) f32: per-slot histograms, one pass."""
         if use_pallas and quantize_bins:
@@ -292,6 +304,7 @@ def _grow_fast_impl(
                 h = histogram_multi_quantized(
                     hist_bins, gq, hq, row_mask & (leaf_slot >= 0),
                     jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
+                    base=hist_base,
                 )
         elif use_pallas and num_bins <= 64:
             # measured strategy selection (ops/histogram.py docstring): at
@@ -305,7 +318,7 @@ def _grow_fast_impl(
             h = histogram_multi(
                 hist_bins, grad, hess, row_mask & (leaf_slot >= 0),
                 jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
-                precision=hist_precision,
+                precision=hist_precision, base=hist_base,
             )
         else:
             # CPU/test fallback: per-slot masked scatter histograms (uses the

@@ -42,8 +42,9 @@ DEVICE_PHASES = (
     "grow.root",  # the root's full pass and totals
     "grow.partition",  # admission, split apply, row routing (leaf_id)
     "grow.slots",  # slot per row for the pass
-    "hist.payload",  # mask, bf16x2 split, one-hot x base, reshape, lane pad
-    "hist.rowpad",  # bins and payload padded to the row tile
+    "hist.payload",  # the kernel's per-tree base (once a tree); the einsum
+    # route's mask, split, one-hot x base, reshape (every pass)
+    "hist.rowpad",  # the einsum route's pads to its row tile
     "hist.kernel",  # the Pallas kernel (the one-hot einsum at <= 64 bins)
     "hist.unpack",  # slice, hi + lo, transpose; unbundle and psum
     "grow.sibling",  # parent gather, subtraction, scatter into state.hist

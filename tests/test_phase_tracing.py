@@ -49,30 +49,76 @@ def _grow(bins, grad, hess, **kw):
 # 1. the catalogue reaches the HLO
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def grower_op_names():
-    """op_names of the rounds grower lowered for the TPU at toy size: the
-    Pallas path (payload, row pad, kernel, unpack), which the CPU backend
-    does not take.  Lowering only: nothing compiles, nothing runs."""
-    n, f = 1500, 5
+TOY_N, TOY_F, TOY_TILE = 1500, 5, 4
+
+
+def _lowered_grower_hlo(num_bins):
+    """HLO text of the rounds grower lowered for the TPU at toy size: the
+    paths the CPU backend does not take.  Lowering only: nothing compiles,
+    nothing runs."""
+    n, f = TOY_N, TOY_F
     s = jax.ShapeDtypeStruct
     args = (s((n, f), jnp.int16), s((n,), jnp.float32), s((n,), jnp.float32),
             s((n,), jnp.bool_), s((n,), jnp.float32), s((f,), jnp.bool_),
             s((f,), jnp.int32), s((f,), jnp.int32))
     lowered = _grow_fast_impl.trace(
-        *args, num_leaves=8, num_bins=255, params=SplitParams(), leaf_tile=4,
-        hist_precision="f32", use_pallas=True).lower(
+        *args, num_leaves=8, num_bins=num_bins, params=SplitParams(),
+        leaf_tile=TOY_TILE, hist_precision="f32", use_pallas=True).lower(
             lowering_platforms=("tpu",))
-    text = lowered.as_text(dialect="hlo", debug_info=True)
-    return set(re.findall(r'op_name="([^"]*)"', text))
+    return lowered.as_text(dialect="hlo", debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def grower_hlo():
+    """255 bins: the Pallas route (base, kernel, unpack)."""
+    return _lowered_grower_hlo(255)
+
+
+@pytest.fixture(scope="module")
+def grower_op_names(grower_hlo):
+    return set(re.findall(r'op_name="([^"]*)"', grower_hlo))
+
+
+@pytest.fixture(scope="module")
+def einsum_grower_op_names():
+    """63 bins: the XLA einsum route, which still builds its payload and
+    pads its rows every pass (``hist.rowpad``'s only home)."""
+    return set(re.findall(r'op_name="([^"]*)"', _lowered_grower_hlo(63)))
 
 
 @pytest.mark.parametrize("phase", GROWER_PHASES)
-def test_every_grower_phase_is_a_scope_in_the_lowered_hlo(grower_op_names,
-                                                          phase):
-    hits = [n for n in grower_op_names if phase in n.split("/")]
+def test_every_grower_phase_is_a_scope_in_the_lowered_hlo(
+        grower_op_names, einsum_grower_op_names, phase):
+    names = (einsum_grower_op_names if phase == "hist.rowpad"
+             else grower_op_names)
+    hits = [n for n in names if phase in n.split("/")]
     assert hits, f"no operation of the lowered grower carries {phase}"
     assert all(profiling.phase_of(n) is not None for n in hits)
+
+
+def test_a_pass_of_the_pallas_route_builds_and_pads_nothing_n_sized(
+        grower_hlo):
+    """The kernel takes its inputs as they lie: the base is built once,
+    before the loop, under ``hist.payload``; inside the ``while`` body no
+    operation pads, stacks or reshapes a lane-expanded payload (N x tile x
+    6 elements) and none pads the bins."""
+    ops = []  # (opcode, dtype, elements, op_name)
+    for m in re.finditer(
+            r'= (\w+)\[([\d,]*)\]\S* (\w[\w-]*)\(.*?op_name="([^"]*)"',
+            grower_hlo):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        ops.append((m.group(3), m.group(1), int(np.prod(dims)), m.group(4)))
+    in_loop = [o for o in ops if "/while/body/" in o[3]]
+    assert len(in_loop) > 100
+    expanded = TOY_N * TOY_TILE * 6
+    assert not [o for o in in_loop if o[2] >= expanded
+                and o[0] in ("pad", "concatenate", "reshape")]
+    assert not [o for o in in_loop if o[0] == "pad" and o[2] >= TOY_N]
+    assert not [o for o in ops if "hist.rowpad" in o[3].split("/")]
+    payload = [o for o in ops if "hist.payload" in o[3].split("/")]
+    assert payload and not [o for o in payload if "while" in o[3].split("/")]
+    base = [o for o in payload if o[0] == "concatenate"]
+    assert [o[1:3] for o in base] == [("f32", 8 * TOY_N)]
 
 
 def test_the_kernel_keeps_the_name_the_benchmark_finds_it_by(grower_op_names):
