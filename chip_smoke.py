@@ -132,7 +132,10 @@ def leg_kernels(n_rows: int = 4096, shapes=KERNEL_SHAPES) -> dict:
         h = (np.abs(rng.randn(n_rows)) + 0.1).astype(np.float32)
         gq = rng.randint(-8, 9, size=n_rows).astype(np.int8)
         hq = rng.randint(0, 17, size=n_rows).astype(np.int8)
-        mask = rng.rand(n_rows) < 0.9
+        # nine rows in ten of the first half, one in ten of the second: a
+        # row tile that takes the kernel's dense product and one that packs
+        mask = rng.rand(n_rows) < np.where(
+            np.arange(n_rows) < n_rows // 2, 0.9, 0.1)
         m = mask.astype(np.float64)
 
         tile = recommended_leaf_tile(b, f, leaves)

@@ -303,20 +303,25 @@ class GBDT:
                 tree.apply_shrinkage(shrink)
                 self._models.append(tree)
                 if arrays.hist_passes is not None:
-                    self._count_hist_passes(int(arrays.hist_passes), tree)
+                    self._count_hist_passes(int(arrays.hist_passes),
+                                            int(arrays.hist_blocks), tree)
 
-    def _count_hist_passes(self, passes: int, tree: Tree) -> None:
+    def _count_hist_passes(self, passes: int, blocks: int, tree: Tree) -> None:
         """What the tree's histogram passes read against what the tree
         needed, from arrays the flush has on the host already (no pull on
         the hot path).  Every pass of the rounds grower streams all rows of
         the ``Dataset``; a leaf-wise learner with histogram subtraction
         needs the rows once for the root and then the smaller child of
-        every split."""
+        every split.  What the Pallas kernel put through its one-hot product
+        lies between the two: sub-blocks of ``hist_pallas.SUB_BLOCK`` rows,
+        as many as each row tile's rows in the pass fill (0 from a grower
+        route that does not run the kernel)."""
         n_rows = int(self.train_set.num_data())
         _obs.counter("train_hist_passes_total").inc(passes)
         _obs.counter("train_hist_rows_streamed_total").inc(passes * n_rows)
         _obs.counter("train_hist_rows_needed_total").inc(
             n_rows + tree.smaller_child_rows())
+        _obs.counter("train_hist_blocks_multiplied_total").inc(blocks)
 
     # -- non-finite guard rail (docs/ROBUSTNESS.md) --------------------
     def _guard_accumulate(self, arrays) -> None:

@@ -25,36 +25,61 @@ lane-expanded (N, tile x ncl) payload and row-padded bins were, every pass:
 39.6% of a Higgs tree's device time).  What XLA still does by itself, once
 a tree: it keeps an (N, F) int16 matrix feature-major on the device and
 copies it to the row-major layout this kernel's operand asks for.
+(4) One int32 COUNT per row tile and pass (``pass_counts``): how many of
+the tile's rows have a slot.  It arrives as a prefetched scalar and decides
+what the tile costs.
 
-Measured design notes (in-jit fori_loop probes on a v5e chip, N=1M F=28;
-methodology + full numbers in docs/PERF_NOTES.md):
+The cost of a pass follows the rows in the pass, not N (since PR 29; before,
+every pass multiplied every row, 35 times a 255-leaf tree on 10.5M rows
+where 3.9 passes' worth were needed).  Per row tile: no row in the pass,
+the tile's DMA and nothing else; otherwise the tile's rows of the pass are
+packed to the front in VMEM (a 0/1 place matrix on the MXU moves bins,
+channels and slot) and the one-hot build and product run over whole
+SUB_BLOCK-row sub-blocks of packed rows only; a tile so full that packing
+would cost more takes the dense product over all its rows (``_tile_cost``:
+the root's pass, a one-leaf call, a window of grouped rows).  Above 256
+bins nothing is packed (bfloat16 would not hold the bins on the move):
+every tile with a row in the pass takes the dense product.
 
-* A full-N pass costs ~8-10 ms and is INVARIANT to num_bins, payload
-  lanes, row tile and bins layout — the floor is the per-(tile, feature)
-  dot on this toolchain, NOT the one-hot build.  A hi/lo bin-decomposition
-  variant (8x fewer MXU passes) measured 3x SLOWER; a pure-XLA one-hot
-  einsum (ops/histogram.py::histogram_onehot_multi) beats this kernel at
-  num_bins <= 64 (~3 ms) and loses above it — the grower selects per
-  max_bin.
-* Payload lanes are nearly free up to the 128-lane MXU tile: the (NC, B)
-  output occupies the same MXU tiles for NC in 4..128.  Near-f32 precision
-  therefore costs the same as bf16: the payload is split hi+lo bfloat16
+Measured on a v5e, the kernel alone, ms a pass by the share of rows in the
+pass, rows drawn at random (PERF.md section 6, PR 29; before: 70.5 and
+189.1 at every share):
+
+    share of rows      100%    50%    25%    12%     4%     1%
+    10.5M x 28, 8 x 6  71.3   71.3   42.5   23.3   12.8   12.9
+    400k x 2000, 10x6  190.2  129.1  69.5   35.7   17.1   17.0
+
+What a tile pays before its first sub-block (the rank of its rows, the
+bins as bfloat16) and what a packed sub-block pays beyond its one-hots (a
+(SUB_BLOCK, row tile) place matrix, two small products) both grow with the
+row tile, and the rows a sparse tile rounds up to shrink with it: 2048 is
+where a 255-leaf tree's late passes (2 to 6% of the rows each) came out
+cheapest at 28 features, and no worse than 1024 or 4096 at 128.
+
+Design notes (*log*: in-jit fori_loop probes at N=1M F=28 over the remote
+link, before PR 26; methodology + numbers in docs/PERF_NOTES.md):
+
+* The dense product's cost per row did not change with num_bins (64 vs
+  256), payload lanes (8 vs 48), row tile (1024-8192) or bins layout.  A
+  hi/lo bin-decomposition variant (8x fewer MXU passes) measured 3x SLOWER;
+  a pure-XLA one-hot einsum (ops/histogram.py::histogram_onehot_multi)
+  beats this kernel at num_bins <= 64 and loses above it — the grower
+  selects per max_bin.
+* Payload lanes up to ~64 cost the dense product nothing, so near-f32
+  precision costs the same as bf16: the payload is split hi+lo bfloat16
   (bf16x2) into two channels and recombined after accumulation.  hi is
   exact in bf16 (cut on the bit pattern, ``_split_bf16x2``: a conversion to
   bfloat16 and back is a no-op to XLA on the TPU under its default flags);
   lo is rounded to bf16 in the kernel, so products carry ~16-17 mantissa
   bits (vs 8 for plain bf16, 24 for true f32) and accumulation is f32 —
   between the reference's float-hist and double-hist modes in practice.
-* The same free-lane property batches MULTIPLE histograms in one pass:
+* The same property batches MULTIPLE histograms in one pass:
   `histogram_pallas_multi` computes per-leaf histograms for a tile of
   leaves (lanes = leaf x channel) in a single data pass — the engine of
   the level-batched grower.  The single-leaf entry points are its tile = 1
   case.
 * Mosaic on this toolchain rejects bf16/int8 broadcast-selects (and int8
-  compares); everything is built in 32-bit dtypes and cast at the dot.  The
-  multi-leaf kernels measured ~20% faster at a 1024-row tile (verified to
-  compile and run on-chip); the select-heavy experimental kernels that
-  motivated the earlier 512 cap were removed after losing the benchmark.
+  compares); everything is built in 32-bit dtypes and cast at the dot.
 
 Channels convention of the package: CHANNEL-FIRST (3, F, B) with channels
 (sum_grad, sum_hess, count).  Channel-first is a measured TPU layout
@@ -141,32 +166,47 @@ _FEAT_BLOCK = 128  # feature-block width for wide datasets (Epsilon-class);
 
 _BASE_ROWS = 8  # rows of the channel-first per-tree base: one f32 sublane tile
 
+ROW_TILE = 2048  # rows of a row tile: one DMA, one count
+_DENSE_ROWS = 1024  # rows of one dense product inside a tile
+_PACK_MAX_BINS = 256  # bfloat16 holds a bin up to here: packed rows' bins
+# are moved as bfloat16
+SUB_BLOCK = 128  # rows of a packed sub-block: what a tile pays in whole
 
-def _direct_kernel(chunk_ref, bins_ref, base_ref, slot_ref, out_ref, *, n,
-                   tile, ncl):
+
+def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
+                   n, tile, ncl, pack):
     """Grid (1, row_tiles): the accumulator lives across the row sweep.
     ``chunk_ref`` is the scalar the bins' index map picked its 128 columns
-    by; the body has no use for it.
+    by; the body has no use for it.  ``cnt_ref[i]`` is the number of rows of
+    row tile ``i`` that have a slot in this pass (or more: see
+    :func:`pass_counts`), and decides what the tile costs:
 
-    Per row tile the kernel forms the dot's (NC, T) operand in VMEM from the
-    base block (8, T) and the slot ids (1, T):
-    ``lane[l * ncl + c, t] = base[c, t] if slot[t] == l else 0``.  The
-    channels are spread over the leaves by a 0/1 matrix on the MXU (exact:
-    every channel is bfloat16-exact or an int8, or is rounded to bfloat16
-    here as it would be at the dot), then a select by slot keeps each row's
-    own leaf.  A select and not a product, and rows at or past ``n`` take
-    slot -1: what the ragged last block holds past the arrays' end never
-    reaches the accumulator.
+    * 0: the tile's DMA and nothing else.
+    * so many that packing would cost more (:func:`_tile_cost`), or above
+      256 bins: the dense product.  The dot's (NC, T) operand is formed from
+      the base block (8, T) and the slot ids (1, T),
+      ``lane[l * ncl + c, t] = base[c, t] if slot[t] == l else 0``: the
+      channels are spread over the leaves by a 0/1 matrix on the MXU (exact:
+      every channel is bfloat16-exact or an int8, or is rounded to bfloat16
+      here as it would be at the dot), then a select by slot keeps each
+      row's own leaf; per feature a (T, B) one-hot of the bins is contracted
+      against it.
+    * otherwise the tile's rows that have a slot are PACKED to the front
+      and only ``ceil(cnt / SUB_BLOCK)`` sub-blocks of SUB_BLOCK rows go
+      through the one-hot product.  A row's place among the packed rows is
+      its rank among the tile's rows with a slot (inside each 128-lane group
+      from one product with a 0/1 triangle, plus the groups before it); per
+      sub-block a 0/1 matrix P (SUB_BLOCK, T) with ``P[s, t] = 1`` where
+      row ``t`` takes place ``s`` moves the bins, the channels and the slot
+      on the MXU (exact: 0/1 times values that bfloat16 holds, bins up to
+      256 among them), and the same lanes and one-hots are then built from
+      the packed block.  Places past the tile's count hold zeros and add
+      nothing.  The sums of a tile are taken sub-block by sub-block, so a
+      float histogram need not equal the dense product's digit for digit.
 
-    Measured cost model (in-jit fori_loop probes, so that no host
-    dispatch is in the timing, v5e): a full-N pass costs ~7.7-10 ms at
-    N=1M, F=28 and
-    is INVARIANT to num_bins (64 vs 256), payload lanes (8 vs 48), row
-    tile (1024-8192), bins layout (row- vs feature-major), and even to
-    replacing the one-hot compare with a constant — the floor is the
-    per-(tile, feature) dot itself.  Consequence: payload lanes up to the
-    128-wide MXU tile are FREE; fill them (21 leaves x 6ch) and cut the
-    number of passes, do not shrink B or NC."""
+    Rows at or past ``n`` take slot -1 and their base is zeroed by a select
+    before any product: what the ragged last block holds past the arrays'
+    end never reaches the accumulator."""
     i = pl.program_id(1)
 
     # the revisited output block IS the accumulator (a separate VMEM
@@ -178,34 +218,165 @@ def _direct_kernel(chunk_ref, bins_ref, base_ref, slot_ref, out_ref, *, n,
 
     FB, NC, B = out_ref.shape
     K, T = base_ref.shape
+    S = SUB_BLOCK
+    cnt = cnt_ref[i]
+    blocks, dense = _tile_cost(cnt, T, FB, pack)
     # the dots' operand type: int8 for the quantized base, bfloat16 otherwise
     dtype = jnp.int8 if base_ref.dtype == jnp.int8 else jnp.bfloat16
-    row = i * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-    slot = jnp.where(row < n, slot_ref[...], -1)  # (1, T)
 
     # everything is built in 32-bit types and cast at the dots: Mosaic on
     # this toolchain refuses bf16/int8 broadcast-selects and int8 compares
+    def bf16(x):
+        return x.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def operands(r0, rows):
+        """Rows [r0, r0 + rows) of the tile -> slot (1, rows) int32 and the
+        base (K, rows) float32, rows past n and rows with no slot zeroed."""
+        row = i * T + r0 + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        slot = jnp.where(row < n, slot_ref[:, pl.ds(r0, rows)], -1)
+        base = base_ref[:, pl.ds(r0, rows)]
+        if dtype == jnp.int8:
+            base = base.astype(jnp.int32)
+        return slot, jnp.where(slot >= 0, base.astype(jnp.float32), 0.0)
+
     r = jax.lax.broadcasted_iota(jnp.int32, (NC, K), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (NC, K), 1)
-    spread = ((r - r // ncl * ncl == c) & (r < tile * ncl)).astype(
-        jnp.float32).astype(jnp.bfloat16)  # (NC, K), 0/1
-    base = base_ref[...]
-    if dtype == jnp.int8:
-        base = base.astype(jnp.int32)
-    wide = jnp.dot(spread, base.astype(jnp.float32).astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32)  # (NC, T)
+    spread = bf16((r - r // ncl * ncl == c) & (r < tile * ncl))  # (NC, K) 0/1
     leaf_of = jax.lax.broadcasted_iota(jnp.int32, (NC, 1), 0) // ncl
-    lane = jnp.where(slot == leaf_of, wide, 0.0)  # (1, T) == (NC, 1)
-    if dtype == jnp.int8:
-        lane = lane.astype(jnp.int32)
-    lane = lane.astype(dtype)  # (NC, T)
 
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (T, B), 1)  # hoisted
-    bins_i32 = bins_ref[...].astype(jnp.int32)  # (T, FB) upcast once
-    for f in range(FB):
-        binf = bins_i32[:, f][:, None]  # (T, 1)
-        oh = (binf == iota_b).astype(dtype)  # (T, B)
-        out_ref[f] += jnp.dot(lane, oh, preferred_element_type=out_ref.dtype)
+    def accumulate(base, slot, bins_i32):
+        """base (K, W) bfloat16, slot (1, W), bins (W, FB) int32: the
+        one-hot product of W rows into the accumulator."""
+        W = slot.shape[1]
+        wide = jnp.dot(spread, base,
+                       preferred_element_type=jnp.float32)  # (NC, W)
+        lane = jnp.where(slot == leaf_of, wide, 0.0)  # (1, W) == (NC, 1)
+        if dtype == jnp.int8:
+            lane = lane.astype(jnp.int32)
+        lane = lane.astype(dtype)
+        iota_b = jax.lax.broadcasted_iota(jnp.int32, (W, B), 1)  # hoisted
+        for f in range(FB):
+            oh = (bins_i32[:, f][:, None] == iota_b).astype(dtype)  # (W, B)
+            out_ref[f] += jnp.dot(lane, oh,
+                                  preferred_element_type=out_ref.dtype)
+
+    @pl.when(dense)
+    def _():
+        # in pieces of _DENSE_ROWS, so that the unrolled one-hots (and the
+        # time Mosaic takes to compile them) do not grow with the row tile
+        D = _DENSE_ROWS if T % _DENSE_ROWS == 0 else T
+
+        def piece(p, carry):
+            r0 = pl.multiple_of(p * D, D)
+            slot, base = operands(r0, D)
+            accumulate(base.astype(jnp.bfloat16), slot,
+                       bins_ref[pl.ds(r0, D), :].astype(jnp.int32))
+            return carry
+
+        jax.lax.fori_loop(0, T // D, piece, 0)
+
+    if not pack:
+        return
+
+    @pl.when((cnt > 0) & jnp.logical_not(dense))
+    def _():
+        G = T // 128
+        slot, base = operands(0, T)
+        # the slot rides in the base's last row, which no channel uses
+        krow = jax.lax.broadcasted_iota(jnp.int32, (K, T), 0)
+        base = jnp.where(krow == K - 1, slot.astype(jnp.float32),
+                         base).astype(jnp.bfloat16)
+        # place[g, j]: where row g * 128 + j goes among the packed rows
+        taken = (slot >= 0).astype(jnp.float32)
+        taken = jnp.concatenate(
+            [taken[:, g * 128:(g + 1) * 128] for g in range(G)], axis=0)
+        k = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+        j = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+        rank = jnp.dot(taken.astype(jnp.bfloat16), bf16(k < j),
+                       preferred_element_type=jnp.float32)  # in its group
+        total = jnp.broadcast_to(jnp.sum(taken, axis=1, keepdims=True),
+                                 (G, 128))
+        gk = jax.lax.broadcasted_iota(jnp.int32, (G, G), 0)
+        gj = jax.lax.broadcasted_iota(jnp.int32, (G, G), 1)
+        before = jnp.dot(bf16(gj < gk), total.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32)  # groups before
+        place = jnp.where(taken > 0, rank + before, -1.0).astype(jnp.int32)
+        bins_bf = bf16(bins_ref[...].astype(jnp.int32))  # (T, FB)
+        iota_s = jax.lax.broadcasted_iota(jnp.int32, (S, 128), 0)
+
+        def block(b, carry):
+            bins_c = jnp.zeros((S, FB), jnp.float32)
+            base_c = jnp.zeros((K, S), jnp.float32)
+            for g in range(G):
+                cols = slice(g * 128, (g + 1) * 128)
+                p = bf16(place[g:g + 1, :] - b * S == iota_s)  # (S, 128)
+                bins_c += jnp.dot(p, bins_bf[cols, :],
+                                  preferred_element_type=jnp.float32)
+                base_c += jax.lax.dot_general(
+                    base[:, cols], p, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            accumulate(base_c.astype(jnp.bfloat16),
+                       base_c[K - 1:K, :].astype(jnp.int32),
+                       bins_c.astype(jnp.int32))
+            return carry
+
+        jax.lax.fori_loop(0, blocks, block, 0)
+
+
+def _row_tile(n: int, row_tile: int) -> int:
+    """The kernel's row tile: whole 128-row lane groups, and no more of them
+    than the rows need."""
+    return min(_round_up(row_tile, 128), _round_up(n, 128))
+
+
+def pass_counts(mask: jnp.ndarray, row_tile: int = ROW_TILE) -> jnp.ndarray:
+    """(row_tiles,) int32: the rows of each row tile of the kernel that
+    ``mask`` (N,) keeps.  The kernel's cost follows these counts.  A count
+    may be too high (rows the mask keeps but no slot of the pass takes: the
+    kernel multiplies empty places), never too low."""
+    n = mask.shape[0]
+    t = _row_tile(n, row_tile)
+    full = n // t
+    keep = mask.astype(jnp.int32)
+    counts = jnp.sum(keep[:full * t].reshape(full, t), axis=1)
+    if full * t < n:  # the ragged last tile
+        counts = jnp.concatenate([counts, jnp.sum(keep[full * t:])[None]])
+    return counts
+
+
+def _tile_cost(cnt, row_tile: int, feat_block: int, pack: bool):
+    """What a row tile with ``cnt`` rows in the pass does -> (its rows in
+    whole sub-blocks, whether it takes the dense product).  The kernel asks
+    with its scalar, :func:`blocks_multiplied` with every tile's count.
+
+    Packing is taken where it is the cheaper of the two, in units of one
+    sub-block's one-hot product for one feature (v5e, 28 and 128 features,
+    PERF.md section 6, PR 29): a dense tile costs ``row_tile / SUB_BLOCK``
+    sub-blocks of ``feat_block`` features; a packed sub-block costs its
+    features and about two more for every 128-row lane group of the tile
+    (its share of the place matrix and of the two moves), and the tile
+    about three a group before its first sub-block.  Both constants were
+    read off tiles of one sub-block; at seven or eight a group costs nearer
+    1.5, so the rule turns dense a sub-block or two early at 28 features."""
+    groups = row_tile // 128
+    blocks = (cnt + SUB_BLOCK - 1) // SUB_BLOCK
+    dense = cnt > 0
+    if pack:
+        dense &= (blocks * (feat_block + 2 * groups) + 3 * groups
+                  >= row_tile // SUB_BLOCK * feat_block)
+    return blocks, dense
+
+
+def blocks_multiplied(counts: jnp.ndarray, shape: tuple, num_bins: int,
+                      row_tile: int = ROW_TILE) -> jnp.ndarray:
+    """int32 scalar: the SUB_BLOCK-row blocks that a pass with these
+    :func:`pass_counts` over bins of ``shape`` (N, F) puts through the
+    one-hot product of each 128-feature chunk: a packed tile its rows in
+    whole sub-blocks, a dense tile all of its own."""
+    t = _row_tile(shape[0], row_tile)
+    blocks, dense = _tile_cost(counts, t, min(shape[1], _FEAT_BLOCK),
+                               num_bins <= _PACK_MAX_BINS)
+    return jnp.sum(jnp.where(dense, t // SUB_BLOCK, blocks))
 
 
 @functools.partial(jax.jit,
@@ -215,6 +386,7 @@ def _hist_pallas_raw(
     base: jnp.ndarray,  # (_BASE_ROWS, N) f32 or int8: the per-tree channels
     slot: jnp.ndarray,  # (1, N) int32: the row's leaf of this pass, or -1
     chunk: jnp.ndarray,  # (1,) int32: which block of _FEAT_BLOCK features
+    counts: jnp.ndarray,  # (row_tiles,) int32: pass_counts of slot >= 0
     *,
     num_bins: int,
     row_tile: int,
@@ -228,32 +400,34 @@ def _hist_pallas_raw(
     and picks its 128 columns in its index map.  The chunk is a prefetched
     scalar and not a static, so that the sixteen calls of a pass at
     F = 2000 are one traced and lowered function: with sixteen index maps
-    Epsilon's first ``update()`` took 95 s instead of 20."""
+    Epsilon's first ``update()`` took 95 s instead of 20.  ``row_tile`` is
+    rounded to whole 128-row groups (``_row_tile``)."""
     n, f = bins.shape
     B = _round_up(max(num_bins, 8), 8)
     quantized = base.dtype == jnp.int8
     nc = _round_up(tile * ncl, 32 if quantized else 8)
     FB = min(f, _FEAT_BLOCK)
-    if n <= row_tile:
-        row_tile = n  # one block, the arrays' full extent
+    row_tile = _row_tile(n, row_tile)
 
     # no scope and no name= here: XLA names the custom call after the
     # innermost component of its op_name, and the benchmark's kernel metrics
     # find it in a device trace as ``_hist_pallas_raw.N`` (_leaf_histograms)
     return pl.pallas_call(
-        functools.partial(_direct_kernel, n=n, tile=tile, ncl=ncl),
+        functools.partial(_direct_kernel, n=n, tile=tile, ncl=ncl,
+                          pack=num_bins <= _PACK_MAX_BINS),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(1, pl.cdiv(n, row_tile)),
             in_specs=[
-                pl.BlockSpec((row_tile, FB), lambda _, i, c: (i, c[0]),
+                pl.BlockSpec((row_tile, FB), lambda _, i, c, k: (i, c[0]),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((_BASE_ROWS, row_tile), lambda _, i, c: (0, i),
+                pl.BlockSpec((_BASE_ROWS, row_tile),
+                             lambda _, i, c, k: (0, i),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, row_tile), lambda _, i, c: (0, i),
+                pl.BlockSpec((1, row_tile), lambda _, i, c, k: (0, i),
                              memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((FB, nc, B), lambda j, i, c: (j, 0, 0),
+            out_specs=pl.BlockSpec((FB, nc, B), lambda j, i, c, k: (j, 0, 0),
                                    memory_space=pltpu.VMEM),
         ),
         out_shape=jax.ShapeDtypeStruct(
@@ -264,7 +438,7 @@ def _hist_pallas_raw(
                                 + _BASE_ROWS * base.dtype.itemsize + 4),
             transcendentals=0,
         ),
-    )(chunk, bins, base, slot)
+    )(chunk, counts, bins, base, slot)
 
 
 def _split_bf16x2(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -317,13 +491,16 @@ def payload_base_quantized(grad_q: jnp.ndarray, hess_q: jnp.ndarray,
 
 
 def _leaf_histograms(bins, base, mask, leaf_id, leaf_base, tile, num_bins,
-                     ncl, row_tile):
+                     ncl, row_tile, counts=None):
     """One pass of the kernel -> (tile, ncl, F, B) in the accumulator's
     dtype: channel ``c`` of the rows that ``mask`` keeps and that sit in
-    leaf ``leaf_base + l``."""
+    leaf ``leaf_base + l``.  ``counts``: :func:`pass_counts` of ``mask`` at
+    this ``row_tile``, from a caller that made them already."""
     with phase_scope("grow.slots"):
         slot = jnp.where(mask.astype(bool),
                          leaf_id.astype(jnp.int32) - leaf_base, -1)[None, :]
+        if counts is None:
+            counts = pass_counts(slot[0] >= 0, row_tile)
     f = bins.shape[1]
     # wide data (Epsilon-class): one pallas_call PER 128-feature chunk,
     # unrolled in-trace.  Each call's output/accumulator is (128, NC, B)
@@ -340,8 +517,8 @@ def _leaf_histograms(bins, base, mask, leaf_id, leaf_base, tile, num_bins,
     with phase_scope("hist.kernel"):
         outs = [
             _hist_pallas_raw(bins, base, slot, jnp.full((1,), j, jnp.int32),
-                             num_bins=num_bins, row_tile=row_tile, tile=tile,
-                             ncl=ncl)
+                             counts, num_bins=num_bins, row_tile=row_tile,
+                             tile=tile, ncl=ncl)
             for j in range(pl.cdiv(f, _FEAT_BLOCK))]
     with phase_scope("hist.unpack"):
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
@@ -361,8 +538,9 @@ def histogram_pallas_multi(
     num_bins: int,
     *,
     precision: str = "f32",
-    row_tile: int = 1024,
+    row_tile: int = ROW_TILE,
     base: jnp.ndarray = None,  # payload_base(grad, hess, m, precision), m >= mask
+    counts: jnp.ndarray = None,  # pass_counts(mask, row_tile)
 ) -> jnp.ndarray:
     """Per-leaf histograms for a tile of leaves in ONE data pass.
 
@@ -370,7 +548,8 @@ def histogram_pallas_multi(
     l*NCL + c holds payload channel c masked to leaf leaf_base+l, formed in
     the kernel from ``base`` and the rows' slots.  A caller with many
     passes over the same gradients builds ``base`` once and hands it in;
-    without it, it is built here.
+    without it, it is built here.  Likewise ``counts``, for a caller that
+    keeps them (the rounds grower counts what the kernel multiplied).
     This is the TPU replacement for per-leaf row-index histogramming
     (reference: Dataset::ConstructHistograms over DataPartition indices).
     """
@@ -379,7 +558,8 @@ def histogram_pallas_multi(
             base = payload_base(grad, hess, mask, precision)
     out = _leaf_histograms(
         bins, base, mask, leaf_id, leaf_base, num_leaves_tile, num_bins,
-        payload_channels(precision, False), row_tile)  # (L_tile, ncl, F, B)
+        payload_channels(precision, False), row_tile,
+        counts)  # (L_tile, ncl, F, B)
     if precision == "f32":
         with phase_scope("hist.unpack"):
             out = jnp.stack([out[:, 0] + out[:, 3], out[:, 1] + out[:, 4],
@@ -414,8 +594,9 @@ def histogram_pallas_multi_quantized(
     num_leaves_tile: int,
     num_bins: int,
     *,
-    row_tile: int = 1024,
+    row_tile: int = ROW_TILE,
     base: jnp.ndarray = None,  # payload_base_quantized(grad_q, hess_q, m)
+    counts: jnp.ndarray = None,  # pass_counts(mask, row_tile)
 ) -> jnp.ndarray:
     """Quantized per-leaf histograms for a tile of leaves in one pass ->
     (L_tile, 3, F, B) int32: exact integer accumulation on the int8 MXU
@@ -425,7 +606,7 @@ def histogram_pallas_multi_quantized(
         with phase_scope("hist.payload"):
             base = payload_base_quantized(grad_q, hess_q, mask)
     return _leaf_histograms(bins, base, mask, leaf_id, leaf_base,
-                            num_leaves_tile, num_bins, 3, row_tile)
+                            num_leaves_tile, num_bins, 3, row_tile, counts)
 
 
 def histogram_pallas_quantized(

@@ -64,6 +64,8 @@ class TreeArrays(NamedTuple):
     path_features: Optional[jnp.ndarray] = None  # (L, F) bool (linear trees)
     hist_passes: Optional[jnp.ndarray] = None  # i32 scalar — full passes over
     # the rows this tree took (the rounds grower counts them; others: None)
+    hist_blocks: Optional[jnp.ndarray] = None  # i32 scalar — sub-blocks of
+    # hist_pallas.SUB_BLOCK rows the Pallas kernel multiplied in those passes
 
 
 class GrowState(NamedTuple):

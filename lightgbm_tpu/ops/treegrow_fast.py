@@ -1,9 +1,12 @@
 """Round-batched leaf-wise tree growth — the TPU throughput grower.
 
-Motivation (measured on a v5e chip; see ops/hist_pallas.py): one full-data
-histogram pass costs ~6 ms at 1M x 28 x 256 regardless of how few rows are
-masked in, because the one-hot build is VPU-bound on ALL rows.  The strict
-leaf-wise grower (ops/treegrow.py) pays that pass per SPLIT (num_leaves-1
+Motivation (*log*, 1M x 28 x 256 on a v5e, before the kernel packed its
+rows): one full-data histogram pass cost ~6 ms regardless of how few rows
+were masked in.  Since PR 29 the Pallas kernel's cost follows the rows of
+the pass (ops/hist_pallas.py: each row tile pays for whole sub-blocks of
+its rows in the pass, plus a floor per tile), so a pass over small children
+is cheap but not free.  The strict
+leaf-wise grower (ops/treegrow.py) pays a pass per SPLIT (num_leaves-1
 passes/tree).  This grower pays it per ROUND: each round splits EVERY
 already-evaluated leaf whose gain clears the bar (best-gain-first within the
 remaining num_leaves budget), then computes histograms for ALL new smaller
@@ -42,7 +45,8 @@ import jax.numpy as jnp
 
 from ..utils import degrade as _degrade
 from ..utils.profiling import phase_scope
-from .hist_pallas import payload_base, payload_base_quantized
+from .hist_pallas import (blocks_multiplied, pass_counts, payload_base,
+                          payload_base_quantized)
 from .histogram import (histogram, histogram_multi, histogram_multi_quantized,
                         histogram_onehot_multi,
                         histogram_onehot_multi_quantized, unbundle_hists)
@@ -112,6 +116,8 @@ class FastState(NamedTuple):
     progress: jnp.ndarray  # bool — this round applied at least one split
     hist_passes: jnp.ndarray  # i32 — full passes over the rows so far: the
     # root's, plus one for every hist_and_eval taken
+    hist_blocks: jnp.ndarray  # i32 — sub-blocks of rows the Pallas kernel put
+    # through its one-hot product in those passes (0 on the other routes)
     tree: TreeArrays
     anc: jnp.ndarray = False  # (L, L-1) bool ancestor masks, or () placeholder
     aside: jnp.ndarray = False  # (L, L-1) bool — leaf on the RIGHT side of m
@@ -290,35 +296,42 @@ def _grow_fast_impl(
                          payload_base(grad, hess, row_mask, hist_precision))
 
     def multi_hist(leaf_slot, tile):
-        """(N,)-slot -> (tile, 3, F, B) f32: per-slot histograms, one pass."""
+        """(N,)-slot -> (tile, 3, F, B) f32: per-slot histograms, one pass;
+        and the sub-blocks the Pallas kernel multiplied for it."""
+        keep = row_mask & (leaf_slot >= 0)
+        counts, blocks = None, jnp.asarray(0, jnp.int32)
+        if hist_base is not None:
+            with phase_scope("grow.slots"):  # once a pass, for every chunk
+                counts = pass_counts(keep)
+                blocks = blocks_multiplied(counts, hist_bins.shape, num_bins)
         if use_pallas and quantize_bins:
             if num_bins <= 64:
                 # same measured strategy selection as the float path: XLA's
                 # fused one-hot (here int8 x int8 -> int32) wins at narrow
                 # bins; exactness is identical
                 h = histogram_onehot_multi_quantized(
-                    hist_bins, gq, hq, row_mask & (leaf_slot >= 0),
+                    hist_bins, gq, hq, keep,
                     jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 )
             else:
                 h = histogram_multi_quantized(
-                    hist_bins, gq, hq, row_mask & (leaf_slot >= 0),
+                    hist_bins, gq, hq, keep,
                     jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
-                    base=hist_base,
+                    base=hist_base, counts=counts,
                 )
         elif use_pallas and num_bins <= 64:
             # measured strategy selection (ops/histogram.py docstring): at
             # narrow bins XLA's fused one-hot einsum beats the Pallas kernel
             h = histogram_onehot_multi(
-                hist_bins, grad, hess, row_mask & (leaf_slot >= 0),
+                hist_bins, grad, hess, keep,
                 jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 precision=hist_precision,
             )
         elif use_pallas:
             h = histogram_multi(
-                hist_bins, grad, hess, row_mask & (leaf_slot >= 0),
+                hist_bins, grad, hess, keep,
                 jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
-                precision=hist_precision, base=hist_base,
+                precision=hist_precision, base=hist_base, counts=counts,
             )
         else:
             # CPU/test fallback: per-slot masked scatter histograms (uses the
@@ -333,11 +346,13 @@ def _grow_fast_impl(
             h = unbundle(h)
             if use_pallas and quantize_bins:  # int32 sums back to floats
                 h = h.astype(jnp.float32) * quant_scale[:, None, None]
-            return psum(h)
+            return psum(h), blocks
 
     # ---- root ----
     with phase_scope("grow.root"):
-        hist0 = multi_hist(jnp.where(row_mask, 0, -1).astype(jnp.int32), 1)[0]
+        hist0, blocks0 = multi_hist(
+            jnp.where(row_mask, 0, -1).astype(jnp.int32), 1)
+        hist0 = hist0[0]
         sum0 = jnp.sum(hist0[:, 0, :], axis=1)  # totals from feature 0: (3,)
         g0, h0, c0 = sum0[0], sum0[1], sum0[2]
 
@@ -430,6 +445,7 @@ def _grow_fast_impl(
             slot_small_left=jnp.zeros((leaf_tile,), bool),
             progress=jnp.asarray(True),
             hist_passes=jnp.asarray(1, jnp.int32),  # the root's
+            hist_blocks=blocks0,
             tree=tree0,
             anc=(jnp.zeros((L, L - 1), bool) if use_intermediate
                  else jnp.zeros((), bool)),
@@ -754,6 +770,7 @@ def _grow_fast_impl(
             slot_small_left=slot_small_left,
             progress=k_acc > 0,
             hist_passes=state.hist_passes,
+            hist_blocks=state.hist_blocks,
             tree=tree,
             anc=anc,
             aside=aside,
@@ -773,7 +790,7 @@ def _grow_fast_impl(
                 leaf_r = jnp.argmax(has_r).astype(jnp.int32)
                 exists = jnp.any(has_r)
                 leaf_slot = jnp.where(exists & (lid == leaf_r), r, leaf_slot)
-        fresh_hists = multi_hist(leaf_slot, leaf_tile)  # (leaf_tile, 3, F, B)
+        fresh_hists, blocks = multi_hist(leaf_slot, leaf_tile)  # (tile, 3, F, B)
         with phase_scope("grow.sibling"):
             idx = jnp.arange(L, dtype=jnp.int32)
             # COMPACT sibling recovery (round 5): parent hists live in the left
@@ -825,6 +842,7 @@ def _grow_fast_impl(
                 return state._replace(
                     hist=hist, best=best,
                     hist_passes=state.hist_passes + 1,
+                    hist_blocks=state.hist_blocks + blocks,
                     fresh=jnp.zeros((L,), bool),
                     small_slot=jnp.full((L,), -1, jnp.int32),
                     slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
@@ -861,6 +879,7 @@ def _grow_fast_impl(
             return state._replace(
                 hist=hist, best=best,
                 hist_passes=state.hist_passes + 1,
+                hist_blocks=state.hist_blocks + blocks,
                 fresh=jnp.zeros((L,), bool),
                 small_slot=jnp.full((L,), -1, jnp.int32),
                 slot_left=jnp.full((leaf_tile,), -1, jnp.int32),
@@ -957,6 +976,7 @@ def _grow_fast_impl(
             leaf_depth=state.leaf_depth,
             path_features=(state.used_features if track_path else None),
             hist_passes=state.hist_passes,
+            hist_blocks=state.hist_blocks,
         )
     if use_lazy:
         # hand the cross-tree charge state back (reference: the

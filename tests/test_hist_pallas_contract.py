@@ -15,7 +15,7 @@ N = 1333  # 309 rows into the second 1024-row tile
 B = 37
 
 
-def _oracle(bins, chans, slot, tile):
+def _oracle(bins, chans, slot, tile, B=B):
     """(tile, C, F, B) float64 sums of each channel over the rows of a slot."""
     n, f = bins.shape
     out = np.zeros((tile, len(chans), f, B))
@@ -26,6 +26,15 @@ def _oracle(bins, chans, slot, tile):
                 out[l, c, j] = np.bincount(bins[rows, j], weights=v[rows],
                                            minlength=B)
     return out
+
+
+def _run(fn, arrays, *static, **kw):
+    """One jitted call through the TPU interpreter, waited for.  Not op by
+    op: the interpreter's callbacks run jax operations of their own, and an
+    eager operation dispatched beside a kernel still in flight can deadlock
+    with them."""
+    return np.asarray(jax.jit(lambda *a: fn(*a, *static, **kw))(
+        *[jnp.asarray(a) for a in arrays]))
 
 
 def _data(f, tile):
@@ -53,20 +62,17 @@ def test_leaf_histograms_match_the_oracle(f, precision, tile):
             rng = np.random.RandomState(5)
             gq = rng.randint(-127, 128, size=N).astype(np.int8)
             hq = rng.randint(0, 128, size=N).astype(np.int8)
-            got = np.asarray(hp.histogram_pallas_multi_quantized(
-                jnp.asarray(bins), jnp.asarray(gq), jnp.asarray(hq),
-                jnp.asarray(live), jnp.asarray(np.maximum(leaf, 0)), 0, tile,
-                B))
+            got = _run(hp.histogram_pallas_multi_quantized,
+                       (bins, gq, hq, live, np.maximum(leaf, 0)), 0, tile, B)
             want = _oracle(bins, [gq.astype(np.float64),
                                   hq.astype(np.float64), np.ones(N)], slot,
                            tile)
             assert got.dtype == np.int32
             np.testing.assert_array_equal(got, want.astype(np.int64))
             return
-        got = np.asarray(hp.histogram_pallas_multi(
-            jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
-            jnp.asarray(live), jnp.asarray(np.maximum(leaf, 0)), 0, tile, B,
-            precision=precision))
+        got = _run(hp.histogram_pallas_multi,
+                   (bins, grad, hess, live, np.maximum(leaf, 0)), 0, tile, B,
+                   precision=precision)
     assert got.shape == (tile, 3, f, B) and got.dtype == np.float32
     want = _oracle(bins, [grad, hess, np.ones(N)], slot, tile)
     # products carry ~17 bits of the gradient with the bf16x2 split, 8 without
@@ -85,22 +91,20 @@ def test_a_base_built_once_serves_every_pass_of_a_tree(precision):
     if q:
         grad = np.round(grad * 20).astype(np.int8)
         hess = np.round(hess * 20).astype(np.int8)
-    args = [jnp.asarray(a) for a in (bins, grad, hess)]
     with pltpu.force_tpu_interpret_mode():
-        base = (hp.payload_base_quantized if q else hp.payload_base)(
-            args[1], args[2], jnp.asarray(inbag))
+        base = np.asarray((hp.payload_base_quantized if q else hp.payload_base)(
+            jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(inbag)))
         assert base.shape == (8, N)
+        call = (hp.histogram_pallas_multi_quantized if q
+                else hp.histogram_pallas_multi)
         for shift in (0, 2):
             lid = (leaf + shift) % tile
             live = inbag & (leaf >= 0)
-            call = (hp.histogram_pallas_multi_quantized if q
-                    else hp.histogram_pallas_multi)
-            with_base = call(*args, jnp.asarray(live), jnp.asarray(lid), 0,
-                             tile, B, base=base)
-            alone = call(*args, jnp.asarray(live), jnp.asarray(lid), 0, tile,
-                         B)
-            np.testing.assert_array_equal(np.asarray(with_base),
-                                          np.asarray(alone))
+            with_base = _run(
+                lambda *a: call(*a[:-1], 0, tile, B, base=a[-1]),
+                (bins, grad, hess, live, lid, base))
+            alone = _run(call, (bins, grad, hess, live, lid), 0, tile, B)
+            np.testing.assert_array_equal(with_base, alone)
 
 
 @pytest.mark.parametrize("quantized", [False, True])
@@ -125,12 +129,13 @@ def test_rows_past_n_never_reach_the_accumulator(quantized):
     kw = dict(num_bins=B, row_tile=1024, tile=tile, ncl=ncl)
     chunk = jnp.zeros((1,), jnp.int32)
     with pltpu.force_tpu_interpret_mode():
+        counts = hp.pass_counts(jnp.asarray(slot[0, :N] >= 0), 1024)
         long = np.asarray(hp._hist_pallas_raw(
             jnp.asarray(bins), jnp.asarray(base), jnp.asarray(slot), chunk,
-            **kw))
+            counts, **kw))
         exact = np.asarray(hp._hist_pallas_raw(
             jnp.asarray(bins), jnp.asarray(base[:, :N]),
-            jnp.asarray(slot[:, :N]), chunk, **kw))
+            jnp.asarray(slot[:, :N]), chunk, counts, **kw))
     assert np.isfinite(long.astype(np.float64)).all()
     np.testing.assert_array_equal(long, exact)
     assert long[:, :tile * ncl].any()

@@ -1,0 +1,62 @@
+"""The histogram kernel is accepted by the TPU's compiler at the shapes the
+benchmark's cells run, compiled here for a described v5e with no chip
+attached.  Nothing runs: no result, no time.  (What the interpreter cannot
+show: slices off the tiling, too much VMEM, an operation Mosaic refuses.)"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from lightgbm_tpu.ops import hist_pallas as hp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # what is compiled for a described chip cannot be read back from the
+    # persistent cache without one
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+SHAPES = {
+    # rows, features, leaves a pass, channels a leaf, base dtype, bins
+    "higgs_float": (10_500_000, 28, 8, 6, jnp.float32, 255),
+    "higgs_root_one_leaf": (10_500_000, 28, 1, 6, jnp.float32, 255),
+    "narrow_int8": (1_000_000, 28, 20, 3, jnp.int8, 255),
+    "fewer_rows_than_a_tile": (500, 28, 4, 3, jnp.float32, 255),
+    "over_256_bins_dense_alone": (1_000_000, 28, 8, 6, jnp.float32, 300),
+    "epsilon_float_one_chunk_of_sixteen": (400_000, 2000, 10, 6, jnp.float32,
+                                           255),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHAPES))
+def test_mosaic_takes_the_kernel(one_chip, case):
+    n, f, tile, ncl, dtype, num_bins = SHAPES[case]
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    tiles = -(-n // hp._row_tile(n, hp.ROW_TILE))
+    compiled = jax.jit(
+        lambda *a: hp._hist_pallas_raw(*a, num_bins=num_bins,
+                                       row_tile=hp.ROW_TILE, tile=tile,
+                                       ncl=ncl)).lower(
+            s((n, f), jnp.int16), s((8, n), dtype), s((1, n), jnp.int32),
+            s((1,), jnp.int32), s((tiles,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

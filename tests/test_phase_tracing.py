@@ -162,6 +162,42 @@ def test_a_tree_that_cannot_split_took_the_root_pass_alone():
     assert int(tree.num_leaves) == 1 and int(tree.hist_passes) == 1
 
 
+def test_hist_blocks_counts_the_sub_blocks_the_kernel_multiplied():
+    """Two leaves on the Pallas route (through the interpreter), 130
+    features so that packing pays: the root's pass takes every row, the
+    second the smaller child alone, and each row tile then pays whole
+    sub-blocks of the rows it holds of the pass."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from lightgbm_tpu.ops import hist_pallas as hp
+
+    n = 2 * hp.ROW_TILE + 300
+    rng = np.random.RandomState(5)
+    bins = rng.randint(0, 32, size=(n, 130)).astype(np.int16)
+    bins[:, 0] = rng.rand(n) < 0.2  # the split every gain points at
+    grad = np.where(bins[:, 0] > 0, -0.5, 0.5).astype(np.float32)
+    with pltpu.force_tpu_interpret_mode():
+        tree, leaf_id = _grow(bins, grad, np.full(n, 0.25, np.float32),
+                              num_leaves=2, num_bins=128, use_pallas=True)
+    assert int(tree.num_leaves) == 2 and int(tree.hist_passes) == 2
+    leaf_id = np.asarray(leaf_id)
+    small = leaf_id == np.argmin(np.bincount(leaf_id, minlength=2))
+    assert 0.1 * n < small.sum() < 0.3 * n
+
+    def blocks(rows):  # the rule itself: tests/test_hist_pallas_contract.py
+        return int(hp.blocks_multiplied(
+            hp.pass_counts(jnp.asarray(rows)), bins.shape, 128))
+
+    every, packed = blocks(np.ones(n, bool)), blocks(small)
+    assert int(tree.hist_blocks) == every + packed
+    assert packed == sum(
+        -(-int(small[i:i + hp.ROW_TILE].sum()) // hp.SUB_BLOCK)
+        for i in range(0, n, hp.ROW_TILE)) < every / 3
+    # the other routes run no kernel and count none
+    tree, _ = _grow(*_toy(), num_leaves=2)
+    assert int(tree.hist_blocks) == 0
+
+
 def _booster(mode, n=4000):
     rng = np.random.RandomState(11)
     x = rng.randn(n, 8)
@@ -172,7 +208,8 @@ def _booster(mode, n=4000):
 
 
 COUNTERS = ("train_hist_passes_total", "train_hist_rows_streamed_total",
-            "train_hist_rows_needed_total")
+            "train_hist_rows_needed_total",
+            "train_hist_blocks_multiplied_total")
 
 
 def test_three_updates_and_a_flush_move_the_three_counters():
@@ -183,7 +220,8 @@ def test_three_updates_and_a_flush_move_the_three_counters():
     for _ in range(3):
         bst.update()
     g = bst._gbdt
-    passes = [int(arrays.hist_passes) for arrays, _, _ in g._pending]
+    pending = list(g._pending)
+    passes = [int(arrays.hist_passes) for arrays, _, _ in pending]
     assert len(passes) == 3 and all(p >= 2 for p in passes)
     # nothing is counted on the hot path: the flush brings the trees over
     assert all(obs.counter(c).value == 0 for c in COUNTERS)
@@ -191,6 +229,9 @@ def test_three_updates_and_a_flush_move_the_three_counters():
     got = {c: obs.counter(c).value for c in COUNTERS}
     assert got["train_hist_passes_total"] == sum(passes)
     assert got["train_hist_rows_streamed_total"] == sum(passes) * n
+    # the CPU's route runs no kernel: the counter is there and reads 0
+    assert got["train_hist_blocks_multiplied_total"] == sum(
+        int(arrays.hist_blocks) for arrays, _, _ in pending) == 0
     assert got["train_hist_rows_needed_total"] == sum(
         work.tree_rows(t, n) for t in trees)
     assert obs.counter("train_boost_rounds_total").value == 3
