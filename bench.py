@@ -44,22 +44,6 @@ p50/p99 batch latency over batch sizes x ensemble sizes) and emits a
 {"metric": "predict_rows_per_sec*", ...} artifact row with the same
 incremental un-losable contract; its knobs are PREDICT_BENCH_*.
 
-Multislice mode (round 20): BENCH_MODE=multislice runs the hierarchical
-two-level-merge dryrun (2 slices x 4 ranks off-chip via the hermetic
-subprocess helper; MULTISLICE_SLICES/MULTISLICE_RANKS override): tree ==
-single-mesh sharded at full top-k coverage, per-rank round budget, and
-the statically pinned per-round DCN byte bill in-artifact
-(MULTICHIP_r07-format JSON).
-
-Feature2d mode (round 24): BENCH_MODE=feature2d runs the 2-D
-(rows x features) windowed-round dryrun (2x4 float and 4x2 int8
-off-chip via the hermetic subprocess helper; FEATURE2D_ROW_SHARDS /
-FEATURE2D_FEATURE_SHARDS override the float grid): tree == serial
-windowed, per-rank round budget, and the statically pinned per-axis
-collective byte bills — the feature axis carrying ONLY the go/no-go
-broadcast + election, never histograms — in-artifact
-(MULTICHIP_r08-format JSON).
-
 Out-of-core mode (round 12): BENCH_MODE=ooc runs the data-path levers
 (benchmarks/ooc_bench.py — stream-ingest rows/s vs chunk size,
 spill-training rows/s with bitwise parity asserted, and the partition
@@ -81,14 +65,6 @@ ingest rows/s incl. the durable CRC'd cache append, refit vs
 append-trees update latency, and serve p50/p99 ACROSS zero-downtime
 rollovers vs the BENCH_serve_r01 baseline, rollover parity + audit
 verdict asserted in-artifact); knobs CONTINUAL_BENCH_*.
-
-Fleet mode (round 21): BENCH_MODE=fleet runs the booster-fleet
-benchmark (benchmarks/fleet_bench.py — models/s at B in {1, 64, 4096}
-training B independent boosters as one donated dispatch per round via
-lgb.train_fleet vs the host loop over the solo windowed grower, with
-B=8 bitwise parity float + int8, the warm 1-dispatch/0-sync/0-retrace
-round budget pinned per B from the fleet_round event ledger, and the
-audit verdict in-artifact); knobs FLEET_BENCH_*.
 """
 
 import json
@@ -396,16 +372,6 @@ def main():
         from benchmarks.continual_bench import main as continual_main
 
         return continual_main()
-    if os.environ.get("BENCH_MODE") == "fleet":
-        # booster-fleet benchmark (round 21): B independent boosters as
-        # ONE donated dispatch per round vs the host loop over the solo
-        # grower, bitwise parity + per-B round budget + audit verdict
-        # in-artifact (BENCH_fleet_* row)
-        import sys as _sys
-        _sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        from benchmarks.fleet_bench import main as fleet_main
-
-        return fleet_main()
     if os.environ.get("BENCH_MODE") == "ooc":
         # out-of-core/partition data-path levers (BENCH_ooc_* artifact)
         import sys as _sys
@@ -413,149 +379,6 @@ def main():
         from benchmarks.ooc_bench import main as ooc_main
 
         return ooc_main()
-    if os.environ.get("BENCH_MODE") == "multichip":
-        # sharded fused windowed dryrun (round 14): the one-dispatch
-        # windowed round under shard_map with the histogram merge an
-        # in-dispatch psum / psum_scatter, validated for tree equality +
-        # the per-rank round budget on an n-device mesh (off-chip this is
-        # the CPU loopback mesh; on a slice the same lever exercises real
-        # ICI).  Writes MULTICHIP_r06-format JSON to stdout.
-        import sys as _sys
-        _sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import __graft_entry__ as _ge
-
-        n_dev = int(os.environ.get("MULTICHIP_DEVICES", "8"))
-        result = {"n_devices": n_dev, "mode": "sharded_fused_windowed",
-                  "merges": {}, "ok": True}
-        for merge in ("psum", "scatter"):
-            import io
-            from contextlib import redirect_stdout
-
-            buf = io.StringIO()
-            try:
-                with redirect_stdout(buf):
-                    _ge.dryrun_multichip_windowed(n_dev, merge)
-                result["merges"][merge] = {
-                    "rc": 0, "ok": True,
-                    "tail": buf.getvalue()[-500:]}
-            except Exception as e:  # noqa: BLE001 — artifact robustness
-                result["merges"][merge] = {
-                    "rc": 1, "ok": False,
-                    "tail": (buf.getvalue() + f"\n{type(e).__name__}: "
-                             f"{e}")[-800:]}
-                result["ok"] = False
-        print(json.dumps(result, indent=2))
-        return 0 if result["ok"] else 1
-    if os.environ.get("BENCH_MODE") == "multislice":
-        # hierarchical two-level merge dryrun (round 20): the windowed
-        # round over a nested (dcn, ici) mesh — intra-slice
-        # psum/psum_scatter unchanged, top-k feature exchange over dcn —
-        # validated for tree equality vs the single-mesh sharded round
-        # at full top-k coverage + the per-rank round budget, with the
-        # statically pinned per-round DCN byte bill from the jaxpr audit
-        # embedded in-artifact.  Writes MULTICHIP_r07-format JSON.
-        import sys as _sys
-        _sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import __graft_entry__ as _ge
-
-        n_slices = int(os.environ.get("MULTISLICE_SLICES", "2"))
-        n_ranks = int(os.environ.get("MULTISLICE_RANKS", "4"))
-        result = {"num_slices": n_slices, "ranks_per_slice": n_ranks,
-                  "mode": "hierarchical_two_level_merge",
-                  "merges": {}, "ok": True}
-        for merge in ("psum", "scatter"):
-            import io
-            from contextlib import redirect_stdout
-
-            buf = io.StringIO()
-            try:
-                with redirect_stdout(buf):
-                    _ge.dryrun_multislice_windowed(n_slices, n_ranks, merge)
-                result["merges"][merge] = {
-                    "rc": 0, "ok": True,
-                    "tail": buf.getvalue()[-500:]}
-            except Exception as e:  # noqa: BLE001 — artifact robustness
-                result["merges"][merge] = {
-                    "rc": 1, "ok": False,
-                    "tail": (buf.getvalue() + f"\n{type(e).__name__}: "
-                             f"{e}")[-800:]}
-                result["ok"] = False
-        # the DCN byte budget, proven on the traced IR: per-contract
-        # dcn_bytes + the collective token sequences ride the artifact
-        try:
-            from lightgbm_tpu.analysis.jaxpr_audit import run_jaxpr_audit
-
-            rep = run_jaxpr_audit(
-                ["windowed_round_hierarchical_psum",
-                 "windowed_round_hierarchical_voting"], runtime=False)
-            result["jaxpr_audit"] = {
-                r.name: {"ok": r.ok,
-                         "dcn_bytes": r.detail.get("dcn_bytes"),
-                         "large_collectives":
-                             r.detail.get("large_collectives")}
-                for r in rep.results}
-            result["ok"] = result["ok"] and rep.ok
-        except Exception as e:  # noqa: BLE001 — artifact robustness
-            result["jaxpr_audit"] = {"error": f"{type(e).__name__}: {e}"}
-            result["ok"] = False
-        print(json.dumps(result, indent=2))
-        return 0 if result["ok"] else 1
-    if os.environ.get("BENCH_MODE") == "feature2d":
-        # 2-D (rows x features) windowed-round dryrun (round 24): the
-        # fused round over the (feature, row) mesh — per-feature-block
-        # histograms complete by layout (ZERO feature-axis collectives
-        # in the histogram phase), owned-feature election, winner's
-        # go/no-go row broadcast — validated for structural tree
-        # equality vs serial windowed growth + the per-rank round
-        # budget, with the per-axis collective byte bills from the
-        # jaxpr audit embedded in-artifact.  Writes MULTICHIP_r08-format
-        # JSON.
-        import sys as _sys
-        _sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import __graft_entry__ as _ge
-
-        d_r = int(os.environ.get("FEATURE2D_ROW_SHARDS", "2"))
-        d_f = int(os.environ.get("FEATURE2D_FEATURE_SHARDS", "4"))
-        grids = [(d_r, d_f, False), (d_f, d_r, True)]
-        result = {"mode": "feature2d_windowed", "grids": {}, "ok": True}
-        for rows, feats, quant in grids:
-            import io
-            from contextlib import redirect_stdout
-
-            key = f"{rows}x{feats}" + ("_int8" if quant else "_float")
-            buf = io.StringIO()
-            try:
-                with redirect_stdout(buf):
-                    _ge.dryrun_feature2d_windowed(rows, feats, quant)
-                result["grids"][key] = {
-                    "rc": 0, "ok": True,
-                    "tail": buf.getvalue()[-500:]}
-            except Exception as e:  # noqa: BLE001 — artifact robustness
-                result["grids"][key] = {
-                    "rc": 1, "ok": False,
-                    "tail": (buf.getvalue() + f"\n{type(e).__name__}: "
-                             f"{e}")[-800:]}
-                result["ok"] = False
-        # the per-axis byte bills, proven on the traced IR: the feature
-        # axis budget (go/no-go broadcast + election, no histograms)
-        # rides the artifact next to the row-axis histogram merge bill
-        try:
-            from lightgbm_tpu.analysis.jaxpr_audit import run_jaxpr_audit
-
-            rep = run_jaxpr_audit(
-                ["windowed_round_2d_float",
-                 "windowed_round_2d_quantized"], runtime=False)
-            result["jaxpr_audit"] = {
-                r.name: {"ok": r.ok,
-                         "axis_bytes": r.detail.get("axis_bytes"),
-                         "feature_bytes": r.detail.get("feature_bytes")}
-                for r in rep.results}
-            result["ok"] = result["ok"] and rep.ok
-        except Exception as e:  # noqa: BLE001 — artifact robustness
-            result["jaxpr_audit"] = {"error": f"{type(e).__name__}: {e}"}
-            result["ok"] = False
-        print(json.dumps(result, indent=2))
-        return 0 if result["ok"] else 1
     n = int(os.environ.get("BENCH_ROWS", 1_000_000))
     f = 28
     iters = int(os.environ.get("BENCH_ITERS", 30))
@@ -590,7 +413,7 @@ def main():
     def _embed_audit():
         from lightgbm_tpu.analysis.jaxpr_audit import verdict
 
-        _STATE["jaxpr_audit"] = verdict(runtime=False, exec_contracts=False)
+        _STATE["jaxpr_audit"] = verdict(exec_contracts=False)
 
     _guarded("jaxpr_audit", _embed_audit, budget_floor=30.0)
 

@@ -341,7 +341,7 @@ def main():
     def _embed_audit():
         from lightgbm_tpu.analysis.jaxpr_audit import verdict
 
-        _STATE["jaxpr_audit"] = verdict(runtime=False, exec_contracts=False)
+        _STATE["jaxpr_audit"] = verdict(exec_contracts=False)
         _STATE["workloads"]["jaxpr_audit"] = {
             "ok": _STATE["jaxpr_audit"].get("ok")}
 

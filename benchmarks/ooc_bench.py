@@ -1,6 +1,6 @@
-"""Out-of-core / partition benchmark: the round-12 data-path levers.
+"""Out-of-core benchmark: the round-12 data-path levers.
 
-Three levers, each emitting BENCH-style rows (bench.py contract — a full
+Two levers, each emitting BENCH-style rows (bench.py contract — a full
 JSON snapshot line printed + flushed after EVERY completed workload, so a
 driver timeout keeps everything measured so far):
 
@@ -12,21 +12,6 @@ driver timeout keeps everything measured so far):
   (ops/treegrow_ooc.py): streamed rows/sec across all histogram passes
   of a small boosting run, per chunk size, with bitwise parity vs
   in-memory training asserted in the artifact path itself.
-* ``partition_move`` — move-phase timing of the segment partition at
-  segment fractions {1.0, 0.25, 0.03}: the XLA permutation is O(N) flat
-  across fractions; the HBM-resident DMA kernel's traffic is segment-
-  proportional, so ON CHIP its move phase should FALL with the fraction
-  — the written-proof-shaped claim this artifact is queued to verify at
-  the next chip session (off-chip the kernel runs in interpret mode at a
-  reduced N for semantics, not speed; ``pallas_interpret`` rows are
-  marked so nobody reads them as device numbers).
-* ``megakernel_move`` (round 16) — the same fraction sweep through the
-  ROUND MEGAKERNEL (ops/round_pallas.py, partition + one-sweep window
-  histogram in one Pallas call) vs the three-pass XLA composite
-  (permutation + window gather + scatter histogram), with in-artifact
-  BITWISE parity of the produced histograms.  Off-chip rows are
-  interpret-mode (semantics + the parity proof, not speed); on chip the
-  expected story is the J7-pinned 3->1 bin-sweep cut.
 
 Env knobs: OOC_BENCH_ROWS (default 120k), OOC_BENCH_FEATURES (default
 16), OOC_BENCH_CHUNKS (csv, default "4096,16384,65536"),
@@ -174,136 +159,6 @@ def bench_spill_train(cache, X, y, n, chunks, rounds=2):
         _emit()
 
 
-def bench_partition_move(n_xla, platform):
-    """Move-phase timing at segment fractions: the O(N)-vs-segment-
-    proportional claim in one row.  On TPU the real DMA kernel runs; off
-    chip the interpret-mode kernel runs at a reduced N (semantics only)."""
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.partition import partition_rows
-
-    on_tpu = platform == "tpu"
-    n_pallas = n_xla if on_tpu else min(n_xla, 20_000)
-    entry = {"platform": platform, "n_xla": n_xla, "n_pallas": n_pallas,
-             "pallas_mode": "device" if on_tpu else "interpret",
-             "fractions": {}}
-    rng = np.random.RandomState(9)
-    for frac in (1.0, 0.25, 0.03):
-        row = {}
-        for tag, n, kw in (("xla", n_xla, dict(use_pallas=False)),
-                           ("pallas", n_pallas,
-                            dict(use_pallas=on_tpu, interpret=not on_tpu))):
-            seg_rows = max(int(n * frac), 8)
-            order = jnp.asarray(rng.permutation(n).astype(np.int32))
-            seg_id = np.full(n, -1, np.int32)
-            seg_id[:seg_rows] = 0
-            args = (order, jnp.asarray(seg_id),
-                    jnp.asarray([0], np.int32),
-                    jnp.asarray([seg_rows], np.int32),
-                    jnp.asarray(rng.rand(n) < 0.5))
-            out = partition_rows(*args, **kw)  # warm the executable
-            jax.block_until_ready(out)
-            reps = 3 if (tag == "pallas" and not on_tpu) else 10
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = partition_rows(*args, **kw)
-            jax.block_until_ready(out)
-            row[f"{tag}_ms"] = round(
-                (time.perf_counter() - t0) / reps * 1e3, 3)
-        entry["fractions"][str(frac)] = row
-        _STATE["workloads"]["partition_move"] = entry
-        _emit()
-
-
-def bench_megakernel_move(n_xla, platform, f=16, bins=32):
-    """Round-16 lever: one fused-round data phase (partition + window
-    histogram) through the megakernel vs the three-pass XLA composite,
-    at the same segment fractions as ``partition_move``.  The histogram
-    the kernel accumulates must be BITWISE the composite's (asserted in
-    the artifact path) — same contract tests/test_megakernel.py pins at
-    the grower level."""
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.histogram import histogram
-    from lightgbm_tpu.ops.partition import stable_partition_ranges
-    from lightgbm_tpu.ops.round_pallas import round_megakernel
-
-    on_tpu = platform == "tpu"
-    n = n_xla if on_tpu else min(n_xla, 20_000)
-    entry = {"platform": platform, "rows": n, "features": f,
-             "pallas_mode": "device" if on_tpu else "interpret",
-             "fractions": {}}
-    rng = np.random.RandomState(16)
-    bins_t = jnp.asarray(rng.randint(0, bins, (f, n)), jnp.int16)
-    grad = jnp.asarray(rng.randn(n), jnp.float32)
-    hess = jnp.asarray(rng.rand(n) + 0.5, jnp.float32)
-    mask = jnp.ones((n,), bool)
-    tile = 2  # slot 0 live, slot 1 dead — one segment per round phase
-
-    def make_three_pass(seg_rows):
-        @jax.jit
-        def three_pass(order, seg_id, seg_start, seg_len, go):
-            new_order, lefts = stable_partition_ranges(
-                order, seg_id, seg_start, seg_len, go)
-            rows = new_order[:seg_rows]  # the split segment (static size)
-            sub = bins_t[:, rows].T      # the materialized window copy
-            h = histogram(sub, grad[rows], hess[rows],
-                          (jnp.arange(seg_rows) < lefts[0]).astype(
-                              jnp.float32), bins, strategy="scatter")
-            return new_order, h
-
-        return three_pass
-
-    for frac in (1.0, 0.25, 0.03):
-        seg_rows = max(int(n * frac), 64)
-        three_pass = make_three_pass(seg_rows)
-        order = jnp.asarray(rng.permutation(n).astype(np.int32))
-        go = jnp.asarray(rng.rand(n) < 0.5)
-        seg_id = np.full(n, -1, np.int32)
-        seg_id[:seg_rows] = 0
-        seg_start = jnp.asarray([0, 0], jnp.int32)
-        seg_len = jnp.asarray([seg_rows, 0], jnp.int32)
-        n_left = jnp.asarray(
-            [int(np.asarray(go)[:seg_rows].sum()), 0], jnp.int32)
-        win_start = jnp.asarray([0, 0], jnp.int32)
-        win_cnt = n_left  # window = the left run
-        small = jnp.asarray([1, 0], jnp.int32)
-
-        def mk():
-            return round_megakernel(
-                bins_t, order, go, grad, hess, mask,
-                seg_start, seg_len, n_left, win_start, win_cnt, small,
-                num_bins=bins, leaf_tile=tile, fuse_tail=False,
-                interpret=not on_tpu)
-
-        raw, fresh = mk()
-        no3, h3 = three_pass(order, jnp.asarray(seg_id),
-                             jnp.asarray([0], jnp.int32),
-                             jnp.asarray([seg_rows], jnp.int32), go)
-        jax.block_until_ready((fresh, h3))
-        parity = bool(np.array_equal(np.asarray(fresh[0]), np.asarray(h3)))
-        row = {"bitwise_parity": parity, "segment_rows": seg_rows}
-        for tag, fn, reps in (("three_pass", lambda: three_pass(
-                order, jnp.asarray(seg_id), jnp.asarray([0], jnp.int32),
-                jnp.asarray([seg_rows], jnp.int32), go), 10),
-                              ("megakernel", mk, 3 if not on_tpu else 10)):
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = fn()
-            jax.block_until_ready(out)
-            row[f"{tag}_ms"] = round(
-                (time.perf_counter() - t0) / reps * 1e3, 3)
-        entry["fractions"][str(frac)] = row
-        _STATE["workloads"]["megakernel_move"] = entry
-        _emit()
-        if not parity:
-            raise AssertionError(
-                f"megakernel hist diverged from the three-pass composite "
-                f"at fraction {frac}")
-
-
 def main():
     import jax
 
@@ -328,10 +183,6 @@ def main():
     _guarded("spill_train",
              lambda: bench_spill_train(cache, X, y, n, chunks),
              budget_floor=30.0)
-    _guarded("partition_move", lambda: bench_partition_move(n, platform),
-             budget_floor=20.0)
-    _guarded("megakernel_move", lambda: bench_megakernel_move(n, platform),
-             budget_floor=20.0)
 
     _STATE["elapsed_s"] = round(time.monotonic() - _T0, 1)
     _emit()

@@ -4,8 +4,8 @@ The r5 compact-pair rework took the primary fused-step warmup from ~137 s
 to ~240 s (docs/NEXT.md lever 4).  Compile time on the remote Mosaic
 toolchain scales with traced-op count far more than with FLOPs, so this
 probe makes the trace size itself a measurable artifact: jaxpr equation
-counts for grow_tree_fast (the fused step's dominant component) and the
-fused windowed round at representative configs.  bench.py records the
+counts for grow_tree_fast (the fused step's dominant component) at
+representative configs.  bench.py records the
 primary-config count in every artifact (trace_eqns) so the next
 regression is caught structurally, off-chip, before it costs a 4-minute
 warmup on the chip.
@@ -56,43 +56,11 @@ def fast_grower_eqns(n=4096, f=28, num_leaves=31, num_bins=64, leaf_tile=8):
     return count_eqns(jaxpr.jaxpr)
 
 
-def windowed_round_eqns(n=4096, f=28, num_leaves=31, num_bins=64,
-                        leaf_tile=8, W=8192):
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops import treegrow_windowed as tw
-
-    state, g, h, gq, hq, qs, gt, ht = tw._w_init(
-        jnp.zeros((f, n), jnp.int16), jnp.zeros((n,), jnp.float32),
-        jnp.zeros((n,), jnp.float32), jnp.ones((n,), bool),
-        jnp.ones((n,), jnp.float32), jnp.full((f,), num_bins, jnp.int32),
-        jnp.full((f,), -1, jnp.int32), jnp.ones((f,), bool),
-        None, None, None,
-        num_leaves=num_leaves, num_bins=num_bins, params=SplitParams(),
-        leaf_tile=leaf_tile, use_pallas=False, quantize_bins=0,
-        hist_precision="f32", stochastic_rounding=False)
-    jaxpr = jax.make_jaxpr(
-        lambda s, b, gg, hh, m: tw._round_fused(
-            s, b, gg, hh, None, None, None, m,
-            jnp.full((f,), num_bins, jnp.int32),
-            jnp.full((f,), -1, jnp.int32), jnp.ones((f,), bool), None, None,
-            num_leaves=num_leaves, num_bins=num_bins, max_depth=-1,
-            params=SplitParams(), leaf_tile=leaf_tile, W=W,
-            use_pallas=False, quantize_bins=0, hist_precision="f32")
-    )(state, jnp.zeros((f, n), jnp.int16), g, h, jnp.ones((n,), bool))
-    return count_eqns(jaxpr.jaxpr)
-
-
 def main():
     tiles = [int(t) for t in sys.argv[1:]] or [8, 16]
     for t in tiles:
         print(f"grow_tree_fast   leaf_tile={t:2d}: "
               f"{fast_grower_eqns(leaf_tile=t):6d} eqns", flush=True)
-    for t in tiles:
-        print(f"windowed _round_fused leaf_tile={t:2d}: "
-              f"{windowed_round_eqns(leaf_tile=t):6d} eqns", flush=True)
 
 
 if __name__ == "__main__":

@@ -182,56 +182,6 @@ def bench_ooc(n_rows=3000, n_feat=8, rounds=3):
     return n_rows * passes / dt, passes
 
 
-def bench_megakernel(n_rows=2000, n_feat=10):
-    """Round-16 smoke: the megakernel round (interpret mode) must grow
-    the BIT-identical tree to the three-pass round, and the metrics
-    snapshot must carry the megakernel keys — so an off-chip CI run
-    catches megakernel regressions in the artifact path, not just in
-    tier-1."""
-    import time
-
-    import jax.numpy as jnp
-    import numpy as np
-    from lightgbm_tpu.binning import DatasetBinner
-    from lightgbm_tpu.obs import metrics as _obs
-    from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-
-    rng = np.random.RandomState(7)
-    X = rng.randn(n_rows, n_feat)
-    y = X @ rng.randn(n_feat) + 0.2 * rng.randn(n_rows)
-    binner = DatasetBinner.fit(X, max_bin=63)
-    args = (jnp.asarray(binner.transform(X).T, jnp.int16),
-            jnp.asarray(0.6 * y, jnp.float32), jnp.ones((n_rows,), jnp.float32),
-            jnp.ones((n_rows,), bool), jnp.ones((n_rows,), jnp.float32),
-            jnp.ones((n_feat,), bool),
-            jnp.asarray(binner.num_bins_per_feature),
-            jnp.asarray(binner.missing_bin_per_feature))
-    kw = dict(num_leaves=15, num_bins=64,
-              params=SplitParams(min_data_in_leaf=5.0), leaf_tile=4,
-              use_pallas=False)
-
-    os.environ["LGBMTPU_MEGAKERNEL"] = "0"
-    t0, l0 = grow_tree_windowed(*args, **kw)
-    os.environ["LGBMTPU_MEGAKERNEL"] = "interpret"
-    try:
-        t_start = time.perf_counter()
-        t1, l1 = grow_tree_windowed(*args, **kw)
-        dt = time.perf_counter() - t_start
-    finally:
-        os.environ.pop("LGBMTPU_MEGAKERNEL", None)
-    for name in t0._fields:
-        a, b = np.asarray(getattr(t0, name)), np.asarray(getattr(t1, name))
-        assert np.array_equal(a, b), f"megakernel diverged on {name}"
-    assert np.array_equal(np.asarray(l0), np.asarray(l1))
-
-    snap = _obs.snapshot()
-    _obs.validate_snapshot(snap)
-    assert snap["counters"].get("train_megakernel_trees_total", 0) >= 1, (
-        "metrics snapshot missing the megakernel counter")
-    return int(t0.num_leaves), dt
-
-
 def bench_serve(n_rows=600, n_feat=8, n_trees=12):
     """Round-18 serving-loop smoke: concurrent requests through the
     coalescing runtime must come back BITWISE equal to individual
@@ -410,177 +360,12 @@ def bench_continual(n_rows=600, n_feat=6, n_trees=6):
     return 2, cr.booster.num_trees(), dt
 
 
-def bench_multislice(n=1600, n_feat=10):
-    """Hierarchical two-level-merge smoke (round 20): a 2-slice x 2-rank
-    nested-mesh windowed training (needs >= 4 local devices — self-skips
-    below) must equal single-device windowed growth structurally at full
-    top-k coverage with zero retries/syncs, and the per-round DCN byte
-    bill must be pinned in the metrics-facing audit detail."""
-    import jax
-
-    if jax.device_count() < 4:
-        return None
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.analysis.jaxpr_audit import run_jaxpr_audit
-    from lightgbm_tpu.binning import DatasetBinner
-    from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-    from lightgbm_tpu.parallel.hierarchy import (
-        SlicedData, grow_tree_windowed_hierarchical)
-    from lightgbm_tpu.parallel.mesh import make_mesh_hierarchical
-
-    rng = np.random.RandomState(5)
-    X = rng.randn(n, n_feat)
-    y = X @ rng.randn(n_feat) + 0.2 * rng.randn(n)
-    binner = DatasetBinner.fit(X, max_bin=31)
-    bins = binner.transform(X)
-    grad = jnp.asarray(0.6 * y, jnp.float32)
-    hess = jnp.ones((n,), jnp.float32)
-    kw = dict(num_leaves=15, num_bins=32,
-              params=SplitParams(min_data_in_leaf=5.0), leaf_tile=4,
-              use_pallas=False)
-    t0 = time.perf_counter()
-    tree_s, _ = grow_tree_windowed(
-        jnp.asarray(bins.T, jnp.int16), grad, hess, jnp.ones((n,), bool),
-        jnp.ones((n,), jnp.float32), jnp.ones((n_feat,), bool),
-        jnp.asarray(binner.num_bins_per_feature),
-        jnp.asarray(binner.missing_bin_per_feature), **kw)
-    sd = SlicedData(make_mesh_hierarchical(2, 2), bins,
-                    binner.num_bins_per_feature,
-                    binner.missing_bin_per_feature)
-    stats = {}
-    tree_h, leaf_h = grow_tree_windowed_hierarchical(
-        sd, sd.pad_rows(np.asarray(grad)), sd.pad_rows(np.asarray(hess)),
-        sd.row_valid, sd.pad_rows(np.ones(n, np.float32), fill=1.0),
-        jnp.ones((n_feat,), bool), merge="psum", top_k_features=n_feat,
-        stats=stats, **kw)
-    import jax as _jax
-    _jax.block_until_ready(leaf_h)
-    m = int(tree_s.num_leaves) - 1
-    assert int(tree_h.num_leaves) == m + 1
-    assert (np.asarray(tree_s.split_feature)[:m]
-            == np.asarray(tree_h.split_feature)[:m]).all()
-    assert stats["retries"] == 0 and stats["host_syncs"] == 0, stats
-    rep = run_jaxpr_audit(["windowed_round_hierarchical_psum"],
-                          runtime=False)
-    assert rep.ok, [f.format() for f in rep.findings]
-    dcn = rep.results[0].detail["dcn_bytes"]
-    assert 0 < dcn <= 16384
-    return int(tree_h.num_leaves), dcn, time.perf_counter() - t0
-
-
-def bench_feature2d(n=1600, n_feat=10):
-    """2-D (rows x features) windowed smoke (round 24): a 2x2
-    (row, feature) mesh training (needs >= 4 local devices — self-skips
-    below) must equal single-device windowed growth structurally with
-    zero retries/syncs, and the per-round feature-axis byte bill — the
-    go/no-go broadcast + election only, never histograms — must be
-    pinned in the metrics-facing audit detail."""
-    import jax
-
-    if jax.device_count() < 4:
-        return None
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.analysis.contracts import _2D_FEATURE_BUDGET
-    from lightgbm_tpu.analysis.jaxpr_audit import run_jaxpr_audit
-    from lightgbm_tpu.binning import DatasetBinner
-    from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-    from lightgbm_tpu.parallel.feature2d import (
-        Sharded2DData, grow_tree_windowed_feature2d)
-    from lightgbm_tpu.parallel.mesh import make_mesh_2d
-
-    rng = np.random.RandomState(5)
-    X = rng.randn(n, n_feat)
-    y = X @ rng.randn(n_feat) + 0.2 * rng.randn(n)
-    binner = DatasetBinner.fit(X, max_bin=31)
-    bins = binner.transform(X)
-    grad = jnp.asarray(0.6 * y, jnp.float32)
-    hess = jnp.ones((n,), jnp.float32)
-    kw = dict(num_leaves=15, num_bins=32,
-              params=SplitParams(min_data_in_leaf=5.0), leaf_tile=4,
-              use_pallas=False)
-    t0 = time.perf_counter()
-    tree_s, _ = grow_tree_windowed(
-        jnp.asarray(bins.T, jnp.int16), grad, hess, jnp.ones((n,), bool),
-        jnp.ones((n,), jnp.float32), jnp.ones((n_feat,), bool),
-        jnp.asarray(binner.num_bins_per_feature),
-        jnp.asarray(binner.missing_bin_per_feature), **kw)
-    sd = Sharded2DData(make_mesh_2d(2, 2), bins,
-                       binner.num_bins_per_feature,
-                       binner.missing_bin_per_feature)
-    stats = {}
-    tree_d, leaf_d = grow_tree_windowed_feature2d(
-        sd, sd.pad_rows_device(grad, jnp.float32),
-        sd.pad_rows_device(hess, jnp.float32), sd.row_valid,
-        sd.pad_rows_device(np.ones(n, np.float32), jnp.float32, fill=1.0),
-        jnp.ones((sd.f_pad,), bool).at[n_feat:].set(False),
-        stats=stats, **kw)
-    jax.block_until_ready(leaf_d)
-    m = int(tree_s.num_leaves) - 1
-    assert int(tree_d.num_leaves) == m + 1
-    assert (np.asarray(tree_s.split_feature)[:m]
-            == np.asarray(tree_d.split_feature)[:m]).all()
-    assert stats["retries"] == 0 and stats["host_syncs"] == 0, stats
-    rep = run_jaxpr_audit(["windowed_round_2d_float"], runtime=False)
-    assert rep.ok, [f.format() for f in rep.findings]
-    fb = rep.results[0].detail["feature_bytes"]
-    assert 0 < fb <= _2D_FEATURE_BUDGET
-    return int(tree_d.num_leaves), fb, time.perf_counter() - t0
-
-
-def bench_fleet(b=16, n_rows=256, n_feat=6, n_trees=3):
-    """Round-20 fleet smoke: a B-lane fleet trained as one dispatch per
-    round must leave every lane's served predictions bitwise equal to
-    the same lane trained alone through ``lgb.train_fleet`` at B=1, with
-    the warm round budget (dispatches == rounds, 0 syncs/retries/
-    compiles) pinned from the fleet_round event ledger — the off-chip CI
-    catch for batched-training regressions."""
-    import numpy as np
-    import lightgbm_tpu as lgb
-    from lightgbm_tpu.obs import metrics as _obs
-
-    rng = np.random.RandomState(20)
-    X = rng.rand(n_rows, n_feat)
-    labels = (rng.rand(b, n_rows) > 0.5).astype(np.float64)
-    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
-              "min_data_in_leaf": 5, "seed": 3}
-    ds = lgb.Dataset(X, label=labels[0])
-    ev0 = len(_obs.events("fleet_round"))
-    t0 = time.perf_counter()
-    fb = lgb.train_fleet(params, ds, labels, num_boost_round=n_trees)
-    dt = time.perf_counter() - t0
-    warm = [e for e in _obs.events("fleet_round")[ev0:]
-            if e.get("iteration", 0) > 1]
-    assert warm and all(
-        e.get("dispatches") == e.get("rounds") and e.get("host_syncs") == 0
-        and e.get("retries") == 0 and e.get("compiles") == 0
-        for e in warm), f"warm fleet round budget broke: {warm}"
-    Q = rng.rand(64, n_feat)
-    for lane in (0, b // 2, b - 1):
-        ds1 = lgb.Dataset(X, label=labels[lane])
-        solo = lgb.train_fleet(dict(params), ds1, labels[lane:lane + 1],
-                               num_boost_round=n_trees)
-        assert np.array_equal(
-            fb.booster(lane).predict(Q, raw_score=True),
-            solo.booster(0).predict(Q, raw_score=True)), (
-            f"fleet lane {lane} diverged from its B=1 run")
-    snap = _obs.snapshot()
-    _obs.validate_snapshot(snap)
-    assert "train_fleet_models_total" in snap["counters"]
-    assert "fleet_models" in snap["gauges"]
-    return b, n_trees, dt
-
-
 def main():
     n = int(os.environ.get("SMOKE_ROWS", 1_000_000))
     iters = int(os.environ.get("SMOKE_ITERS", 10))
     which = (sys.argv[1].split(",") if len(sys.argv) > 1
              else ["rank", "multiclass", "predict", "serve", "ooc",
-                   "megakernel", "continual", "fleet", "fleet_serve",
-                   "multislice", "feature2d"])
+                   "continual", "fleet_serve"])
     if "rank" in which:
         ips = bench_rank(n, q_len=128, iters=iters)
         print(f"lambdarank {n//1000}k rows x64f q128 63bins: {ips:.2f} iters/sec", flush=True)
@@ -600,46 +385,17 @@ def main():
         print(f"out_of_core 3k rows x8f: {rps:.0f} streamed rows/sec spill "
               f"({passes} hist passes, resident+spill bitwise parity)",
               flush=True)
-    if "megakernel" in which:
-        leaves, dt = bench_megakernel()
-        print(f"megakernel 2k rows x10f: {leaves}-leaf tree bitwise == "
-              f"three-pass round ({dt:.1f}s interpret, snapshot keys ok)",
-              flush=True)
     if "continual" in which:
         rollovers, trees, dt = bench_continual()
         print(f"continual 600 rows x6f: {rollovers} zero-downtime "
               f"rollovers (refit+append) -> {trees} trees, served "
               f"bitwise, staleness drops, snapshot keys ok ({dt:.1f}s)",
               flush=True)
-    if "fleet" in which:
-        b, trees, dt = bench_fleet()
-        print(f"fleet {b} boosters x256 rows x6f: {trees} rounds at one "
-              f"dispatch/round, lanes bitwise == their B=1 runs, warm "
-              f"budget pinned ({dt:.1f}s)", flush=True)
     if "fleet_serve" in which:
         reqs, dt = bench_fleet_serve()
         print(f"fleet_serve 2 replicas x{reqs} requests: injected replica "
               f"death, 0 lost, bitwise parity, requeued + restarted, "
               f"snapshot keys ok ({dt:.1f}s)", flush=True)
-    if "multislice" in which:
-        got = bench_multislice()
-        if got is None:
-            print("multislice: skipped (< 4 local devices)", flush=True)
-        else:
-            leaves, dcn, dt = got
-            print(f"multislice 1.6k rows x10f on 2x2 nested mesh: "
-                  f"{leaves}-leaf tree == single-device at full top-k, "
-                  f"dcn_bytes/round={dcn} pinned ({dt:.1f}s)", flush=True)
-    if "feature2d" in which:
-        got = bench_feature2d()
-        if got is None:
-            print("feature2d: skipped (< 4 local devices)", flush=True)
-        else:
-            leaves, fb, dt = got
-            print(f"feature2d 1.6k rows x10f on 2x2 (rows x features) "
-                  f"mesh: {leaves}-leaf tree == single-device, "
-                  f"feature_bytes/round={fb} pinned, hist merge row-axis "
-                  f"only ({dt:.1f}s)", flush=True)
 
 
 if __name__ == "__main__":

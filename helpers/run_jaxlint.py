@@ -4,11 +4,9 @@
     python helpers/run_jaxlint.py                  # AST lint + locks + jaxpr
     python helpers/run_jaxlint.py --ast-only       # R-rules only, no JAX
     python helpers/run_jaxlint.py --locks-only     # L-rules only, no JAX
-    python helpers/run_jaxlint.py --no-runtime     # audit without the
-                                                   # executing ledger check
     python helpers/run_jaxlint.py --show-suppressed
     python helpers/run_jaxlint.py lightgbm_tpu/ops --rules R1,R3
-    python helpers/run_jaxlint.py --jaxpr --contract windowed_round_float
+    python helpers/run_jaxlint.py --jaxpr --contract predict_warm_single
 
 Layer 1 (jaxlint, rules R1-R17) scans source ASTs and runs without
 touching JAX device state.  Layer 2 (the concurrency layer, rules L1-L5,
@@ -17,8 +15,7 @@ ordering, blocking calls under locks, guard discipline, Condition.wait
 predicates, and thread lifecycle — also pure AST, also no JAX.  Layer 3
 (jaxpr audit, rules J1-J6) traces the registered flagship executables
 hermetically on the host CPU and verifies their IR contracts
-(analysis/contracts.py) — the layer that sees through the
-closure-dispatched round body.  A default full scan runs layers 1+2 in
+(analysis/contracts.py).  A default full scan runs layers 1+2 in
 one pass (same rule registry) and piggybacks layer 3 behind them;
 ``--ast-only`` / ``--locks-only`` scope to one AST-side layer, and
 ``--list-rules``, ``--rules`` subsets, and explicit sub-package paths
@@ -29,20 +26,8 @@ tests/test_lock_lint.py + tests/test_jaxpr_audit.py enforce in tier-1),
 1 = findings, 2 = bad usage.
 """
 
-import os
 import sys
 from pathlib import Path
-
-# the jaxpr layer's sharded contracts want a loopback multi-device mesh;
-# this must land BEFORE the lightgbm_tpu import below pulls jax in (under
-# `python -m lightgbm_tpu.analysis` the parent package import beats main(),
-# so the audit there runs on however many devices already exist — the
-# contracts trace identically, only the lowering differs)
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8"
-                               ).strip()
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
@@ -94,9 +79,8 @@ if __name__ == "__main__":
         sys.exit(main(argv))
     rc = main(argv)
     if not (ast_only or locks_only or narrow or scoped):
-        # layer 3 shares the exit-code contract; forward the flags it
-        # understands (--no-runtime skips the executing ledger check)
-        passthru = [a for a in argv
-                    if a in ("--show-suppressed", "--no-runtime")]
+        # layer 3 shares the exit-code contract; forward the flag it
+        # understands
+        passthru = [a for a in argv if a == "--show-suppressed"]
         rc = max(rc, main(["--jaxpr"] + passthru))
     sys.exit(rc)

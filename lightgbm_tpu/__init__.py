@@ -21,8 +21,7 @@ from .serve import runtime as _serve_runtime_mod
 # `lightgbm_tpu.serve` resolves to the entry-point FUNCTION (engine.serve);
 # the module itself stays importable as `from lightgbm_tpu.serve import ...`
 # (sys.modules resolution is unaffected by the attribute shadowing).
-from .engine import CVBooster, continual_train, cv, serve, train, train_fleet
-from .models.fleet import FleetBooster, FleetError
+from .engine import CVBooster, continual_train, cv, serve, train
 from .utils.guards import NonFiniteError
 from .utils.log import register_logger
 
@@ -46,9 +45,6 @@ __all__ = [
     "NonFiniteError",
     "register_logger",
     "train",
-    "train_fleet",
-    "FleetBooster",
-    "FleetError",
     "cv",
     "serve",
     "ServingRuntime",

@@ -19,10 +19,9 @@ R5    impure-under-jit         Python RNG / time.* / global mutation inside
 
 A second, trace-level layer lives in :mod:`.jaxpr_audit` +
 :mod:`.contracts` (rules J1-J6): it traces the registered flagship
-executables hermetically and verifies the one-dispatch /
-one-collective / all-donated contracts on the jaxpr — the properties
-the AST rules structurally cannot see through the shared round driver's
-closure dispatch.  Import it explicitly (it is not imported here, so
+executables hermetically and verifies the collective-free /
+all-donated contracts on the jaxpr — properties the AST rules
+structurally cannot see.  Import it explicitly (it is not imported here, so
 ``lightgbm_tpu.analysis`` stays JAX-free for pre-commit use).
 
 A third, concurrency layer lives in :mod:`.locks` (rules L1-L5): it
@@ -40,7 +39,7 @@ Usage::
     python -m lightgbm_tpu.analysis --rules R1,R3 ops/        # subset
     python -m lightgbm_tpu.analysis --strict-pragmas          # stale=fail
     python -m lightgbm_tpu.analysis --jaxpr                   # traced-IR audit
-    python -m lightgbm_tpu.analysis --jaxpr --contract windowed_round_float
+    python -m lightgbm_tpu.analysis --jaxpr --contract predict_warm_single
 
 or from tests::
 

@@ -13,8 +13,7 @@ Three layers, one entry point (docs/ANALYSIS.md):
 * ``--jaxpr`` — the **jaxpr executable audit** (rules J1-J6 over the
   registered contracts, analysis/contracts.py).  Traces the flagship
   executables hermetically on the host CPU; ``--contract NAME`` selects
-  a subset (repeatable), ``--no-runtime`` skips the DispatchCounter
-  ledger cross-check (which executes a tiny sharded training).
+  a subset (repeatable).
 
 Exit status 0 when no unsuppressed findings, 1 otherwise, 2 on bad usage
 — so the pytest gates (tests/test_jaxlint_gate.py, tests/
@@ -25,7 +24,6 @@ one contract.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 from pathlib import Path
 
@@ -34,24 +32,7 @@ from . import rules  # noqa: F401
 from . import locks  # noqa: F401  — registers L1-L5
 
 
-def _ensure_loopback_devices() -> None:
-    """Arm the loopback host-device env for the sharded contracts if jax
-    has not loaded yet.  Under ``python -m lightgbm_tpu.analysis`` the
-    parent package import pulls jax in before main() runs, so this is
-    usually a no-op there — the audit then runs on however many devices
-    exist (the collectives trace identically; only the lowering differs).
-    helpers/run_jaxlint.py sets the flag before ANY import, and the
-    pytest gate inherits conftest's 8-device flag."""
-    if "jax" in sys.modules:
-        return
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-
-
 def _main_jaxpr(args) -> int:
-    _ensure_loopback_devices()
     from . import jaxpr_audit
     from .contracts import CONTRACTS
 
@@ -72,7 +53,7 @@ def _main_jaxpr(args) -> int:
             print(f"error: unknown contracts {unknown}; known: "
                   f"{sorted(CONTRACTS)}", file=sys.stderr)
             return 2
-    report = jaxpr_audit.run_jaxpr_audit(names, runtime=not args.no_runtime)
+    report = jaxpr_audit.run_jaxpr_audit(names)
     for f in report.findings:
         print(f.format())
     if args.show_suppressed:
@@ -84,8 +65,6 @@ def _main_jaxpr(args) -> int:
         print(f"jaxpr-audit: {r.name}: "
               f"{'ok' if r.ok else f'{len(r.findings)} finding(s)'}"
               f"{extra}", file=sys.stderr)
-    for merge, summary in report.ledger.items():
-        print(f"jaxpr-audit: ledger[{merge}]: {summary}", file=sys.stderr)
     n, w = len(report.findings), len(report.waived)
     print(f"jaxpr-audit: {n} finding(s), {w} waived", file=sys.stderr)
     return 0 if report.ok else 1
@@ -123,10 +102,6 @@ def main(argv=None) -> int:
     parser.add_argument("--list-contracts", action="store_true",
                         help="print the contract + J-rule catalogue and "
                              "exit (implies --jaxpr)")
-    parser.add_argument("--no-runtime", action="store_true",
-                        help="--jaxpr: skip the DispatchCounter ledger "
-                             "cross-check (pure trace/lower, no "
-                             "execution)")
     args = parser.parse_args(argv)
 
     if args.locks and (args.jaxpr or args.contract or args.list_contracts

@@ -2,11 +2,9 @@
 "Jaxpr audit layer").
 
 A *contract* pins the traced-IR invariants of one flagship executable —
-the properties the AST rules structurally cannot see (R1/R6/R13's
-documented static limits: the shared ``_run_fused_rounds`` driver
-receives its donated dispatch as a closure, so a second collective or a
-dropped donation INSIDE the traced round body is invisible to source
-lint).  Each contract bundles:
+the properties the AST rules structurally cannot see (a collective or a
+dropped donation INSIDE a traced body is invisible to source lint).  Each
+contract bundles:
 
 * a **builder** that constructs the executable and hermetic example
   arguments (CPU, no chip, no network; ShapeDtypeStructs wherever the
@@ -17,17 +15,15 @@ lint).  Each contract bundles:
   traced jaxpr and lowered StableHLO:
 
   - ``collectives``: the exact ordered ``prim@axis`` sequence the
-    executable may contain (J1).  Declaring the order pins cross-variant
-    consistency: the psum and scatter merge variants share the same
-    protocol spine (declared via ``spine``), so an accidental reorder or
-    an extra collective in either fails the audit, not the chip session.
+    executable may contain (J1); ``()`` means the body must be
+    collective-free.
   - ``donated_args``: positional args whose buffers are donated; J2
     asserts every live donated leaf is actually consumable (and, where
     the platform lowers aliasing, actually aliased).
   - ``max_const_bytes``: J5's baked-constant ceiling for this trace.
-  - ``max_live_bytes``: J6's conservative peak-live-bytes budget — an
-    O(L*F*B) state blowup in the round body fails CI here before it
-    fails allocation on a v5e.
+  - ``max_live_bytes``: J6's conservative peak-live-bytes budget — a
+    state blowup in the body fails CI here before it fails allocation
+    on a v5e.
 
 Contracts are DECLARED NEXT TO the invariants they pin, in this module,
 with contract-level **waivers** replacing line pragmas (a traced jaxpr
@@ -44,7 +40,6 @@ Adding a contract::
         collectives=("psum@data",),     # () = the body must be collective-free
         donated_args=(0,),
         max_live_bytes=1 << 22,
-        family="my_family", spine=(0, 0),
     )
     def _build_my_executable() -> Target:
         ...
@@ -62,11 +57,8 @@ import functools
 import inspect
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
-# shared hermetic shapes: every fixture sits far below one W-ladder rung
-# (n < 8192 => the single rung W=8192 covers any round), so the windowed
-# contracts trace the same one-rung executable the tier-1 budget pins run
-_N, _F, _L, _TILE, _BINS = 512, 8, 7, 4, 32
-_W = 8192  # the floor rung: _window_size(n // 2, n) for every n < 8192
+# shared hermetic shapes
+_F, _BINS = 8, 32
 
 
 @dataclasses.dataclass
@@ -89,8 +81,6 @@ class Contract:
     donated_args: Tuple[int, ...]
     max_const_bytes: int
     max_live_bytes: int
-    family: str
-    spine: Tuple[int, int]  # (prefix, suffix) lengths shared family-wide
     waivers: Mapping[str, str]
     file: str
     line: int
@@ -98,22 +88,6 @@ class Contract:
     # e.g. trains a toy model.  Cost-sensitive callers (bench.py on chip,
     # where every compile is a remote Mosaic compile) can exclude these.
     executes: bool = False
-    # J7 (hbm-sweep-bound): positional index of the bin-matrix argument
-    # and the per-round sweep budget the statically estimated bin-matrix
-    # bytes-read must stay under.  None = J7 not pinned for this contract
-    # (the sweep estimate is only meaningful at W≈N fixture shapes — see
-    # the *_sweeps contracts below).
-    bin_arg: Optional[int] = None
-    max_bin_sweeps: Optional[float] = None
-    # per-axis J1 accounting (the hierarchical merge's byte pin,
-    # analogous to J7's sweep bound): total operand bytes of collectives
-    # whose axes include the dcn axis must stay under this — ≤ top-k
-    # histograms' worth per round.  None = no dcn traffic declared.
-    dcn_max_bytes: Optional[int] = None
-    # the feature-axis twin (the 2-D round's pin): ≤ the winner's
-    # go/no-go row broadcast + election scalars per round.  None = no
-    # feature-axis traffic declared.
-    feature_max_bytes: Optional[int] = None
 
 
 CONTRACTS: Dict[str, Contract] = {}
@@ -124,14 +98,8 @@ def contract(name: str, *, description: str,
              donated_args: Tuple[int, ...] = (),
              max_const_bytes: int = 1 << 16,
              max_live_bytes: int,
-             family: str = "",
-             spine: Tuple[int, int] = (0, 0),
              waivers: Optional[Mapping[str, str]] = None,
-             executes: bool = False,
-             bin_arg: Optional[int] = None,
-             max_bin_sweeps: Optional[float] = None,
-             dcn_max_bytes: Optional[int] = None,
-             feature_max_bytes: Optional[int] = None):
+             executes: bool = False):
     """Register a contract; the decorated function is its builder."""
 
     def deco(build: Callable[[], Target]) -> Callable[[], Target]:
@@ -143,12 +111,9 @@ def contract(name: str, *, description: str,
             collectives=tuple(collectives),
             donated_args=tuple(donated_args),
             max_const_bytes=max_const_bytes,
-            max_live_bytes=max_live_bytes, family=family, spine=spine,
+            max_live_bytes=max_live_bytes,
             waivers=dict(waivers or {}), file=frame.filename,
-            line=frame.lineno, executes=executes,
-            bin_arg=bin_arg, max_bin_sweeps=max_bin_sweeps,
-            dcn_max_bytes=dcn_max_bytes,
-            feature_max_bytes=feature_max_bytes)
+            line=frame.lineno, executes=executes)
         return build
 
     return deco
@@ -161,525 +126,6 @@ def contract(name: str, *, description: str,
 def _sds(shape, dtype):
     import jax
     return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _split_params():
-    from ..ops.split import SplitParams
-    return SplitParams(min_data_in_leaf=5.0)
-
-
-def _round_common(n_leaves=_L, bins=_BINS, tile=_TILE):
-    return dict(num_leaves=n_leaves, num_bins=bins, params=_split_params(),
-                leaf_tile=tile)
-
-
-def _single_state(quantize_bins: int, n=_N, f=_F, common=None):
-    """WState avals for the single-device round via eval_shape over
-    ``_w_init`` — abstract, nothing executes."""
-    import functools as ft
-
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops import treegrow_windowed as tw
-
-    row = lambda dt: _sds((n,), dt)  # noqa: E731
-    pf = _sds((f,), jnp.int32)
-    out = jax.eval_shape(
-        ft.partial(tw._w_init.__wrapped__, use_pallas=False,
-                   quantize_bins=quantize_bins, hist_precision="f32",
-                   stochastic_rounding=False, **(common or _round_common())),
-        _sds((f, n), jnp.int16), row(jnp.float32), row(jnp.float32),
-        row(jnp.bool_), row(jnp.float32), pf, pf, _sds((f,), jnp.bool_),
-        None, None, None)
-    return out[0]
-
-
-def _windowed_single_target(quantize_bins: int, n=_N, f=_F, tile=_TILE,
-                            megakernel: bool = False) -> Target:
-    import jax.numpy as jnp
-
-    from ..ops import treegrow_windowed as tw
-
-    common = _round_common(tile=tile)
-    row = lambda dt: _sds((n,), dt)  # noqa: E731
-    pf = _sds((f,), jnp.int32)
-    q = bool(quantize_bins)
-    args = (
-        _single_state(quantize_bins, n, f, common), _sds((f, n), jnp.int16),
-        row(jnp.float32), row(jnp.float32),
-        row(jnp.int8) if q else None, row(jnp.int8) if q else None,
-        _sds((3,), jnp.float32) if q else None,
-        row(jnp.bool_), pf, pf, _sds((f,), jnp.bool_),
-        None, None, None, None, None, None,
-    )
-    kw = dict(max_depth=-1, W=_W, use_pallas=False,
-              quantize_bins=quantize_bins, hist_precision="f32",
-              megakernel=megakernel, mk_interpret=megakernel, **common)
-    return Target(tw._round_fused, args, kw,
-                  note=("megakernel round (interpret-mode Pallas call in "
-                        "the trace)" if megakernel else
-                        "single-device fused round (CPU trace: XLA "
-                        "histogram fallback, Pallas off)"))
-
-
-def audit_mesh():
-    """The loopback mesh the sharded contracts trace over: up to 4 host
-    devices (tests force 8 via conftest's XLA_FLAGS; the CLI sets the
-    same flag before jax loads).  On a single-device interpreter the
-    collectives still trace identically — axis size only changes the
-    lowering, not the jaxpr."""
-    import jax
-
-    from ..parallel.mesh import make_mesh
-    return make_mesh(min(4, len(jax.devices())))
-
-
-def _windowed_sharded_target(merge: str, megakernel: bool = False) -> Target:
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel import data_parallel as dp
-    from ..parallel.mesh import data_axis_size
-
-    mesh = audit_mesh()
-    n_dev = data_axis_size(mesh)
-    f_pad = (-(-_F // n_dev) * n_dev) if merge == "scatter" else _F
-    row = lambda dt: _sds((_N,), dt)  # noqa: E731
-    bt = _sds((f_pad, _N), jnp.int16)
-    pf = _sds((f_pad,), jnp.int32)
-    fm = _sds((f_pad,), jnp.bool_)
-    init_statics = tuple(sorted(dict(
-        _round_common(), use_pallas=False, quantize_bins=0,
-        hist_precision="f32", stochastic_rounding=False).items()))
-    init_fn = dp._windowed_init_sharded(mesh, merge, (), init_statics)
-    state = jax.eval_shape(init_fn, bt, row(jnp.float32), row(jnp.float32),
-                           row(jnp.bool_), row(jnp.float32), pf, pf, fm)[0]
-    round_statics = tuple(sorted(dict(
-        _round_common(), max_depth=-1, use_pallas=False, quantize_bins=0,
-        hist_precision="f32", has_cat=False,
-        pallas_partition=False, megakernel=megakernel,
-        mk_interpret=megakernel).items()))
-    fn = dp._windowed_round_sharded(mesh, _W, merge, (), round_statics)
-    args = (state, bt, row(jnp.float32), row(jnp.float32), row(jnp.bool_),
-            pf, pf, fm)
-    return Target(fn, args, {},
-                  note=f"jit(shard_map) fused round, merge={merge!r}, "
-                       f"{n_dev}-device loopback mesh"
-                       + (", megakernel round body" if megakernel else ""))
-
-
-# the sharded round's protocol spine, identical across merge variants
-# (J1 family check): window verification + info-vector merge...
-_ROUND_PREFIX = (
-    "psum@data",   # global left counts (window-child election)
-    "psum@data",   # global segment lengths (same election)
-    "pmin@data",   # info: ok — one rank breaching skips the round fleet-wide
-    "pmax@data",   # info: total — corrected W must cover the worst rank
-)
-# ...and the two trailing info merges after the split search
-_ROUND_SUFFIX = (
-    "pmax@data",   # info: whint — laddered W covers the worst rank
-    "pmin@data",   # info: finite — rank-consistent non-finite guard
-)
-
-# the owned-feature winner election (_merge_best + _split_tables) between
-# the scatter merge and the info suffix: globalize the feature index,
-# elect by gain, psum-mask-broadcast every BestSplit field from the owner
-_SCATTER_ELECTION = (
-    "axis_index@data",             # _split_tables: this rank's F/R offset
-    "axis_index@data",             # _merge_best: owner election index
-    "pmax@data", "pmin@data",      # gain max, lowest-rank tie-break
-) + ("psum@data",) * 12            # one masked broadcast per BestSplit field
-
-
-# ---------------------------------------------------------------------------
-# windowed fused round (ops/treegrow_windowed.py, parallel/data_parallel.py)
-# ---------------------------------------------------------------------------
-
-@contract(
-    "windowed_round_float",
-    description="single-device fused windowed round, float histograms — "
-                "the one-dispatch donated executable tests/test_retrace.py "
-                "budget-pins; its body must stay collective-free, f64-free, "
-                "callback-free, with every donated WState buffer consumable",
-    collectives=(),
-    donated_args=(0,),
-    # measured peak ≈ 4.03 MB at the 512x8/L7/B32 fixture shape (the CPU
-    # fallback's vmapped window histogram dominates); 10 MB keeps ~2.5x
-    # headroom while still catching an O(L*F*B) state duplication
-    max_live_bytes=10 << 20,
-    family="windowed_single",
-)
-def _build_windowed_round_float() -> Target:
-    return _windowed_single_target(0)
-
-
-@contract(
-    "windowed_round_quantized",
-    description="single-device fused windowed round, int8-quantized config "
-                "(CPU trace: dequantized fallback histograms) — the wide-"
-                "regime default; same contract as the float round",
-    collectives=(),
-    donated_args=(0,),
-    max_live_bytes=10 << 20,
-    family="windowed_single",
-)
-def _build_windowed_round_quantized() -> Target:
-    return _windowed_single_target(16)
-
-
-@contract(
-    "windowed_round_sharded_psum",
-    description="SPMD fused windowed round over the ICI mesh, merge='psum' "
-                "(tree_learner=data): exactly ONE large in-dispatch "
-                "collective — the leaf-histogram psum — plus the declared "
-                "scalar protocol merges, all on the data axis, in order",
-    collectives=_ROUND_PREFIX + ("psum@data",) + _ROUND_SUFFIX,
-    donated_args=(0,),
-    max_live_bytes=10 << 20,  # sharded measured ≈ 4.09 MB
-    family="windowed_sharded",
-    spine=(len(_ROUND_PREFIX), len(_ROUND_SUFFIX)),
-)
-def _build_windowed_round_sharded_psum() -> Target:
-    return _windowed_sharded_target("psum")
-
-
-@contract(
-    "windowed_round_sharded_scatter",
-    description="SPMD fused windowed round, merge='scatter' "
-                "(tree_learner=voting): ONE large in-dispatch collective — "
-                "the psum_scatter histogram merge — then the owned-feature "
-                "winner election (all small-operand), same protocol spine "
-                "as the psum variant",
-    collectives=(_ROUND_PREFIX + ("psum_scatter@data",)
-                 + _SCATTER_ELECTION + _ROUND_SUFFIX),
-    donated_args=(0,),
-    max_live_bytes=10 << 20,  # sharded measured ≈ 4.09 MB
-    family="windowed_sharded",
-    spine=(len(_ROUND_PREFIX), len(_ROUND_SUFFIX)),
-)
-def _build_windowed_round_sharded_scatter() -> Target:
-    return _windowed_sharded_target("scatter")
-
-
-# ---------------------------------------------------------------------------
-# hierarchical two-level merge (parallel/hierarchy.py) — the multi-slice
-# round.  The intra-slice (ici) sequence must equal the legacy sharded
-# round's (tests/test_jaxpr_audit.py asserts the axis-mapped identity),
-# and the dcn-axis byte bill is pinned at ≤ top-k histograms' worth.
-# ---------------------------------------------------------------------------
-
-_HIER_TOPK = 4  # the fixture election width (k < F: a real sub-election)
-
-# scalar protocol merges span BOTH axes (window election + info vector
-# are global agreements); the histogram merge stays per-slice on ici
-_HIER_PREFIX = tuple(t.replace("@data", "@ici,dcn") for t in _ROUND_PREFIX)
-_HIER_SUFFIX = tuple(t.replace("@data", "@ici,dcn") for t in _ROUND_SUFFIX)
-# the dcn election: k gain scalars + k feature ids all_gathered, then the
-# elected k features' histogram columns psummed — the ONLY
-# histogram-shaped dcn operand (jaxlint R17's clean shape)
-_HIER_ELECTION = ("all_gather@dcn", "all_gather@dcn", "psum@dcn")
-_HIER_SCATTER_ELECTION = tuple(
-    t.replace("@data", "@ici") for t in _SCATTER_ELECTION)
-
-# the fixture's per-round dcn bill: C=2*tile candidates x 3 channels x
-# k features x B bins x 4 bytes for the elected-histogram psum, plus the
-# two (S, C, k) vote all_gathers and the 4-byte both-axes scalars — the
-# "top-k histograms' worth" promise, with ~1 KB scalar slack
-_HIER_DCN_BUDGET = 2 * _TILE * 3 * _HIER_TOPK * _BINS * 4 + 1024
-
-
-def _audit_mesh_hier():
-    """Loopback nested (dcn, ici) mesh: 2 slices x 2 ranks on the
-    virtual 8-device host (axis size only changes the lowering, not the
-    jaxpr — see audit_mesh)."""
-    import jax
-
-    from ..parallel.mesh import make_mesh_hierarchical
-    n = len(jax.devices())
-    if n >= 4:
-        return make_mesh_hierarchical(2, 2)
-    return make_mesh_hierarchical(min(n, 2), 1)
-
-
-def _windowed_hier_target(merge: str) -> Target:
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel import hierarchy as hy
-    from ..parallel.mesh import slice_axis_sizes
-
-    mesh = _audit_mesh_hier()
-    _, n_ici = slice_axis_sizes(mesh)
-    f_pad = (-(-_F // n_ici) * n_ici) if merge == "scatter" else _F
-    row = lambda dt: _sds((_N,), dt)  # noqa: E731
-    bt = _sds((f_pad, _N), jnp.int16)
-    pf = _sds((f_pad,), jnp.int32)
-    fm = _sds((f_pad,), jnp.bool_)
-    init_statics = tuple(sorted(dict(
-        _round_common(), use_pallas=False, quantize_bins=0,
-        hist_precision="f32", stochastic_rounding=False).items()))
-    init_fn = hy._windowed_init_hier(mesh, merge, _HIER_TOPK, (),
-                                     init_statics)
-    state = jax.eval_shape(init_fn, bt, row(jnp.float32), row(jnp.float32),
-                           row(jnp.bool_), row(jnp.float32), pf, pf, fm)[0]
-    round_statics = tuple(sorted(dict(
-        _round_common(), max_depth=-1, use_pallas=False, quantize_bins=0,
-        hist_precision="f32", has_cat=False, pallas_partition=False,
-        megakernel=False, mk_interpret=False).items()))
-    fn = hy._windowed_round_hier(mesh, _W, merge, _HIER_TOPK, (),
-                                 round_statics)
-    args = (state, bt, row(jnp.float32), row(jnp.float32), row(jnp.bool_),
-            pf, pf, fm)
-    return Target(fn, args, {},
-                  note=f"jit(shard_map) hierarchical round, intra-slice "
-                       f"merge={merge!r}, top_k={_HIER_TOPK}, nested "
-                       f"{mesh.devices.shape} loopback mesh")
-
-
-@contract(
-    "windowed_round_hierarchical_psum",
-    description="two-level fused windowed round over the nested "
-                "(dcn, ici) mesh, intra-slice merge='psum' "
-                "(tree_learner=data x num_slices>1): the slice-local "
-                "histogram psum rides ici UNCHANGED vs the single-level "
-                "round, the scalar protocol spans both axes, and the "
-                "only histogram-shaped dcn operand is the elected "
-                "top-k feature exchange — byte bill pinned",
-    collectives=(_HIER_PREFIX + ("psum@ici",) + _HIER_ELECTION
-                 + _HIER_SUFFIX),
-    donated_args=(0,),
-    max_live_bytes=10 << 20,  # measured ≈ 4.15 MB at the fixture shape
-    family="windowed_hierarchical",
-    spine=(len(_HIER_PREFIX), len(_HIER_SUFFIX)),
-    dcn_max_bytes=_HIER_DCN_BUDGET,
-)
-def _build_windowed_round_hierarchical_psum() -> Target:
-    return _windowed_hier_target("psum")
-
-
-@contract(
-    "windowed_round_hierarchical_voting",
-    description="two-level fused windowed round, intra-slice "
-                "merge='scatter' (tree_learner=voting x num_slices>1): "
-                "psum_scatter + owned-feature election over ici exactly "
-                "as the single-level scatter round, the dcn top-k "
-                "exchange inside each rank's owned feature block — the "
-                "full PV-Tree route, byte bill pinned",
-    collectives=(_HIER_PREFIX + ("psum_scatter@ici", "axis_index@ici")
-                 + _HIER_ELECTION + _HIER_SCATTER_ELECTION[1:]
-                 + _HIER_SUFFIX),
-    donated_args=(0,),
-    max_live_bytes=10 << 20,  # measured ≈ 4.13 MB at the fixture shape
-    family="windowed_hierarchical",
-    spine=(len(_HIER_PREFIX), len(_HIER_SUFFIX)),
-    dcn_max_bytes=_HIER_DCN_BUDGET,
-)
-def _build_windowed_round_hierarchical_voting() -> Target:
-    return _windowed_hier_target("scatter")
-
-
-# ---------------------------------------------------------------------------
-# 2-D (feature x row) sharded round (parallel/feature2d.py) — the wide-F
-# regime.  The histogram phase must cross the feature axis with ZERO
-# collectives (the tile's histograms are complete for the owned block by
-# layout); the feature axis carries only the winner's go/no-go row
-# broadcast and the owned-feature election, byte-billed and pinned.
-# ---------------------------------------------------------------------------
-
-def _audit_mesh_2d():
-    """Loopback 2-D (row, feature) mesh: 2 x 2 on the virtual 8-device
-    host (axis size only changes the lowering, not the jaxpr — see
-    audit_mesh)."""
-    import jax
-
-    from ..parallel.mesh import make_mesh_2d
-    n = len(jax.devices())
-    if n >= 4:
-        return make_mesh_2d(2, 2)
-    return make_mesh_2d(1, min(n, 2))
-
-
-def _windowed_2d_target(quantize_bins: int) -> Target:
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel import feature2d as f2d
-
-    mesh = _audit_mesh_2d()
-    q = bool(quantize_bins)
-    row = lambda dt: _sds((_N,), dt)  # noqa: E731
-    bt = _sds((_F, _N), jnp.int16)  # _F divides d_f=2: no dead padding
-    pf = _sds((_F,), jnp.int32)
-    fm = _sds((_F,), jnp.bool_)
-    init_statics = tuple(sorted(dict(
-        _round_common(), use_pallas=False, quantize_bins=quantize_bins,
-        hist_precision="f32", stochastic_rounding=False).items()))
-    init_names = ("quant_key",) if q else ()
-    init_fn = f2d._windowed_init_2d(mesh, init_names, init_statics)
-    init_args = (bt, row(jnp.float32), row(jnp.float32), row(jnp.bool_),
-                 row(jnp.float32), pf, pf, fm)
-    if q:
-        init_args = init_args + (_sds((2,), jnp.uint32),)
-    state = jax.eval_shape(init_fn, *init_args)[0]
-    round_statics = tuple(sorted(dict(
-        _round_common(), max_depth=-1, use_pallas=False,
-        quantize_bins=quantize_bins, hist_precision="f32", has_cat=False,
-        pallas_partition=False, megakernel=False,
-        mk_interpret=False).items()))
-    names = ("gq", "hq", "quant_scale") if q else ()
-    fn = f2d._windowed_round_2d(mesh, _W, names, round_statics)
-    args = (state, bt, row(jnp.float32), row(jnp.float32), row(jnp.bool_),
-            pf, pf, fm)
-    if q:
-        args = args + (row(jnp.int8), row(jnp.int8), _sds((3,), jnp.float32))
-    d_r, d_f = mesh.shape["data"], mesh.shape["feature"]
-    return Target(fn, args, {},
-                  note=f"jit(shard_map) 2-D fused round, "
-                       f"{d_r}x{d_f} (row x feature) loopback mesh"
-                       + (", int8-quantized config" if q else ""))
-
-
-# the winner's row decisions — computable only on the owner's feature
-# block — broadcast at round start, BEFORE the partition movement: the
-# round's only feature-axis data exchange
-_2D_DECIDE = ("axis_index@feature", "psum@feature")
-# the protocol spine: row-domain sums stay on the row axis alone (a
-# feature-axis sum would over-count the replicated rows d_f times);
-# idempotent info merges span both axes
-_2D_PREFIX = _2D_DECIDE + (
-    "psum@data",           # global left counts (window-child election)
-    "psum@data",           # global segment lengths (same election)
-    "pmin@data,feature",   # info: ok — idempotent, spans the full mesh
-    "pmax@data,feature",   # info: total
-)
-_2D_SUFFIX = (
-    "pmax@data,feature",   # info: whint
-    "pmin@data,feature",   # info: finite
-)
-# the owned-feature winner election (the scatter merge's machinery with
-# the FEATURE axis as the owning axis): globalize the block offset,
-# elect by gain, psum-mask-broadcast every BestSplit field from the owner
-_2D_ELECTION = (
-    "axis_index@feature",          # _split_tables: this block's F offset
-    "axis_index@feature",          # _merge_best: owner election index
-    "pmax@feature", "pmin@feature",  # gain max, lowest-block tie-break
-) + ("psum@feature",) * 12         # one masked broadcast per field
-
-# the per-round feature-axis byte bill: the go/no-go row broadcast
-# ((N_loc,) i32, worst case d_r=1) + the election's per-leaf broadcast +
-# scalar slack — a full histogram merge (3*F*B*4 per leaf pair) cannot fit
-_2D_FEATURE_BUDGET = 2 * _N * 4 + 1024
-
-
-@contract(
-    "windowed_round_2d_float",
-    description="SPMD fused windowed round over the 2-D (feature x row) "
-                "mesh, float histograms: the histogram phase is the row "
-                "psum ALONE — zero feature-axis collectives (the owned "
-                "block's histograms are complete by layout) — then the "
-                "owned-feature election and the winner's row-decision "
-                "broadcast, the only feature-axis traffic, byte-billed",
-    collectives=_2D_PREFIX + ("psum@data",) + _2D_ELECTION + _2D_SUFFIX,
-    donated_args=(0,),
-    max_live_bytes=10 << 20,  # measured ≈ 4.1 MB at the fixture shape
-    family="windowed_2d",
-    spine=(len(_2D_PREFIX), len(_2D_SUFFIX)),
-    feature_max_bytes=_2D_FEATURE_BUDGET,
-)
-def _build_windowed_round_2d_float() -> Target:
-    return _windowed_2d_target(0)
-
-
-@contract(
-    "windowed_round_2d_quantized",
-    description="SPMD fused windowed round over the 2-D mesh, int8-"
-                "quantized config (CPU trace: dequantized fallback "
-                "histograms) — the wide-F regime default; same sequence, "
-                "same feature-axis byte bill as the float round",
-    collectives=_2D_PREFIX + ("psum@data",) + _2D_ELECTION + _2D_SUFFIX,
-    donated_args=(0,),
-    max_live_bytes=10 << 20,
-    family="windowed_2d",
-    spine=(len(_2D_PREFIX), len(_2D_SUFFIX)),
-    feature_max_bytes=_2D_FEATURE_BUDGET,
-)
-def _build_windowed_round_2d_quantized() -> Target:
-    return _windowed_2d_target(16)
-
-
-# ---------------------------------------------------------------------------
-# round megakernel (ops/round_pallas.py) + J7 sweep pins
-# ---------------------------------------------------------------------------
-# J7's sweep estimate is shape-relative (the window gather reads W
-# columns), so the sweep-pinned contracts trace at n == _W == 8192 (still
-# exactly ONE ladder rung) with f=64/tile=2 to keep the decisions-gather
-# epsilon (tile/f) small: the legacy round's three window-scale reads
-# document as 3 + tile/f ≈ 3.03, the megakernel's single kernel charge as
-# 1 + tile/f ≈ 1.03.
-
-_NS, _FS, _TILES = 8192, 64, 2  # the W=N sweep-pin fixture shape
-
-
-@contract(
-    "windowed_round_megakernel",
-    description="single-device MEGAKERNEL round (ops/round_pallas.py, "
-                "interpret-mode Pallas call in the trace): partition + "
-                "one-sweep window histogram + on-core per-feature gain "
-                "reduction in ONE kernel — collective-free, donated, and "
-                "<= 1 bin-matrix sweep (+ the tile/f decisions-gather "
-                "epsilon) by J7's static estimate",
-    collectives=(),
-    donated_args=(0,),
-    # the kernel's ref plumbing + the vmapped on-core gain planes at the
-    # 8192x64 fixture measure ≈27 MB peak-live; 64 MB headroom still
-    # catches an O(L*F*B) state duplication
-    max_live_bytes=64 << 20,
-    family="windowed_single",
-    bin_arg=1,
-    max_bin_sweeps=1.1,
-)
-def _build_windowed_round_megakernel() -> Target:
-    return _windowed_single_target(0, n=_NS, f=_FS, tile=_TILES,
-                                   megakernel=True)
-
-
-@contract(
-    "windowed_round_three_pass_sweeps",
-    description="the LEGACY three-pass round at the same W=N fixture — "
-                "J7 documents its three bin-matrix sweeps (window gather "
-                "+ transpose of the materialized copy + the histogram's "
-                "int cast, ~3 + tile/f) next to the megakernel's one; "
-                "this contract is the baseline the 3->1 claim is pinned "
-                "against",
-    collectives=(),
-    donated_args=(0,),
-    max_live_bytes=64 << 20,  # the (W, F) window copy + scatter payloads
-    family="windowed_single",
-    bin_arg=1,
-    max_bin_sweeps=3.2,
-)
-def _build_windowed_round_three_pass_sweeps() -> Target:
-    return _windowed_single_target(0, n=_NS, f=_FS, tile=_TILES)
-
-
-@contract(
-    "windowed_round_sharded_megakernel_psum",
-    description="SPMD megakernel round, merge='psum': the kernel fuses "
-                "each rank's partition + window histogram, and the round "
-                "keeps the IDENTICAL collective protocol as the three-"
-                "pass sharded round (windowed_round_sharded_psum) — the "
-                "single large in-dispatch histogram merge UNCHANGED, "
-                "pinned by J1's exact-sequence + family-spine checks",
-    collectives=_ROUND_PREFIX + ("psum@data",) + _ROUND_SUFFIX,
-    donated_args=(0,),
-    max_live_bytes=10 << 20,
-    family="windowed_sharded",
-    spine=(len(_ROUND_PREFIX), len(_ROUND_SUFFIX)),
-)
-def _build_windowed_round_sharded_megakernel_psum() -> Target:
-    return _windowed_sharded_target("psum", megakernel=True)
 
 
 # ---------------------------------------------------------------------------
@@ -871,62 +317,10 @@ def _build_continual_refit_leaves() -> Target:
 
 
 # ---------------------------------------------------------------------------
-# fleet round (ops/treegrow_fleet.py)
-# ---------------------------------------------------------------------------
-
-_FB = 4  # fleet lanes in the fixture — small, but enough that a
-# superlinear state duplication (O(B^2) broadcast in the vmapped body)
-# overshoots the linear budget below
-
-
-@contract(
-    "fleet_round_batched",
-    description="the vmapped fleet round (B independent boosters, one "
-                "donated dispatch): the solo round body lifted over a "
-                "leading model axis plus the in-dispatch (B,5)->(5,) "
-                "info fold — vmap must add ZERO collectives vs. the "
-                "single-model round (J1), donation consumed on the "
-                "(B, ...) stacked state (J2), peak-live LINEAR in B at "
-                "the fixture shape (J6: B x the solo budget)",
-    collectives=(),
-    donated_args=(0,),
-    # the solo float round measures ~4.03 MB at this fixture under its
-    # 10 MB budget; linear-in-B means the fleet stays under _FB x that —
-    # an accidental O(B^2) buffer (e.g. a cross-lane broadcast in the
-    # histogram fallback) fails HERE, before it fails allocation at
-    # B=4096 on chip
-    max_live_bytes=_FB * (10 << 20),
-    family="fleet",
-)
-def _build_fleet_round_batched() -> Target:
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops import treegrow_fleet as tf
-
-    common = _round_common()
-    solo = _single_state(0, _N, _F, common)
-    stacked = jax.tree_util.tree_map(
-        lambda s: _sds((_FB,) + tuple(s.shape), s.dtype), solo)
-    row = lambda dt: _sds((_FB, _N), dt)  # noqa: E731
-    pf = _sds((_F,), jnp.int32)
-    args = (stacked, _sds((_F, _N), jnp.int16),
-            row(jnp.float32), row(jnp.float32),
-            None, None, None,
-            row(jnp.bool_), pf, pf, _sds((_F,), jnp.bool_))
-    kw = dict(max_depth=-1, W=_W, use_pallas=False, quantize_bins=0,
-              hist_precision="f32", pallas_partition=False, **common)
-    return Target(tf._fleet_round, args, kw,
-                  note="B=4 float fleet round (CPU trace: XLA histogram "
-                       "fallback; the quantized/Pallas lanes share the "
-                       "solo contracts' variant coverage)")
-
-
-# ---------------------------------------------------------------------------
 # spill grower chunk steps (ops/treegrow_ooc.py)
 # ---------------------------------------------------------------------------
 
-_CN, _CC = 4096, 1024  # padded resident rows, chunk rows (both < 8192)
+_CN, _CC = 4096, 1024  # padded resident rows, chunk rows
 
 
 @contract(

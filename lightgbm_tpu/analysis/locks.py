@@ -5,7 +5,7 @@ coalescer/dispatcher pair, the continual runner, the periodic-snapshot
 and watchdog threads, and the HTTP server all share mutable state behind
 ~10 ad-hoc locks.  PR 14 needed four review rounds of hand-auditing to
 find its races; this layer turns that checklist into a pinned contract,
-the way R1-R17 pinned jit purity and J1-J7 pinned the traced IR.
+the way R1-R17 pinned jit purity and J1-J6 pinned the traced IR.
 
 The pass builds a whole-package **lock model** from the ASTs the shared
 :class:`~.core.PackageIndex` already parsed:

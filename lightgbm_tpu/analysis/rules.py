@@ -1,4 +1,4 @@
-"""jaxlint built-in rules R1-R21.
+"""jaxlint built-in rules R1-R21 (R18 retired with the booster fleet).
 
 Each rule is a generator over the :class:`~.core.PackageIndex`; see
 ``docs/ANALYSIS.md`` for the catalogue with examples and the pragma format.
@@ -1232,8 +1232,7 @@ def r13_collective_outside_fused_round(pkg: PackageIndex) -> Iterator[Finding]:
     donated dispatch (setup/eval phases) are out of scope."""
     hint = ("fold the collective into the donated round body (psum/"
             "psum_scatter inside the shard_mapped fused round — see "
-            "parallel/data_parallel.py::grow_tree_windowed_data_parallel "
-            "and docs/ANALYSIS.md R13); if the host truly needs the "
+            "docs/ANALYSIS.md R13); if the host truly needs the "
             "reduced value, return it in the round's async info vector")
     for mod in pkg.modules.values():
         for fi in mod.functions.values():
@@ -1307,8 +1306,8 @@ def r14_metadata_via_device_pull(pkg: PackageIndex) -> Iterator[Finding]:
     the ``np.asarray`` is a BLOCKING device pull of the entire buffer —
     paid to read a property (``.shape``/``.dtype``/``len``) the array
     object already exposes for free, device or host (the exact class the
-    round-14 review caught in ``grow_tree_windowed_data_parallel``, which
-    read ``num_bins_pf``'s length via ``np.asarray`` once per tree).
+    round-14 review caught in a sharded grower, which read
+    ``num_bins_pf``'s length via ``np.asarray`` once per tree).
     Unlike R1 this fires EVERYWHERE, not just hot paths: a metadata read
     never needs the conversion, so the pull is pure waste wherever it
     sits — and on host inputs it is still a gratuitous O(N) copy."""
@@ -1656,112 +1655,14 @@ def r17_full_histogram_over_dcn(pkg: PackageIndex) -> Iterator[Finding]:
                     hint)
 
 
-# ---------------------------------------------------------------------------
-# R18 — host-loop-over-independent-boosters
-# ---------------------------------------------------------------------------
-
-# the per-model entry points a fleet batches: one dispatch per round for
-# ALL models (ops/treegrow_fleet.py) instead of one per model per round
-_R18_ENTRIES = ("train_one_iter", "refit_leaves")
-# "train" is a common verb — only the package entry spellings count
-# (bare `train` from `from lightgbm_tpu import train`, or qualified
-# through the canonical module aliases); `self.train()` methods do not
-_R18_TRAIN_QUALS = ("train", "lgb.train", "engine.train",
-                    "lightgbm_tpu.train", "lightgbm_tpu.engine.train")
-
-
-def _r18_is_entry(fn: str) -> bool:
-    last = fn.split(".")[-1]
-    if last in _R18_ENTRIES:
-        return True
-    return fn in _R18_TRAIN_QUALS
-
-
-def _r18_walk_no_defs(node: ast.AST) -> Iterator[ast.AST]:
+def _walk_no_defs(node: ast.AST) -> Iterator[ast.AST]:
     """ast.walk minus nested function defs — their bodies are their own
     FuncInfo's territory (the _own_body discipline)."""
     for child in ast.iter_child_nodes(node):
         if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
         yield child
-        yield from _r18_walk_no_defs(child)
-
-
-def _r18_loop_assigned(loop: ast.For) -> set:
-    """Names assigned by statements in the loop body — the loop-carried
-    candidates.  A call argument reading one of these means iteration i
-    consumes iteration i-1's output (warm-started training, a running
-    score feeding the next refit): sequentially dependent, not a fleet."""
-    out = set()
-    for node in ast.walk(loop):
-        if isinstance(node, ast.Assign):
-            for t in node.targets:
-                for sub in ast.walk(t):
-                    if isinstance(sub, ast.Name):
-                        out.add(sub.id)
-        elif isinstance(node, ast.AugAssign):
-            if isinstance(node.target, ast.Name):
-                out.add(node.target.id)
-    return out
-
-
-@register_rule("R18", "host-loop-over-independent-boosters")
-def r18_host_loop_over_independent_boosters(
-        pkg: PackageIndex) -> Iterator[Finding]:
-    """A host ``for`` loop training or refitting boosters one model per
-    iteration with no cross-iteration data dependence: each pass calls
-    ``train`` / ``train_one_iter`` / ``refit_leaves`` on its own
-    element of a model list/dict, so every round costs one dispatch PER
-    MODEL — B dispatch fees, B recompilation keys, B host round-trips —
-    for work that is one vmapped dispatch in total.  The booster fleet
-    (``lgb.train_fleet``, ``ops/treegrow_fleet.py``) trains B
-    independent boosters in ONE donated dispatch per round, and
-    ``continual.fleet_refit_leaves`` is the batched refit twin; at
-    B=64 the batched path is the difference between a fleet sweep and a
-    lunch break (BENCH_fleet artifacts).  A call argument that READS a
-    name assigned inside the loop body is a loop-carried dependence
-    (warm-start chains like ``bst = train(..., init_model=bst)``, a
-    running score feeding the next refit) — sequential by construction,
-    not flagged.  Name-heuristic on the entry spellings: bare/qualified
-    package ``train`` plus any ``train_one_iter``/``refit_leaves``
-    (methods named ``.train`` on other objects are out of scope)."""
-    hint = ("batch the models: lgb.train_fleet(datasets, params) trains "
-            "B boosters in one dispatch per round "
-            "(lightgbm_tpu/models/fleet.py); "
-            "continual.fleet_refit_leaves batches the refit — or "
-            "suppress with the dependence that makes the loop "
-            "sequential")
-    for mod in pkg.modules.values():
-        for fi in mod.functions.values():
-            seen = set()
-            for node in _own_body(fi):
-                if not isinstance(node, ast.For):
-                    continue
-                carried = _r18_loop_assigned(node)
-                for sub in _r18_walk_no_defs(node):
-                    if not isinstance(sub, ast.Call) or id(sub) in seen:
-                        continue
-                    fn = dotted_name(sub.func)
-                    if fn is None and isinstance(sub.func, ast.Attribute):
-                        # subscripted receiver (lanes[i].train_one_iter):
-                        # no dotted spelling, but the method name decides
-                        if sub.func.attr in _R18_ENTRIES:
-                            fn = sub.func.attr
-                    if fn is None or not _r18_is_entry(fn):
-                        continue
-                    seen.add(id(sub))
-                    arg_names = {
-                        s.id for a in (list(sub.args)
-                                       + [k.value for k in sub.keywords])
-                        for s in ast.walk(a) if isinstance(s, ast.Name)}
-                    if arg_names & carried:
-                        continue  # loop-carried input: sequential
-                    yield _finding(
-                        fi, sub, "R18",
-                        f"{fn}(...) inside {fi.qualname}'s host loop "
-                        "trains/refits one model per iteration — B "
-                        "independent models cost B dispatches per round "
-                        "where a fleet costs one", hint)
+        yield from _walk_no_defs(child)
 
 
 # ---------------------------------------------------------------------------
@@ -1805,7 +1706,7 @@ def _r19_is_broad_handler(handler: ast.ExceptHandler) -> bool:
 def _r19_handler_escapes(handler: ast.ExceptHandler) -> bool:
     """True when the handler leaves the loop or re-raises — the failure
     is surfaced, not swallowed back into another attempt."""
-    for node in _r18_walk_no_defs(handler):
+    for node in _walk_no_defs(handler):
         if isinstance(node, (ast.Raise, ast.Break, ast.Return)):
             return True
     return False
@@ -1826,7 +1727,7 @@ def _r19_is_pacing_call(node: ast.Call) -> bool:
 
 
 def _r19_loop_is_paced_or_bounded(loop: ast.While) -> bool:
-    for node in _r18_walk_no_defs(loop):
+    for node in _walk_no_defs(loop):
         if isinstance(node, ast.Call) and _r19_is_pacing_call(node):
             return True
         if isinstance(node, ast.Name) and _R19_BUDGET_RE.search(node.id):
@@ -1877,7 +1778,7 @@ def r19_unbounded_retry(pkg: PackageIndex) -> Iterator[Finding]:
                     continue
                 if _r19_loop_is_paced_or_bounded(node):
                     continue
-                for sub in _r18_walk_no_defs(node):
+                for sub in _walk_no_defs(node):
                     if not isinstance(sub, ast.Try):
                         continue
                     broad = [h for h in sub.handlers
@@ -1887,7 +1788,7 @@ def r19_unbounded_retry(pkg: PackageIndex) -> Iterator[Finding]:
                         continue
                     io_call = None
                     for b in sub.body:
-                        for c in _r18_walk_no_defs(b):
+                        for c in _walk_no_defs(b):
                             if isinstance(c, ast.Call):
                                 fn = (dotted_name(c.func)
                                       or getattr(c.func, "attr", ""))

@@ -745,17 +745,6 @@ class Dataset:
                 )
         return self._bins_device_t
 
-    def efb_bins_device_t(self) -> Optional[jnp.ndarray]:
-        """(F_b, N) feature-major shadow of the EFB bundled matrix (the
-        windowed grower gathers window rows from it); lazy, device-side
-        transpose (one-time)."""
-        if self.efb is None:
-            return None
-        if getattr(self, "_efb_device_t", None) is None:
-            tabs = self.efb_device_tables()
-            self._efb_device_t = jnp.asarray(jnp.transpose(tabs[0]))
-        return self._efb_device_t
-
     def num_data(self) -> int:
         if self._constructed:
             return self._num_data

@@ -398,38 +398,12 @@ class Config:
     time_out: int = 120
     machine_list_filename: str = ""
     machines: str = ""
-    # num_slices (ours; docs/DISTRIBUTED.md "Hierarchical merge"): slice
-    # count of the nested (dcn, ici) mesh for multi-slice scale-out.
-    # With num_slices > 1 and tree_learner=data|voting, the fused
-    # windowed round runs the two-level merge: full psum/psum_scatter
-    # histogram collectives stay INSIDE each slice's ici axis, and only
-    # top_k_features features' histograms + gain scalars per split
-    # candidate cross the dcn axis (the PV-Tree/voting-parallel route).
-    # Devices must divide evenly into slices.  1 (default) = the
-    # single-level sharded round.
+    # num_slices (ours; docs/ROBUSTNESS.md "Elastic fleet recovery"): how
+    # many slices the launcher groups its ranks into.  A slice is the unit
+    # of recovery: when a rank dies its whole slice restarts from the last
+    # fleet-valid checkpoint and the other slices keep running
+    # (parallel/launcher.py).  Ranks must divide evenly into slices.
     num_slices: int = 1
-    # top_k_features (ours; docs/DISTRIBUTED.md "Hierarchical merge"):
-    # per-slice feature election width of the hierarchical merge — how
-    # many features' histograms each slice may ship over DCN per split
-    # candidate.  k >= num_features makes the election exhaustive
-    # (trees structurally exact vs the single-mesh sharded round, at
-    # full-merge byte cost over DCN); smaller k is the PV-Tree
-    # approximation with a statically pinned DCN byte budget
-    # (jaxpr-audit dcn_max_bytes, jaxlint R17).  Distinct from top_k,
-    # which parameterizes the strict voting-parallel grower.
-    top_k_features: int = 32
-    # num_feature_shards (ours; docs/DISTRIBUTED.md "2-D sharding"):
-    # feature-axis size d_f of the 2-D (feature, row) mesh for
-    # tree_learner=feature2d — each device owns an (F/d_f, N/d_r) tile
-    # of the bin matrix, per-leaf histograms are complete for the owned
-    # feature block with ZERO feature-axis collectives, and the split
-    # election runs the owned-feature winner machinery over the feature
-    # axis.  F pads to a multiple of d_f with dead features (never
-    # electable), rows pad to a multiple of d_r = devices/d_f.  A d_f
-    # that does not divide the device count warns and falls back to the
-    # single-level mesh instead of crashing.  1 (default) = rows-only
-    # sharding.
-    num_feature_shards: int = 1
 
     # --- GPU-compat (accepted, translated to mesh semantics) ---
     gpu_platform_id: int = -1
@@ -649,15 +623,6 @@ class Config:
     # many live segments accumulate.
     bin_cache_segment_threshold: int = 0
 
-    # --- booster fleets (ours; README "Booster fleets",
-    # lightgbm_tpu/models/fleet.py) ---
-    # fleet_size: expected number of boosters in a train_fleet batch.
-    # 0 (default) = infer B from the (B, N) label matrix; a non-zero
-    # value is a guard — train_fleet raises when it disagrees with the
-    # labels, catching a transposed label matrix before a B=N fleet
-    # trains silently.
-    fleet_size: int = 0
-
     # unknown/passthrough params preserved here
     extra: Dict[str, Any] = field(default_factory=dict)
     # names the user explicitly set (vs defaults) — lets device-specific
@@ -691,6 +656,12 @@ class Config:
         if self.objective in ("multiclass", "multiclassova") and self.num_class < 2:
             raise ValueError(
                 "Number of classes should be specified and greater than 1 for multiclass training"
+            )
+        if self.tree_learner not in ("serial", "data", "feature", "voting"):
+            # reference: ParseTreeLearnerAlias logs "Unknown tree learner type"
+            raise ValueError(
+                f"Unknown tree learner type {self.tree_learner!r}: "
+                "tree_learner must be serial/data/feature/voting"
             )
         if self.tree_growth_mode not in ("auto", "strict", "rounds"):
             raise ValueError(
@@ -736,7 +707,7 @@ class Config:
         "gpu_use_dp": "histogram accumulation precision is controlled by "
         "hist_precision (bf16x2/f32 lanes)",
         "num_gpu": "multi-device scale-out uses jax.sharding meshes via "
-        "tree_learner=data|feature|voting|feature2d",
+        "tree_learner=data|feature|voting",
         "precise_float_parser": "parsing always uses full float64 "
         "precision (numpy)",
         "parser_config_file": "custom parser plugins are not supported",

@@ -31,9 +31,8 @@ Semantics notes (deliberate, documented deviations are none — this IS
   which is also what ``Booster.refit`` does without a ``weight=``.
 
 Round 20 adds the BATCHED twin :func:`make_fleet_refit_entry` /
-:func:`fleet_refit_leaves`: B independent k=1 models (a
-``FleetBooster``'s lanes, or any same-config model list) refresh their
-leaves in ONE donated dispatch — shared bucket-padded batch, per-lane
+:func:`fleet_refit_leaves`: B independent k=1 models (any same-config
+model list) refresh their leaves in ONE donated dispatch — shared bucket-padded batch, per-lane
 stacked packs, per-lane labels, the solo scan vmapped over the model
 axis with the traversal input unmapped.
 
@@ -341,9 +340,8 @@ def fleet_refit_leaves(models, X: np.ndarray, labels: np.ndarray, *,
                        weights: Optional[np.ndarray] = None,
                        entry=None) -> int:
     """Refresh B models' leaf values in ONE donated dispatch + ONE
-    accounted sync — the batched twin of :func:`refit_leaves` for a
-    :class:`~lightgbm_tpu.models.fleet.FleetBooster` (or any list of
-    same-config k=1 Boosters/GBDTs over the same feature space).
+    accounted sync — the batched twin of :func:`refit_leaves` for a list
+    of same-config k=1 Boosters/GBDTs over the same feature space.
 
     ``labels`` is (B, n) per-lane targets over the SHARED ``X``;
     ``weights`` optionally (B, n).  Each lane's stacked pack is padded
@@ -353,8 +351,6 @@ def fleet_refit_leaves(models, X: np.ndarray, labels: np.ndarray, *,
     pack lock with the solo version guard.  Returns the rows used."""
     from ..models.gbdt import _predict_bucket
 
-    if hasattr(models, "boosters"):  # a FleetBooster
-        models = models.boosters()
     lanes = [_unwrap_lane(m) for m in models]
     if not lanes:
         raise ContinualError("fleet_refit_leaves: no models")

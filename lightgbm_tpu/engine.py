@@ -332,8 +332,7 @@ def train(
                         f"{trace_out}: {e}")
 
     # the run-level span is HOST-CAUSAL wall clock (docs/OBSERVABILITY.md
-    # "Span tracing"): per-round device-inclusive spans are the windowed
-    # grower's, anchored at its accounted async-info resolves
+    # "Span tracing")
     train_span = _trace.span("train", num_boost_round=num_boost_round)
     train_span.__enter__()
     # arm the heartbeat: heartbeat_done=0 marks this process as actively
@@ -534,65 +533,6 @@ def continual_train(model=None, params: Optional[Dict[str, Any]] = None, *,
                            reference=reference, state_dir=state_dir,
                            cache_path=cache_path, start=start,
                            **runner_kwargs)
-
-
-def train_fleet(params: Optional[Dict[str, Any]], train_set, labels=None, *,
-                num_boost_round: int = 100, weights=None, rounds=None):
-    """Fleet-training entry point (README "Booster fleets"): train B
-    independent k=1 boosters over ONE shared binned feature matrix as
-    one donated dispatch per round
-    (:class:`~lightgbm_tpu.models.fleet.FleetBooster`), instead of the
-    host loop over :func:`train` that jaxlint R18 flags.
-
-    ``train_set`` is either the shared :class:`Dataset` plus ``labels``
-    as a (B, N) per-lane label matrix (optionally ``weights`` (B, N)),
-    or a LIST of Datasets over identical feature data whose labels/
-    weights are stacked here.  ``rounds`` optionally gives per-lane
-    boosting budgets (device-side early stop; default
-    ``num_boost_round`` everywhere).  ``params`` may pin ``fleet_size``
-    as a shape guard (docs/Parameters.md).  Returns the trained
-    :class:`FleetBooster`; per-lane :class:`Booster` handles come from
-    its ``booster(b)`` / ``boosters()``.
-
-    >>> fb = lgb.train_fleet({"num_leaves": 31}, ds, labels_bn)
-    >>> fb.booster(3).predict(X)
-    """
-    from .models.fleet import FleetBooster, FleetError
-
-    cfg = Config.from_dict(dict(params or {}))
-    set_verbosity(cfg.verbosity)
-    telemetry_on = (bool(cfg.telemetry) if cfg.is_set("telemetry")
-                    else _obs.DEFAULT_ENABLED)
-    _obs.set_enabled(telemetry_on)
-    if telemetry_on:
-        try:
-            _obs_server.maybe_start(
-                cfg.metrics_port if cfg.is_set("metrics_port") else None)
-        except OSError as e:
-            log_warning(f"metrics endpoint could not start: {e}")
-    if isinstance(train_set, (list, tuple)):
-        if labels is not None:
-            raise FleetError(
-                "train_fleet: pass EITHER a list of Datasets OR one "
-                "Dataset + a (B, N) label matrix, not both")
-        datasets = list(train_set)
-        if not datasets:
-            raise FleetError("train_fleet: empty Dataset list")
-        labels = np.stack([np.asarray(d.label, np.float64)
-                           for d in datasets])
-        ws = [d.weight for d in datasets]
-        if any(w is not None for w in ws):
-            weights = np.stack([
-                np.ones(labels.shape[1], np.float64) if w is None
-                else np.asarray(w, np.float64) for w in ws])
-        train_set = datasets[0]
-    elif labels is None:
-        raise FleetError(
-            "train_fleet: a (B, N) label matrix (or a list of Datasets) "
-            "is required")
-    fb = FleetBooster(train_set, labels, params,
-                      weights=weights, rounds=rounds)
-    return fb.train(num_boost_round)
 
 
 def _trace_path(cfg: Config) -> str:

@@ -372,8 +372,7 @@ def read_cache_shard(path: str, row_lo: int, row_hi: int,
     """Materialize rows [row_lo, row_hi) of a save_binary cache through
     the shard-restricted stream: one reused read buffer, every CRC block
     the shard fully covers verified row-ranged — the launcher's
-    pre-partition worker feed (docs/DISTRIBUTED.md "Hierarchical
-    merge"): each rank reads ONLY its shard of one shared cache instead
+    pre-partition worker feed (docs/DISTRIBUTED.md): each rank reads ONLY its shard of one shared cache instead
     of every rank decompressing the full matrix."""
     st = BinCacheStream(path, member=member, shard=(int(row_lo),
                                                     int(row_hi)))
@@ -937,9 +936,8 @@ def prefetch_device(chunks: Iterator[Tuple[int, np.ndarray]],
     whole sweep; ``valid_rows`` masks the tail.  The upload of the next
     chunk is enqueued BEFORE the current one is yielded: JAX host->device
     transfers are async, so the copy engine overlaps the consumer's
-    dispatches instead of serializing after them (the data-feed analogue
-    of the windowed driver's one-round-deep pipeline; jaxlint R9: no
-    timing is read here, nothing syncs).
+    dispatches instead of serializing after them (jaxlint R9: no timing
+    is read here, nothing syncs).
     """
     import jax.numpy as jnp
 

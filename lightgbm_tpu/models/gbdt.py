@@ -45,9 +45,8 @@ _MODEL_VERSION = "v4"
 
 # serving bucket ladder: predict batches pad N up to the next power of two
 # (floor 8) so the jitted traversal compiles once per bucket instead of once
-# per distinct batch size — the predict-side analogue of the windowed
-# grower's W ladder.  Padding rows are masked on device; the padded result
-# is bit-identical to the unpadded one (rows traverse independently).
+# per distinct batch size.  Padding rows are masked on device; the padded
+# result is bit-identical to the unpadded one (rows traverse independently).
 _PREDICT_BUCKET_MIN = 8
 
 
@@ -326,9 +325,8 @@ class GBDT:
     # -- non-finite guard rail (docs/ROBUSTNESS.md) --------------------
     def _guard_accumulate(self, arrays) -> None:
         """Fold this iteration's tree stats into the device-side guard
-        flag: O(num_leaves) reductions, no host pull.  Mirrors the
-        windowed grower's in-round info-vector guard on the full-pass and
-        fast growers, which have no per-round host read to ride."""
+        flag: O(num_leaves) reductions, no host pull (the growers have no
+        per-round host read to ride)."""
         ok = (jnp.isfinite(arrays.leaf_value).all()
               & ~jnp.isnan(arrays.split_gain).any())
         self._guard_bad_iter = jnp.where(
@@ -727,10 +725,7 @@ class GBDT:
         # TreeLearner::CreateTreeLearner picking {serial,data,feature,voting})
         self._dp = None
         self._fp = None
-        self._dp_hier = None
-        self._dp2d = None
-        if self.cfg.tree_learner in ("data", "feature", "voting",
-                                     "feature2d"):
+        if self.cfg.tree_learner in ("data", "feature", "voting"):
             import jax as _jax
 
             if _jax.device_count() > 1:
@@ -742,44 +737,7 @@ class GBDT:
                 host_bins = train_set._host_bins(
                     f"tree_learner={self.cfg.tree_learner}")
                 mesh = make_mesh()
-                if self.cfg.tree_learner == "feature2d":
-                    # 2-D (feature, row) mesh for the wide-F regime
-                    # (docs/DISTRIBUTED.md "2-D sharding"): d_f feature
-                    # blocks x d_r row shards.  A d_f that does not
-                    # divide the device count falls back to the
-                    # single-level row mesh, loudly, instead of crashing.
-                    nd = _jax.device_count()
-                    d_f = max(int(self.cfg.num_feature_shards), 1)
-                    if d_f > 1 and nd % d_f:
-                        log_warning(
-                            f"num_feature_shards={d_f} does not divide "
-                            f"{nd} devices; training on the single-level "
-                            "row mesh")
-                        d_f = 1
-                    if d_f > 1:
-                        from ..parallel.feature2d import Sharded2DData
-                        from ..parallel.mesh import make_mesh_2d
-
-                        self._dp2d = Sharded2DData(
-                            make_mesh_2d(nd // d_f, d_f),
-                            np.asarray(host_bins),
-                            np.asarray(
-                                train_set.binner.num_bins_per_feature),
-                            np.asarray(
-                                train_set.binner.missing_bin_per_feature),
-                        )
-                    else:
-                        from ..parallel.data_parallel import ShardedData
-
-                        self._dp = ShardedData(
-                            mesh,
-                            np.asarray(host_bins),
-                            np.asarray(
-                                train_set.binner.num_bins_per_feature),
-                            np.asarray(
-                                train_set.binner.missing_bin_per_feature),
-                        )
-                elif self.cfg.tree_learner == "feature":
+                if self.cfg.tree_learner == "feature":
                     from ..parallel.feature_parallel import FeatureShardedData
 
                     self._fp = FeatureShardedData(
@@ -801,34 +759,6 @@ class GBDT:
                         np.asarray(train_set.binner.missing_bin_per_feature),
                         process_local=self._pre_partition,
                     )
-                    # nested (dcn, ici) mesh for multi-slice scale-out
-                    # (docs/DISTRIBUTED.md "Hierarchical merge"): built
-                    # NEXT TO the flat mesh — the hierarchical two-level
-                    # merge serves the windowed fused round; every other
-                    # grower keeps the single-level path above
-                    ns = int(self.cfg.num_slices)
-                    if ns > 1:
-                        if self._pre_partition:
-                            log_warning(
-                                "num_slices > 1 is not wired through the "
-                                "multi-controller pre_partition path yet; "
-                                "training on the single-level mesh")
-                        elif _jax.device_count() % ns:
-                            log_warning(
-                                f"num_slices={ns} does not divide "
-                                f"{_jax.device_count()} devices; training "
-                                "on the single-level mesh")
-                        else:
-                            from ..parallel.hierarchy import SlicedData
-                            from ..parallel.mesh import (
-                                make_mesh_hierarchical)
-
-                            # reshard the flat layout's device buffers —
-                            # the nested row layout places the same
-                            # per-device blocks, so the bin matrix stays
-                            # ONE device copy
-                            self._dp_hier = SlicedData.from_sharded(
-                                make_mesh_hierarchical(ns), self._dp)
 
     def reset_split_params(self) -> None:
         """Refresh jit-static split hyperparams after a config mutation
@@ -983,115 +913,6 @@ class GBDT:
         mask = np.zeros(f, dtype=bool)
         mask[chosen] = True
         return jnp.asarray(mask) & self._allowed_features
-
-    def _use_windowed(self, ts) -> bool:
-        """Wide-regime windowed grower gate (ops/treegrow_windowed.py).
-
-        The windowed grower shrinks each histogram pass from full-N to the
-        round's small-children window (pass ~200 ms -> ~30 ms at Epsilon,
-        400k x 2000 x 255 bins).  Round 7 fused its two per-round phases
-        into ONE donated dispatch with zero blocking host syncs (the round
-        driver no longer pulls between admit and pass; window sizes are
-        predicted from the device's own bound and verified on device), and
-        moved the row partition to the Pallas segment kernel — targeting
-        the ~0.10-0.14 s/round admit fixed cost that round 6 measured as
-        the parity blocker (docs/NEXT.md lever 1).  Still OPT-IN via
-        windowed_growth=true until the fused round is re-benched on chip
-        (docs/PERF_NOTES.md round 7).  Its v1 feature envelope excludes
-        the rarer options below; anything outside falls back to the
-        full-pass rounds grower, which supports everything.
-
-        Round 16: inside the windowed envelope, the round MEGAKERNEL
-        (ops/round_pallas.py — one HBM sweep of the bin matrix per
-        round) can replace the round body; the ``megakernel`` extra
-        param / ``LGBMTPU_MEGAKERNEL`` env ("1"/"interpret") select it —
-        "auto", the default, selects nothing since PR 21, because Mosaic
-        refuses the kernel on the chip — and configurations
-        outside ITS envelope (EFB bundles, per-node feature sampling)
-        fall back to the three-pass round loudly
-        (megakernel_envelope_fallbacks_total + a megakernel_fallback
-        event), never silently."""
-        return (
-            self._on_tpu
-            and bool(self.cfg.extra.get("windowed_growth", False))
-            and jax.device_count() == 1
-            and ts.num_feature() >= 512
-            and self.cfg.num_leaves >= 64
-            and self._monotone is None
-            and self._interaction_sets is None
-            and self._forced_schedule() is None
-            and self._cegb_lazy is None
-            and self._cegb_coupled is None
-            and not self._linear
-        )
-
-    def _use_windowed_dp(self, ts) -> bool:
-        """Sharded fused windowed round gate (docs/DISTRIBUTED.md "Sharded
-        fused rounds"): the one-dispatch windowed round over the ICI mesh,
-        with the histogram merge a single in-dispatch psum/psum_scatter
-        (parallel/data_parallel.py::grow_tree_windowed_data_parallel).
-        Mirrors :meth:`_use_windowed`'s envelope minus the single-device
-        requirement; configurations outside it fall back to the
-        multi-dispatch sharded rounds grower (fast-DP) or the strict
-        sharded grower, which support everything.  EFB is excluded (the
-        bundled tables are not threaded through the sharded path yet)."""
-        mode = self.cfg.tree_growth_mode
-        return (
-            self._on_tpu
-            and bool(self.cfg.extra.get("windowed_growth", False))
-            and (self._dp is not None or self._dp2d is not None)
-            and self.cfg.tree_learner in ("data", "voting", "feature2d")
-            and (mode == "rounds" or (mode == "auto" and self._on_tpu))
-            and getattr(ts, "efb", None) is None
-            and ts.num_feature() >= 512
-            and self.cfg.num_leaves >= 64
-            and self._monotone is None
-            and self._interaction_sets is None
-            and self._forced_schedule() is None
-            and self._cegb_lazy is None
-            and self._cegb_coupled is None
-            and not self._linear
-        )
-
-    def _use_windowed_hier(self, ts) -> bool:
-        """Multi-slice hierarchical merge gate (docs/DISTRIBUTED.md
-        "Hierarchical merge"): the two-level windowed round over the
-        nested (dcn, ici) mesh — intra-slice psum/psum_scatter, top-k
-        feature exchange over dcn.  Rides :meth:`_use_windowed_dp`'s
-        envelope, minus per-node feature sampling (the slice-local vote
-        must be deterministic and slice-consistent)."""
-        return (
-            self._dp_hier is not None
-            and not self._needs_node_rng
-            and self._use_windowed_dp(ts)
-        )
-
-    def _use_windowed_2d(self, ts) -> bool:
-        """2-D (feature, row) mesh gate (docs/DISTRIBUTED.md "2-D
-        sharding"): the one-dispatch windowed round with the bin matrix
-        on P(feature, row) — feature-complete per-block histograms, the
-        owned-feature election over the feature axis.  Rides
-        :meth:`_use_windowed_dp`'s envelope minus per-node feature
-        sampling (the owned-feature search needs the sampled set to span
-        the full axis deterministically, like the scatter merge)."""
-        return (
-            self._dp2d is not None
-            and not self._needs_node_rng
-            and self._use_windowed_dp(ts)
-        )
-
-    def _windowed_dp_merge(self) -> str:
-        """Merge strategy for the sharded fused round: tree_learner=voting
-        maps to the owned-feature ``psum_scatter`` variant (the reference's
-        ReduceScatter + per-rank feature ownership — half the merge bytes,
-        split search parallelized over F), tree_learner=data to the plain
-        ``psum`` (replicated split search, the latency-lean ICI default).
-        Per-node feature sampling forces psum: under owned features each
-        rank would sample only its block (see
-        grow_tree_windowed_data_parallel)."""
-        if self.cfg.tree_learner == "voting" and not self._needs_node_rng:
-            return "scatter"
-        return "psum"
 
     @property
     def _monotone_method(self) -> str:
@@ -1555,127 +1376,6 @@ class GBDT:
                     monotone_method=self._monotone_method,
                 )
                 arrays, leaf_id = self._localize_tree(arrays, leaf_id)
-            elif self._dp2d is not None and self._use_windowed_2d(ts):
-                # 2-D (feature, row) mesh (docs/DISTRIBUTED.md "2-D
-                # sharding"): each device owns an (F/d_f, N/d_r) tile,
-                # the histogram phase crosses the feature axis with ZERO
-                # collectives, the owned-feature election crosses it with
-                # scalars + one (N_loc,) decision broadcast — all inside
-                # the one donated dispatch per round
-                from ..parallel.feature2d import grow_tree_windowed_feature2d
-
-                d2 = self._dp2d
-                quant = self.cfg.use_quantized_grad
-                arrays, leaf_id_pad = grow_tree_windowed_feature2d(
-                    d2,
-                    d2.pad_rows_device(gc, jnp.float32),
-                    d2.pad_rows_device(hc, jnp.float32),
-                    d2.pad_rows_device(row_mask, bool, fill=False),
-                    d2.pad_rows_device(sample_weight, jnp.float32,
-                                       fill=1.0),
-                    feature_mask,
-                    self._categorical_mask,
-                    None,  # rng_key: per-node sampling is outside the gate
-                    (jax.random.PRNGKey(
-                        self.cfg.seed * 1000003 + self.iter_ * 31 + c)
-                     if quant else None),
-                    self._feature_contri,
-                    num_leaves=self.cfg.num_leaves,
-                    num_bins=ts.max_num_bins,
-                    max_depth=self.cfg.max_depth,
-                    params=self._split_params,
-                    leaf_tile=self._leaf_tile(ts, use_efb=False),
-                    hist_precision=self.cfg.hist_precision,
-                    use_pallas=self._on_tpu,
-                    quantize_bins=(self.cfg.num_grad_quant_bins
-                                   if quant else 0),
-                    stochastic_rounding=bool(self.cfg.stochastic_rounding),
-                    quant_renew=bool(self.cfg.quant_train_renew_leaf),
-                    guard_label=(
-                        f" (boosting iteration {self.iter_ + 1})"),
-                )
-                arrays, leaf_id_pad = self._localize_tree(
-                    arrays, leaf_id_pad)
-                leaf_id = leaf_id_pad[: ts.num_data()]
-            elif self._dp_hier is not None and self._use_windowed_hier(ts):
-                # multi-slice scale-out (docs/DISTRIBUTED.md "Hierarchical
-                # merge"): the two-level windowed round — intra-slice
-                # psum/psum_scatter over ici unchanged, top-k feature
-                # exchange over dcn, all inside the one donated dispatch
-                from ..parallel.hierarchy import (
-                    grow_tree_windowed_hierarchical)
-
-                dph = self._dp_hier
-                quant = self.cfg.use_quantized_grad
-                arrays, leaf_id_pad = grow_tree_windowed_hierarchical(
-                    dph,
-                    dph.pad_rows_device(gc, jnp.float32),
-                    dph.pad_rows_device(hc, jnp.float32),
-                    dph.pad_rows_device(row_mask, bool, fill=False),
-                    dph.pad_rows_device(sample_weight, jnp.float32,
-                                        fill=1.0),
-                    feature_mask,
-                    self._categorical_mask,
-                    (jax.random.PRNGKey(
-                        self.cfg.seed * 1000003 + self.iter_ * 31 + c)
-                     if quant else None),
-                    self._feature_contri,
-                    num_leaves=self.cfg.num_leaves,
-                    num_bins=ts.max_num_bins,
-                    max_depth=self.cfg.max_depth,
-                    params=self._split_params,
-                    leaf_tile=self._leaf_tile(ts, use_efb=False),
-                    hist_precision=self.cfg.hist_precision,
-                    use_pallas=self._on_tpu,
-                    quantize_bins=(self.cfg.num_grad_quant_bins
-                                   if quant else 0),
-                    stochastic_rounding=bool(self.cfg.stochastic_rounding),
-                    quant_renew=bool(self.cfg.quant_train_renew_leaf),
-                    merge=self._windowed_dp_merge(),
-                    top_k_features=int(self.cfg.top_k_features),
-                    guard_label=(
-                        f" (boosting iteration {self.iter_ + 1})"),
-                )
-                arrays, leaf_id_pad = self._localize_tree(
-                    arrays, leaf_id_pad)
-                leaf_id = leaf_id_pad[: ts.num_data()]
-            elif self._dp is not None and self._use_windowed_dp(ts):
-                # the tentpole path: sharded one-dispatch windowed rounds —
-                # histogram merge is one psum/psum_scatter INSIDE the
-                # donated dispatch, 1 dispatch + 0 blocking syncs per rank
-                from ..parallel.data_parallel import (
-                    grow_tree_windowed_data_parallel)
-
-                dp = self._dp
-                quant = self.cfg.use_quantized_grad
-                arrays, leaf_id_pad = grow_tree_windowed_data_parallel(
-                    dp,
-                    dp.pad_rows_device(gc, jnp.float32),
-                    dp.pad_rows_device(hc, jnp.float32),
-                    dp.pad_rows_device(row_mask, bool, fill=False),
-                    dp.pad_rows_device(sample_weight, jnp.float32, fill=1.0),
-                    feature_mask,
-                    self._categorical_mask,
-                    node_rng,
-                    (jax.random.PRNGKey(self.cfg.seed * 1000003 + self.iter_ * 31 + c)
-                     if quant else None),
-                    self._feature_contri,
-                    num_leaves=self.cfg.num_leaves,
-                    num_bins=ts.max_num_bins,
-                    max_depth=self.cfg.max_depth,
-                    params=self._split_params,
-                    leaf_tile=self._leaf_tile(ts, use_efb=False),
-                    hist_precision=self.cfg.hist_precision,
-                    use_pallas=self._on_tpu,
-                    quantize_bins=(self.cfg.num_grad_quant_bins if quant else 0),
-                    stochastic_rounding=bool(self.cfg.stochastic_rounding),
-                    quant_renew=bool(self.cfg.quant_train_renew_leaf),
-                    merge=self._windowed_dp_merge(),
-                    guard_label=f" (boosting iteration {self.iter_ + 1})",
-                    megakernel_opt=self.cfg.extra.get("megakernel"),
-                )
-                arrays, leaf_id_pad = self._localize_tree(arrays, leaf_id_pad)
-                leaf_id = leaf_id_pad[: ts.num_data()]
             elif self._dp is not None and self._use_fast_dp:
                 from ..parallel.data_parallel import grow_tree_fast_data_parallel
 
@@ -1737,42 +1437,6 @@ class GBDT:
                 )
                 arrays, leaf_id_pad = self._localize_tree(arrays, leaf_id_pad)
                 leaf_id = leaf_id_pad[: ts.num_data()]
-            elif self._use_fast and self._use_windowed(ts):
-                from ..ops.treegrow_windowed import grow_tree_windowed
-
-                quant = self.cfg.use_quantized_grad
-                efb_tabs_w = (ts.efb_device_tables()
-                              if getattr(ts, "efb", None) is not None else None)
-                arrays, leaf_id = grow_tree_windowed(
-                    ts.bins_device_t(),
-                    gc,
-                    hc,
-                    row_mask,
-                    sample_weight,
-                    feature_mask,
-                    ts.num_bins_pf_device,
-                    ts.missing_bin_pf_device,
-                    node_rng,
-                    (jax.random.PRNGKey(self.cfg.seed * 1000003 + self.iter_ * 31 + c)
-                     if quant else None),
-                    self._feature_contri,
-                    self._categorical_mask,
-                    ts.efb_bins_device_t() if getattr(ts, "efb", None) is not None else None,
-                    efb_tabs_w[1] if efb_tabs_w else None,
-                    efb_tabs_w[2] if efb_tabs_w else None,
-                    num_leaves=self.cfg.num_leaves,
-                    num_bins=ts.max_num_bins,
-                    max_depth=self.cfg.max_depth,
-                    params=self._split_params,
-                    leaf_tile=self._leaf_tile(ts),
-                    hist_precision=self.cfg.hist_precision,
-                    use_pallas=self._on_tpu,
-                    quantize_bins=(self.cfg.num_grad_quant_bins if quant else 0),
-                    stochastic_rounding=bool(self.cfg.stochastic_rounding),
-                    quant_renew=bool(self.cfg.quant_train_renew_leaf),
-                    guard_label=f" (boosting iteration {self.iter_ + 1})",
-                    megakernel_opt=self.cfg.extra.get("megakernel"),
-                )
             elif self._use_fast:
                 from ..ops.treegrow_fast import grow_tree_fast
 

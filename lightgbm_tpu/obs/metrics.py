@@ -9,8 +9,7 @@ budget protocol:
 **Telemetry adds ZERO device dispatches and ZERO blocking syncs.**  Nothing
 in this module imports jax or touches a device value.  Every device-derived
 metric is recorded by a caller that already holds the value on the host —
-the windowed grower's one-round-behind async info vector, the accounted
-``sync_pull`` at a predict entry, the sanitizer's ``jax.monitoring``
+the accounted ``sync_pull`` at a predict entry, the sanitizer's ``jax.monitoring``
 listener — so enabling telemetry (it is default-on) cannot change the
 dispatch/sync budgets that ``tests/test_retrace.py`` and
 ``tests/test_predict_budget.py`` pin.

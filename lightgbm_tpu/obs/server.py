@@ -64,7 +64,6 @@ UNHEALTHY_COUNTERS = (
 DEGRADED_COUNTERS = (
     ("degrade_disabled_total", "Pallas kernel degraded to XLA fallback"),
     ("launcher_relaunches_total", "fleet relaunched after a failure"),
-    ("train_windowed_retries_total", "windowed W-bound prediction retries"),
     ("checkpoint_fallbacks_total", "resume fell back to an older snapshot"),
     ("fleet_resumes_total", "fleet resumed from a checkpoint round"),
     ("faults_injected_total", "injected faults fired (test harness armed)"),

@@ -10,8 +10,7 @@ Spans are named, attributed, nesting host-side intervals:
 
 plus :func:`record_span` for the retroactive form — an interval whose end
 the caller anchors at an **accounted sync point** it already paid for (the
-windowed grower's one-round-behind async info resolve, the predict entry's
-``sync_pull``).  That split embodies the zero-dispatch rule:
+predict entry's ``sync_pull``).  That split embodies the zero-dispatch rule:
 
 * opening/closing a span NEVER touches a device value.  A span close that
   performs a fresh host pull to "drain" the queue would add the blocking
@@ -20,7 +19,7 @@ windowed grower's one-round-behind async info resolve, the predict entry's
 * consequently a context-manager span measures HOST-CAUSAL wall clock
   (async device work dispatched inside it may still be in flight at
   close).  Spans that must cover device time are recorded retroactively
-  at the next accounted sync (``windowed_round``, ``predict.*``) — the
+  at the next accounted sync (``predict.*``) — the
   instrumented layers own that anchoring, not this module.
 
 Finished spans land in a bounded ring (cap :data:`TRACE_RING_CAP`) and
@@ -495,15 +494,14 @@ def record_span(name: str, duration_s: float,
                 **attrs: Any) -> None:
     """Record a span that ENDS NOW and lasted ``duration_s`` — the
     retroactive form for intervals anchored at an accounted sync point the
-    caller just passed (async info resolve, ``sync_pull``).  Never touches
+    caller just passed (``sync_pull``).  Never touches
     a device value.
 
     Identity is explicit, never implicit-cross-thread: ``ctx=`` records
     under a pre-minted identity (so OTHER spans could already hold links
     to it — the serving batch/leg shape); ``parent=`` derives a fresh
     child of an explicit parent context; with neither, the span adopts
-    this thread's innermost open span as parent when one exists (the
-    training-loop form: ``windowed_round`` under ``boost_round``) and is
+    this thread's innermost open span as parent when one exists and is
     otherwise a fresh root.  ``links=`` attaches peer contexts.  A
     context carrying ``sampled=False`` drops the record — that is the
     request-sampling contract."""

@@ -107,10 +107,8 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-# Bytes of VMEM accumulator headroom shared by the histogram leaf-tile
-# policy (recommended_leaf_tile below) AND the round megakernel's
-# feature-block sizing (ops/round_pallas.py::megakernel_feature_block) —
-# ONE budget so the two VMEM cost models can never drift apart.
+# Bytes of VMEM accumulator headroom for the histogram leaf-tile policy
+# (recommended_leaf_tile below).
 VMEM_ACC_BUDGET = 8_000_000
 
 
@@ -150,7 +148,7 @@ def recommended_leaf_tile(
     ncl = payload_channels(hist_precision, quantized)
     fb = min(n_features_effective if n_features_effective > 0 else 1, 128)
     fb_pad = max(_round_up(fb, 8), 8)
-    budget = VMEM_ACC_BUDGET  # shared with the megakernel (module const)
+    budget = VMEM_ACC_BUDGET
     bpad = _round_up(max(num_bins, 8), 8)  # kernel pads B to 8
     per_leaf = fb_pad * bpad * 4 * ncl  # f32/int32 accumulator lanes
     if n_features_effective <= 128:

@@ -339,7 +339,7 @@ def unbundle_hists(h: jnp.ndarray, efb_gather: jnp.ndarray,
     """(tile, 3, F_b, B) bundle hists -> (tile, 3, F, B) per-feature hists:
     gather each feature's non-default slots; its default-bin row is
     leaf_total - sum(non-default) (reference most-freq-bin subtraction; see
-    io/efb.py).  Shared by the fast and windowed growers."""
+    io/efb.py)."""
     tile = h.shape[0]
     flat = h.reshape(tile, 3, -1)
     flat = jnp.concatenate([flat, jnp.zeros((tile, 3, 1), h.dtype)], axis=2)

@@ -491,148 +491,6 @@ def select_from_plane(gain: jnp.ndarray, ctx: dict) -> BestSplit:
     )
 
 
-class FeatureBests(NamedTuple):
-    """Per-FEATURE reduction of a gain plane: for every feature, the best
-    threshold's gain and the context needed to materialize a BestSplit if
-    that feature wins the cross-feature argmax.  This is the round
-    megakernel's on-core output shape (ops/round_pallas.py): reducing the
-    (F, B) plane to (F,) per candidate happens while the candidate
-    histograms are still VMEM-resident, so the split-gain scan never
-    re-reads them from HBM; :func:`select_from_feature_best` finishes the
-    O(F) selection outside the kernel.
-
-    Selecting per-feature-first is BITWISE equivalent to
-    :func:`select_from_plane`'s flat argmax: both resolve ties to the
-    lexicographically first (feature, bin) cell — ``jnp.argmax`` over B
-    picks the first maximizing bin per feature, and the cross-feature
-    argmax picks the first maximizing feature (pinned by
-    tests/test_megakernel.py against find_best_split on tie-heavy
-    fixtures, including duplicated columns)."""
-
-    gain: jnp.ndarray  # (F,) f32
-    threshold_bin: jnp.ndarray  # (F,) i32
-    use_left: jnp.ndarray  # (F,) bool (False on categorical features)
-    variant: jnp.ndarray  # (F,) i32: -1 numeric, 0 onehot, 1 asc, 2 desc
-    left_g: jnp.ndarray  # (F,) stats of the feature's best candidate
-    left_h: jnp.ndarray
-    left_c: jnp.ndarray
-
-
-def reduce_plane_per_feature(gain: jnp.ndarray, ctx: dict) -> FeatureBests:
-    """Reduce a gain plane over the bin axis: per feature, the first
-    maximizing bin plus the winner-materialization stats
-    (:func:`select_from_plane`'s gathers, done per feature instead of at
-    the flat argmax cell).  Feature-independent by construction, so the
-    megakernel may run it on feature-block slices and concatenate."""
-    f, b = gain.shape
-    bb = jnp.argmax(gain, axis=1).astype(jnp.int32)  # first max per feature
-
-    def at_bb(x):
-        return jnp.take_along_axis(x, bb[:, None], axis=1)[:, 0]
-
-    use_left = at_bb(ctx["use_left"])
-    stats_l, stats_r = ctx["stats_l"], ctx["stats_r"]
-
-    def pick(sl, sr):
-        return jnp.where(use_left, at_bb(sl), at_bb(sr))
-
-    lg = pick(stats_l[0], stats_r[0])
-    lh = pick(stats_l[1], stats_r[1])
-    lc = pick(stats_l[2], stats_r[2])
-    variant = jnp.full((f,), -1, jnp.int32)
-    cmask = ctx["categorical_mask"]
-    if cmask is not None:
-        v = at_bb(ctx["variant"]).astype(jnp.int32)
-        oh_l, st_asc, st_desc = ctx["oh_l"], ctx["st_asc"], ctx["st_desc"]
-
-        def pick_cat(i):
-            # mirror select_from_plane's pick_cat: stack the 3 variants'
-            # value at the feature's best cell, index by the variant
-            stk = jnp.stack([at_bb(oh_l[i]), at_bb(st_asc[i]),
-                             at_bb(st_desc[i])])  # (3, F)
-            return jnp.take_along_axis(stk, v[None], axis=0)[0]
-
-        lg = jnp.where(cmask, pick_cat(0), lg)
-        lh = jnp.where(cmask, pick_cat(1), lh)
-        lc = jnp.where(cmask, pick_cat(2), lc)
-        use_left = jnp.where(cmask, False, use_left)
-        variant = jnp.where(cmask, v, variant)
-    return FeatureBests(
-        gain=at_bb(gain), threshold_bin=bb, use_left=use_left,
-        variant=variant, left_g=lg, left_h=lh, left_c=lc)
-
-
-def categorical_winner_mask(hist_col: jnp.ndarray, missing_bin, params:
-                            SplitParams, variant, threshold) -> jnp.ndarray:
-    """Rebuild the winning categorical feature's left-bin mask from its
-    (3, B) histogram column — the per-feature rank computation of
-    :func:`gain_plane`, replayed for ONE feature.  Deterministic replay of
-    the same formulas (same ``hist_nm`` zeroing, same ratio, same stable
-    ``argsort``) is bitwise-identical to the plane's rank rows, so the
-    megakernel does not need to ship (F, B) rank planes out of the kernel
-    to materialize the winner's ``cat_mask``."""
-    b = hist_col.shape[1]
-    bins_idx = jnp.arange(b, dtype=jnp.int32)
-    is_missing = bins_idx == missing_bin
-    hist_nm = jnp.where(is_missing[None], 0.0, hist_col)
-    used = (hist_nm[2] > 0) & ~is_missing
-    ratio = jnp.where(used, hist_nm[0] / (hist_nm[1] + params.cat_smooth),
-                      jnp.inf)
-    rank_asc = jnp.argsort(jnp.argsort(ratio))
-    rank_desc = jnp.argsort(jnp.argsort(jnp.where(used, -ratio, jnp.inf)))
-    mask_oh = bins_idx == threshold
-    mask_asc = rank_asc <= threshold
-    mask_desc = rank_desc <= threshold
-    return jnp.where(variant == 0, mask_oh,
-                     jnp.where(variant == 1, mask_asc, mask_desc))
-
-
-def select_from_feature_best(
-    fb: FeatureBests,
-    parent_g, parent_h, parent_count,
-    categorical_mask: jnp.ndarray | None = None,
-    cand_hist: jnp.ndarray | None = None,  # (3, F, B) — winner's cat replay
-    missing_bin_per_feature: jnp.ndarray | None = None,
-    params: SplitParams = SplitParams(),
-    num_bins: int | None = None,
-) -> BestSplit:
-    """Cross-feature half of the split selection: argmax the per-feature
-    bests and materialize the winner — the outside-the-kernel counterpart
-    of :func:`reduce_plane_per_feature` (bitwise-equal to
-    :func:`select_from_plane` on the same plane; see FeatureBests)."""
-    best_f = jnp.argmax(fb.gain).astype(jnp.int32)
-    best_gain = fb.gain[best_f]
-    best_t = fb.threshold_bin[best_f]
-    best_left = fb.use_left[best_f]
-    b = num_bins if num_bins is not None else (
-        cand_hist.shape[2] if cand_hist is not None else 1)
-    best_is_cat = jnp.asarray(False)
-    best_cat_mask = jnp.zeros((b,), bool)
-    if categorical_mask is not None:
-        best_is_cat = categorical_mask[best_f]
-        best_cat_mask = jnp.where(
-            best_is_cat,
-            categorical_winner_mask(
-                cand_hist[:, best_f], missing_bin_per_feature[best_f],
-                params, fb.variant[best_f], best_t),
-            jnp.zeros((b,), bool))
-    lg, lh, lc = fb.left_g[best_f], fb.left_h[best_f], fb.left_c[best_f]
-    return BestSplit(
-        gain=best_gain,
-        feature=best_f,
-        threshold_bin=best_t,
-        default_left=best_left,
-        is_cat=best_is_cat,
-        cat_mask=best_cat_mask,
-        left_sum_g=lg,
-        left_sum_h=lh,
-        left_count=lc,
-        right_sum_g=parent_g - lg,
-        right_sum_h=parent_h - lh,
-        right_count=parent_count - lc,
-    )
-
-
 def find_best_split(
     hist: jnp.ndarray,
     parent_sum_g: jnp.ndarray,

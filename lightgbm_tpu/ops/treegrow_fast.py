@@ -797,7 +797,7 @@ def _grow_fast_impl(
             # children's slots; gather the <= tile parents, subtract, and
             # scatter both children once — O(tile) state traffic instead of the
             # full-(L,...) scatter/subtract/where chain (measured 57 ms/round
-            # at Epsilon shape; benchmarks/probe_r5_fixed.py)
+            # at Epsilon shape, round 5)
             active = state.slot_left >= 0  # (tile,)
             sl = jnp.clip(state.slot_left, 0, L - 1)
             sr = jnp.clip(state.slot_right, 0, L - 1)
@@ -987,15 +987,12 @@ def _grow_fast_impl(
 
 def grow_tree_fast(*args, use_pallas: bool = True, **kwargs):
     """Public entry: :func:`_grow_fast_impl` behind the graceful
-    kernel-degradation net (utils/degrade.py, mirrored from
-    ops/treegrow_windowed.py::grow_tree_windowed).  ``use_pallas`` folds
+    kernel-degradation net (utils/degrade.py).  ``use_pallas`` folds
     in the degradation registry before becoming a jit static; a Pallas
     failure surfacing at trace or backend-COMPILE time is caught once,
     logged, and the tree regrown on the XLA histogram path.
 
-    Honest scope: unlike the windowed grower (whose driver resolves
-    device reads inside the impl, so execute-time kernel failures surface
-    here too), this impl returns un-materialized device arrays — an
+    Honest scope: this impl returns un-materialized device arrays — an
     ASYNC execute-time kernel failure surfaces at the caller's next
     blocking pull, outside this net.  Compile-time rejection is the
     dominant real-world Mosaic failure class; the env escape hatches

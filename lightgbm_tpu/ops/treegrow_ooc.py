@@ -42,9 +42,9 @@ than silently training something else.
 Dispatch/sync shape (honest): this is a host-driven per-split loop —
 one small blocking pull per split for the can-split decision (the strict
 grower's host analogue) plus ``ceil(N/chunk)`` chunk dispatches per
-pass.  The windowed 1-dispatch/0-sync budget applies to the RESIDENT
-out-of-core regime (standard growers over a stream-assembled device
-matrix), not to spill-mode growth; tests/test_out_of_core.py pins both.
+pass.  The RESIDENT out-of-core regime runs the standard growers over a
+stream-assembled device matrix and is not this loop;
+tests/test_out_of_core.py pins both.
 The chunk steps' IR is pinned by the ``ooc_root_chunk`` /
 ``ooc_split_chunk`` audit contracts (analysis/contracts.py): donated
 accumulators consumable, collective/callback/transfer-free bodies,

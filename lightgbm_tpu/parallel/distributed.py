@@ -8,12 +8,7 @@ include/LightGBM/network.h.  The TPU-native replacement: every process calls
 `jax.devices()` is the GLOBAL device list across hosts and the existing
 `jax.sharding.Mesh` + shard_map learners run unchanged — XLA routes
 collectives over ICI within a slice and DCN across hosts, replacing the
-reference's hand-rolled Allreduce/ReduceScatter over TCP.  That includes
-the sharded fused windowed round (docs/DISTRIBUTED.md "Sharded fused
-rounds"): every process drives the identical one-dispatch round loop,
-the in-dispatch psum/psum_scatter crosses the process boundary, and the
-collective-merged info vector keeps each process's host-side W-ladder
-decisions in lockstep without any extra synchronization.
+reference's hand-rolled Allreduce/ReduceScatter over TCP.
 
 Config mapping (reference: Config network params):
   machines / machine_list_filename : "host:port" entries, one per process;

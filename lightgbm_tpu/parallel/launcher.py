@@ -125,18 +125,6 @@ if es_rounds > 0 and valid_sets:
     callbacks.append(lgb.early_stopping(es_rounds, verbose=False))
 if valid_sets:
     callbacks.append(lgb.record_evaluation(evals_result))
-if os.environ.get("LGBMTPU_FAULT"):
-    # worker_death injection site (utils/faults.py): rank-gated hard exit
-    # at the start of a chosen iteration — the scenario the launcher
-    # watchdog exists to catch
-    from lightgbm_tpu.utils import faults as _faults
-
-    def _fault_cb(env):
-        _faults.maybe_crash("worker_death", env.iteration + 1)
-    _fault_cb.before_iteration = True
-    _fault_cb.order = -100
-    callbacks.append(_fault_cb)
-
 # coordinated fleet checkpoints (docs/ROBUSTNESS.md "Elastic fleet
 # recovery"): every ckpt_freq GLOBAL iterations rank 0 writes the
 # fleet snapshot + manifest through utils/checkpoint.py and every other
@@ -145,7 +133,8 @@ if os.environ.get("LGBMTPU_FAULT"):
 # previous fleet-valid round authoritative
 _ckpt_dir = os.environ.get("LGBMTPU_FLEET_CKPT_DIR")
 _ckpt_freq = int(os.environ.get("LGBMTPU_FLEET_SNAPSHOT_FREQ", "0") or 0)
-if _ckpt_dir and _ckpt_freq > 0:
+_ckpt_on = bool(_ckpt_dir) and _ckpt_freq > 0
+if _ckpt_on:
     from lightgbm_tpu.utils import checkpoint as _ckpt
 
     _world = int(os.environ.get("LGBMTPU_FLEET_WORLD",
@@ -172,6 +161,43 @@ if _ckpt_dir and _ckpt_freq > 0:
             _ckpt.confirm_fleet_checkpoint(_ckpt_dir, it, _rank_i, text)
     _fleet_ckpt_cb.order = 100
     callbacks.append(_fleet_ckpt_cb)
+
+if os.environ.get("LGBMTPU_FAULT"):
+    # worker_death injection site (utils/faults.py): rank-gated hard exit
+    # at the start of a chosen iteration — the scenario the launcher
+    # watchdog exists to catch
+    from lightgbm_tpu.utils import faults as _faults
+
+    def _await_fleet_ack(done_round):
+        # the injected death models "a rank is lost AFTER the fleet
+        # confirmed the last checkpoint round it completed".  Slices are
+        # independent rendezvous worlds, so on a loaded machine this rank
+        # can reach its death round before rank 0 (another slice) has
+        # published that round's manifest, and the replacement would then
+        # resume from nothing.  Wait for the manifest the scenario
+        # assumes: slice-valid without this slice's own acks.
+        import time
+
+        want = done_round // _ckpt_freq * _ckpt_freq if _ckpt_on else 0
+        if want <= 0:
+            return
+        sl = _slices or {}
+        mine = tuple(int(r) for r, s in sl.items() if s == sl.get(wid))
+        deadline = time.monotonic() + 120.0
+        while time.monotonic() < deadline:
+            got = _ckpt.latest_slice_valid_fleet_manifest(
+                _ckpt_dir, _world, mine)
+            if got is not None and got[0] >= want:
+                return
+            time.sleep(0.05)
+
+    def _fault_cb(env):
+        if _faults.would_fire("worker_death", env.iteration + 1):
+            _await_fleet_ack(env.iteration)
+        _faults.maybe_crash("worker_death", env.iteration + 1)
+    _fault_cb.before_iteration = True
+    _fault_cb.order = -100
+    callbacks.append(_fault_cb)
 
 bst = lgb.train(params, ds, int(os.environ["LGBM_TPU_ROUNDS"]),
                 valid_sets=valid_sets or None,
@@ -748,8 +774,7 @@ def train_distributed(
     ``num_slices`` > 1 (param or config) groups the ranks into slice
     worlds of num_machines/num_slices members each — the loopback
     control-plane form of multi-slice scale-out (docs/ROBUSTNESS.md
-    "Slice-granular recovery"; the in-dispatch two-level DCN merge
-    itself is parallel/hierarchy.py over a nested mesh).  Each slice is
+    "Slice-granular recovery").  Each slice is
     its own rendezvous world training the shared shard plan; the fleet
     manifests carry slice membership, the slow-rank detector compares
     heartbeats WITHIN a slice, and a rank failure kills + respawns ONLY

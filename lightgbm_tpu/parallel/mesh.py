@@ -20,15 +20,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 DATA_AXIS = "data"  # rows (reference: tree_learner=data rank axis)
-FEATURE_AXIS = "feature"  # feature blocks (reference: tree_learner=feature)
-
-# nested two-level mesh axes (docs/DISTRIBUTED.md "Hierarchical merge"):
-# ICI_AXIS ranks share a slice's chip interconnect — full histogram
-# collectives are cheap there; DCN_AXIS crosses slices over data-center
-# network, where only top-k-shaped or scalar operands may travel
-# (jaxlint R17, jaxpr-audit dcn_max_bytes pin)
-ICI_AXIS = "ici"
-DCN_AXIS = "dcn"
 
 
 def make_mesh(n_devices: Optional[int] = None, devices: Optional[Sequence] = None) -> Mesh:
@@ -41,49 +32,5 @@ def make_mesh(n_devices: Optional[int] = None, devices: Optional[Sequence] = Non
 
 
 def data_axis_size(mesh: Mesh) -> int:
-    """Ranks along the data axis — the R in the sharded fused round's
-    per-rank row/feature math (local rows = padded // R; the scatter
-    merge pads F to a multiple of R)."""
+    """Ranks along the data axis (local rows = padded rows // R)."""
     return int(mesh.shape[DATA_AXIS])
-
-
-def make_mesh_2d(n_data: int, n_feature: int, devices: Optional[Sequence] = None) -> Mesh:
-    """(data, feature) mesh for combined data+feature parallel histograms."""
-    if devices is None:
-        devices = jax.devices()
-    devices = np.asarray(devices[: n_data * n_feature]).reshape(n_data, n_feature)
-    return Mesh(devices, (DATA_AXIS, FEATURE_AXIS))
-
-
-def make_mesh_hierarchical(num_slices: int,
-                           ranks_per_slice: Optional[int] = None,
-                           devices: Optional[Sequence] = None) -> Mesh:
-    """Nested (dcn, ici) mesh for multi-slice scale-out: ``num_slices``
-    slice groups of ``ranks_per_slice`` chips each.  On a real multi-slice
-    pod the outer axis crosses DCN (device order from the platform groups
-    slices contiguously); on the loopback CPU mesh it simulates the slice
-    boundary so the two-level merge's collective TOPOLOGY — full
-    psum/psum_scatter over ``ici`` only, top-k-shaped exchange over
-    ``dcn`` — is traceable and testable off-chip
-    (parallel/hierarchy.py, docs/DISTRIBUTED.md "Hierarchical merge")."""
-    if devices is None:
-        devices = jax.devices()
-    num_slices = int(num_slices)
-    if num_slices < 1:
-        raise ValueError(f"num_slices must be >= 1, got {num_slices}")
-    if ranks_per_slice is None:
-        if len(devices) % num_slices:
-            raise ValueError(
-                f"{len(devices)} devices do not divide into "
-                f"{num_slices} slices")
-        ranks_per_slice = len(devices) // num_slices
-    devices = np.asarray(
-        devices[: num_slices * ranks_per_slice]).reshape(
-        num_slices, ranks_per_slice)
-    return Mesh(devices, (DCN_AXIS, ICI_AXIS))
-
-
-def slice_axis_sizes(mesh: Mesh) -> tuple:
-    """(num_slices, ranks_per_slice) of a hierarchical mesh."""
-    return int(mesh.shape[DCN_AXIS]), int(mesh.shape[ICI_AXIS])
-

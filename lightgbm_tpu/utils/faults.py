@@ -43,16 +43,16 @@ Sites (see docs/ROBUSTNESS.md for the exact trigger points):
 ``worker_death``    parallel/launcher.py worker body — hard process exit at
                     the start of iteration <round>, gated to one rank via
                     ``LGBMTPU_FAULT_RANK`` (compared against the worker's
-                    ``LIGHTGBM_TPU_RANK``).
+                    ``LIGHTGBM_TPU_RANK``).  With fleet checkpoints on,
+                    the death waits until the fleet has confirmed the
+                    last checkpoint round this rank completed (slice-
+                    valid without its own slice's acks), so the scenario
+                    is "lost after round R-1 was acknowledged" on a
+                    loaded machine too.
 ``pallas_hist``     the histogram dispatcher (ops/histogram.py) — raises
                     :class:`InjectedFault` at trace time, modelling a
                     Mosaic kernel-compile failure.  <round> counts
                     dispatcher CALLS (0 = first).
-``pallas_partition``ops/partition.py::partition_rows — same semantics.
-``pallas_round``    ops/treegrow_windowed.py::grow_tree_windowed's round-
-                    megakernel attempt — same semantics; exercises the
-                    ROUND layer of the degradation net (fallback = the
-                    three-pass fused round).
 ``nonfinite_grad``  models/gbdt.py — poisons gradient element 0 with NaN at
                     1-based boosting iteration <round>.
 ``nonfinite_hess``  same, for the hessian.
@@ -111,7 +111,7 @@ _RANK_GATED_SITES = ("worker_death", "worker_hang")
 # sites whose <round> is a per-site CALL counter rather than an explicit
 # round number passed by the caller (trace-time sites have no round; the
 # serve sites count pipeline-stage touches — see the module docstring)
-_CALL_COUNTED_SITES = ("pallas_hist", "pallas_partition", "pallas_round",
+_CALL_COUNTED_SITES = ("pallas_hist",
                        "replica_dispatch", "replica_death", "replica_hang",
                        "swap_publish")
 
@@ -221,6 +221,18 @@ def armed(site: str) -> bool:
     return site in _spec()
 
 
+def would_fire(site: str, round_i: int) -> bool:
+    """Peek: True when :func:`fire` would fire for this round-stamped
+    site now.  Consumes nothing — lets a crash site wait for the state
+    its scenario assumes before it dies (parallel/launcher.py)."""
+    if _spec().get(site) != round_i or not _rank_allows(site):
+        return False
+    if (site, round_i) in _fired:
+        return False
+    marker = _once_marker(site, round_i)
+    return marker is None or not os.path.exists(marker)
+
+
 def fire(site: str, round_i: Optional[int] = None) -> bool:
     """True exactly once when ``site`` is armed for this round.
 
@@ -235,17 +247,10 @@ def fire(site: str, round_i: Optional[int] = None) -> bool:
             raise ValueError(f"site {site!r} needs an explicit round")
         round_i = _call_counts.get(site, 0)
         _call_counts[site] = round_i + 1
-    if spec[site] != round_i:
+    if not would_fire(site, round_i):
         return False
-    if not _rank_allows(site):
-        return False
-    key = (site, round_i)
-    if key in _fired:
-        return False
+    _fired.add((site, round_i))
     marker = _once_marker(site, round_i)
-    if marker is not None and os.path.exists(marker):
-        return False
-    _fired.add(key)
     if marker is not None:
         try:
             with open(marker, "w") as fh:

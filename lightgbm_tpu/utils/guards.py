@@ -14,12 +14,9 @@ path anything:
    guard signal is computed ON DEVICE inside work that is already
    dispatched (O(num_leaves) reductions folded into the growers' round
    bodies / iteration epilogue) and is only PULLED at points where the
-   host syncs anyway: the windowed grower folds a finite flag into the
-   async info vector it reads one round behind (zero extra dispatches,
-   zero blocking syncs — tests/test_retrace.py's budget pin holds with
-   guards on), and the full-pass/fast growers accumulate a
-   first-bad-iteration scalar checked at the existing deferred sync
-   points (the %32 finish probe, eval, flush, save).  Detection can
+   host syncs anyway: the growers accumulate a first-bad-iteration scalar
+   checked at the existing deferred sync points (the %32 finish probe,
+   eval, flush, save).  Detection can
    therefore lag the corruption by up to 32 iterations on the fastest
    path — the error is ROUND-STAMPED with the iteration the corruption
    entered, which is what makes the lag acceptable.

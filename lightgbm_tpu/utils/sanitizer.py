@@ -6,19 +6,18 @@ arguments, shape drift, and the actual per-round dispatch/sync traffic are
 runtime properties.  This module turns them into executable assertions: a
 process-global ``jax.monitoring`` listener counts every jaxpr trace and every
 XLA backend compile, and :class:`CompileCounter` exposes deltas so a test can
-pin "N boosting rounds at fixed shape compile exactly once" (the per-round
-recompile class docs/NEXT.md suspects in the windowed admit phase).
+pin "N boosting rounds at fixed shape compile exactly once".
 
 Dispatch side (round 7): host round loops that dispatch jitted work record
 each dispatch through :func:`record_dispatch` and route every host read of
 device data through :func:`sync_pull` (a BLOCKING pull, which stalls the
 device queue) or the :func:`async_pull_start`/:func:`async_pull_result`
 pair (a pipelined read that overlaps device compute and never stalls the
-device queue).  :class:`DispatchCounter` snapshots all of it, so "each
-steady-state windowed round is exactly ONE dispatch and ZERO blocking
-syncs" is an executable invariant (tests/test_retrace.py), not benchmark
-archaeology — and :meth:`DispatchCounter.assert_round_budget` is the gate
-the grower itself arms under ``LGBMTPU_DISPATCH_BUDGET=1``.
+device queue).  :class:`DispatchCounter` snapshots all of it, so "a warm
+predict is exactly ONE dispatch and ONE accounted sync" is an executable
+invariant (tests/test_predict_budget.py, tests/test_retrace.py), not
+benchmark archaeology — :meth:`DispatchCounter.assert_round_budget` is
+the gate.
 
 Counting is cumulative and process-wide — the listener is installed once and
 never removed (``jax.monitoring`` has no unregister; ``clear_event_listeners``
@@ -26,8 +25,8 @@ would nuke listeners we don't own).  Counters snapshot on ``__enter__`` and
 report deltas, so nesting and interleaving are safe.
 
 Donation side: XLA silently ignores ``donate_argnums`` on platforms without
-buffer aliasing (CPU warns and copies), so "the windowed grower donates its
-state" is only true where donation is supported.  :func:`donation_consumed`
+buffer aliasing (CPU warns and copies), so "the step donates its state"
+is only true where donation is supported.  :func:`donation_consumed`
 reports whether a donated input was actually invalidated, and
 :func:`assert_donation_consumed` asserts it on platforms that support
 donation while degrading to a no-op where XLA ignores it — tests stay green
@@ -241,8 +240,7 @@ def async_pull_result(x):
     """Resolve a read started with :func:`async_pull_start`.  Counted
     separately from blocking syncs: the host may wait here, but the
     device queue keeps executing the already-dispatched rounds, so
-    device utilization is unaffected (the property the windowed round
-    protocol is built on)."""
+    device utilization is unaffected."""
     with _lock:
         _counts["async_resolves"] += 1
     return np.asarray(x)
@@ -257,17 +255,14 @@ class DispatchCounter(CompileCounter):
     traces, inherited) in the enclosed block.
 
     >>> with DispatchCounter() as d:
-    ...     grow_tree_windowed(...)
-    >>> d.assert_round_budget(rounds, what="windowed growth")
+    ...     bst.predict(X)
+    >>> d.assert_round_budget(1, syncs_per_round=1, what="warm predict")
 
-    Per-rank semantics under SPMD (docs/DISTRIBUTED.md "Sharded fused
-    rounds"): the ledger is per host PROCESS.  Single-controller, the
-    host's one dispatch of a shard_mapped round IS every rank's dispatch
-    — so "1 dispatch / 0 blocking syncs per round" counted here is the
-    per-rank budget, and the in-dispatch collectives (psum/psum_scatter)
-    add neither dispatches nor host syncs by construction.  In
-    multi-controller runs each process carries its own ledger, pinning
-    its own rank's budget independently.
+    Per-rank semantics under SPMD: the ledger is per host PROCESS.
+    Single-controller, the host's one dispatch of a shard_mapped step IS
+    every rank's dispatch, and in-dispatch collectives add neither
+    dispatches nor host syncs by construction.  In multi-controller runs
+    each process carries its own ledger.
     """
 
     def __enter__(self) -> "DispatchCounter":
@@ -311,37 +306,6 @@ class DispatchCounter(CompileCounter):
                 f"(async resolves: {self.async_resolves}) — a phase was "
                 "dispatched separately or a host pull crept into the loop; "
                 "see docs/ANALYSIS.md (R6)")
-
-
-def assert_ledger_agreement(stats: dict, *, collectives_per_round: int,
-                            what: str = "sharded fused rounds") -> dict:
-    """Static-auditor <-> runtime-ledger cross-check (docs/ANALYSIS.md
-    "Jaxpr audit layer").
-
-    The jaxpr auditor (analysis/jaxpr_audit.py J1) counts the collectives
-    INSIDE the traced round executable; this check confirms the runtime
-    ledger agrees they all rode the single donated dispatch: a driver
-    ``stats`` dict (the windowed grower's) must show exactly ONE dispatch
-    and ZERO blocking host syncs per round.  If a collective had leaked
-    into the host loop (R13's runtime twin — a second dispatch or an
-    eager collective), the dispatch count would exceed the round count
-    and the two ledgers would disagree.  Returns the agreement summary
-    embedded in audit verdicts; raises :class:`BudgetError` on mismatch.
-    """
-    rounds = int(stats.get("rounds", 0))
-    dispatches = int(stats.get("dispatches", -1))
-    syncs = int(stats.get("host_syncs", -1))
-    if rounds <= 0 or dispatches != rounds or syncs != 0:
-        raise BudgetError(
-            f"{what}: runtime ledger ({rounds} rounds, {dispatches} "
-            f"dispatches, {syncs} blocking syncs) cannot carry the "
-            f"audited {collectives_per_round} in-dispatch collectives "
-            "per round — a collective or a second dispatch leaked into "
-            "the host loop; see docs/ANALYSIS.md (J1/R13)")
-    return {"rounds": rounds, "dispatches": dispatches,
-            "host_syncs": syncs,
-            "collectives_per_round": collectives_per_round,
-            "in_dispatch_collectives": rounds * collectives_per_round}
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,9 @@ assert jax.default_backend() == "cpu", (
 # traced acquire checks the witness graph and raises LockOrderError on a
 # cycle, and blocking acquires become 60s timeout-acquires so a true
 # deadlock fails the test instead of hanging the run (docs/ANALYSIS.md)
+from lightgbm_tpu.obs import metrics as _obs_metrics  # noqa: E402
+from lightgbm_tpu.utils import degrade as _degrade  # noqa: E402
+from lightgbm_tpu.utils import faults as _faults  # noqa: E402
 from lightgbm_tpu.utils import locktrace as _locktrace  # noqa: E402
 
 _locktrace.enable(True, strict=True)
@@ -69,7 +72,19 @@ def _release_compiled_programs():
     compilation_cache.put_executable_and_time or get_executable_and_time,
     some 85% of the way through, with a cold cache or a warm one (PR 21).
     What a later module shares with an earlier one comes back from the
-    persistent cache."""
+    persistent cache.
+
+    A worker runs several files in one process (``--dist loadfile``), so
+    the process-wide robustness state goes with the programs: the
+    ``utils/degrade`` registry, armed fault sites and the ``obs``
+    counters.  ``/healthz``, the serving runtime's shedding and
+    ``chip_smoke.check_no_fallback`` read those as totals of a fresh
+    process; what ``test_degrade.py`` or ``test_nonfinite.py`` left in
+    them made ``test_chip_smoke.py`` fail whenever it shared their
+    worker (PR 30)."""
     yield
     jax.clear_caches()
     gc.collect()
+    _degrade.reset()
+    _faults.reset()
+    _obs_metrics.reset()

@@ -282,19 +282,21 @@ def test_fleet_refit_one_dispatch_matches_per_lane_solo():
     X = rng.randn(N, F)
     labels = np.stack([(X[:, 0] + rng.randn(N) > 0).astype(float)
                        for _ in range(B)])
-    fb = lgb.train_fleet(dict(PARAMS), lgb.Dataset(X), labels,
-                         num_boost_round=3)
+    def lanes():
+        return [lgb.train(dict(PARAMS), lgb.Dataset(X, label=labels[b]),
+                          num_boost_round=3) for b in range(B)]
+
+    fb = lanes()
     Xn = rng.randn(N, F)
     labels_n = np.stack([(Xn[:, 0] > 0).astype(float) for _ in range(B)])
     W = rng.uniform(0.5, 2.0, (B, N))
 
     for weights in (None, W):
-        fb2 = lgb.train_fleet(dict(PARAMS), lgb.Dataset(X), labels,
-                              num_boost_round=3)
+        fb2 = lanes()
         solo = []
         for b in range(B):
-            cp = lgb.Booster(model_str=fb.booster(b).model_to_string())
-            cp._gbdt.cfg = fb.booster(b).cfg
+            cp = lgb.Booster(model_str=fb[b].model_to_string())
+            cp._gbdt.cfg = fb[b]._gbdt.cfg
             refit_leaves(cp._gbdt, Xn, labels_n[b],
                          weight=None if weights is None else weights[b])
             solo.append(cp)
@@ -304,7 +306,7 @@ def test_fleet_refit_one_dispatch_matches_per_lane_solo():
                                                          d.host_syncs)
         for b in range(B):
             ps = np.asarray(solo[b].predict(Xn[:64], raw_score=True))
-            pf = np.asarray(fb2.booster(b).predict(Xn[:64], raw_score=True))
+            pf = np.asarray(fb2[b].predict(Xn[:64], raw_score=True))
             assert np.abs(ps - pf).max() < 1e-5, (weights is not None, b)
 
     # envelope: a multiclass lane refuses loudly

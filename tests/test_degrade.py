@@ -47,17 +47,17 @@ def test_python_level_errors_never_degrade():
         raise drift
 
     with pytest.raises(AttributeError, match="RenamedParams"):
-        degrade.run_with_fallback(degrade.PARTITION, primary, lambda: "xla")
-    assert degrade.available(degrade.PARTITION)
-    assert degrade.disabled_reason(degrade.PARTITION) is None
+        degrade.run_with_fallback(degrade.HIST, primary, lambda: "xla")
+    assert degrade.available(degrade.HIST)
+    assert degrade.disabled_reason(degrade.HIST) is None
 
     def mosaic():
         raise RuntimeError("Mosaic failed to compile TPU kernel: scoped "
                            "vmem limit exceeded")
 
     assert degrade.run_with_fallback(
-        degrade.PARTITION, mosaic, lambda: "xla") == "xla"
-    assert "Mosaic" in degrade.disabled_reason(degrade.PARTITION)
+        degrade.HIST, mosaic, lambda: "xla") == "xla"
+    assert "Mosaic" in degrade.disabled_reason(degrade.HIST)
 
 
 def test_disable_logs_once(caplog):
@@ -81,55 +81,6 @@ def test_disable_logs_once(caplog):
         from lightgbm_tpu.utils import log as _log
 
         _log._logger = None
-
-
-def _partition_fixture(n=64, s=2, seed=0):
-    rng = np.random.RandomState(seed)
-    order = jnp.asarray(rng.permutation(n).astype(np.int32))
-    seg_start = jnp.asarray([0, 40], jnp.int32)
-    seg_len = jnp.asarray([24, 24], jnp.int32)
-    seg_id = np.full(n, -1, np.int32)
-    seg_id[0:24] = 0
-    seg_id[40:64] = 1
-    go_left = jnp.asarray(rng.rand(n) < 0.5)
-    return order, jnp.asarray(seg_id), seg_start, seg_len, go_left
-
-
-def test_partition_dispatcher_degrades_and_matches_xla(monkeypatch):
-    """An injected Pallas failure in partition_rows falls back to the XLA
-    permutation with IDENTICAL results, and records the degradation so
-    later traces skip the kernel entirely."""
-    from lightgbm_tpu.ops.partition import (partition_rows,
-                                            stable_partition_ranges)
-
-    order, seg_id, seg_start, seg_len, go_left = _partition_fixture()
-    ref_order, ref_counts = stable_partition_ranges(
-        order, seg_id, seg_start, seg_len, go_left)
-
-    monkeypatch.setenv("LGBMTPU_FAULT", "pallas_partition:0")
-    got_order, got_counts = partition_rows(
-        order, seg_id, seg_start, seg_len, go_left, use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(got_order), np.asarray(ref_order))
-    np.testing.assert_array_equal(np.asarray(got_counts),
-                                  np.asarray(ref_counts))
-    assert not degrade.available(degrade.PARTITION)
-    # degraded process: the kernel is skipped without needing the fault
-    got2, _ = partition_rows(order, seg_id, seg_start, seg_len, go_left,
-                             use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(got2), np.asarray(ref_order))
-
-
-def test_partition_interpret_mode_failures_surface(monkeypatch):
-    """interpret=True is the correctness harness — injected failures must
-    NOT be swallowed into a silent fallback there."""
-    from lightgbm_tpu.ops.partition import partition_rows
-
-    order, seg_id, seg_start, seg_len, go_left = _partition_fixture(seed=1)
-    monkeypatch.setenv("LGBMTPU_FAULT", "pallas_partition:0")
-    with pytest.raises(faults.InjectedFault):
-        partition_rows(order, seg_id, seg_start, seg_len, go_left,
-                       interpret=True)
-    assert degrade.available(degrade.PARTITION)
 
 
 def _hist_fixture(n=256, f=4, tile=2, bins=16, seed=0):
@@ -174,18 +125,35 @@ def test_hist_dispatcher_quantized_degrades(monkeypatch):
     assert not degrade.available(degrade.HIST)
 
 
+def _rounds_inputs(n=600, f=6, seed=9):
+    from lightgbm_tpu.binning import DatasetBinner
+    from lightgbm_tpu.ops.split import SplitParams
+
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, f)
+    y = X @ rng.randn(f) + 0.2 * rng.randn(n)
+    binner = DatasetBinner.fit(X, max_bin=31)
+    args = (jnp.asarray(binner.transform(X), jnp.int16),
+            jnp.asarray(0.6 * y, jnp.float32), jnp.ones((n,), jnp.float32),
+            jnp.ones((n,), bool), jnp.ones((n,), jnp.float32),
+            jnp.ones((f,), bool),
+            jnp.asarray(binner.num_bins_per_feature),
+            jnp.asarray(binner.missing_bin_per_feature))
+    static = dict(num_leaves=15, num_bins=32, params=SplitParams(
+        min_data_in_leaf=5.0), leaf_tile=4)
+    return args, static
+
+
 def test_grower_level_retry_catches_execute_time_failures(monkeypatch):
     """A Pallas failure that escapes the trace-time dispatchers (compile/
-    execute time) is caught by the grower wrapper: disable + regrow on
-    the XLA path from the original inputs.  Since round 16 the net is
-    LAYERED: with the megakernel forced on (``auto`` no longer selects
-    it), the first failure is attributed to the ROUND kernel (retry on
-    the three-pass round, Pallas hist still on); a second failure
-    degrades HIST and lands on the XLA path."""
-    from lightgbm_tpu.ops import treegrow_windowed as tw
+    execute time) is caught by the rounds grower's wrapper: HIST is
+    disabled and the tree regrown on the XLA path from the original
+    inputs; the next tree folds the registry into the static before
+    dispatch and never attempts the kernel."""
+    from lightgbm_tpu.ops import treegrow_fast as tf
 
     calls = []
-    real = tw._grow_windowed_impl
+    real = tf._grow_fast_impl
 
     def flaky(*args, **kwargs):
         calls.append(kwargs.get("use_pallas"))
@@ -193,36 +161,26 @@ def test_grower_level_retry_catches_execute_time_failures(monkeypatch):
             raise RuntimeError("Mosaic kernel compile failed (injected)")
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(tw, "_grow_windowed_impl", flaky)
-
-    from tests.test_nonfinite import _windowed_inputs
-
-    bins_t, grad, hess, kw, static = _windowed_inputs(seed=9)
-    static = dict(static, use_pallas=True, megakernel_opt="1")
-    tree, leaf = tw.grow_tree_windowed(bins_t, grad, hess, **kw, **static)
-    assert calls == [True, True, False]
+    monkeypatch.setattr(tf, "_grow_fast_impl", flaky)
+    args, static = _rounds_inputs()
+    tree, leaf = tf.grow_tree_fast(*args, use_pallas=True, **static)
+    assert calls == [True, False]
     assert int(tree.num_leaves) > 1
-    assert not degrade.available(degrade.ROUND)
     assert not degrade.available(degrade.HIST)
 
-    # a second tree folds the registry into the static before dispatch:
-    # no pallas attempt, no exception
     calls.clear()
-    tree2, _ = tw.grow_tree_windowed(bins_t, grad, hess, **kw, **static)
+    tf.grow_tree_fast(*args, use_pallas=True, **static)
     assert calls == [False]
 
 
 def test_grower_level_retry_does_not_swallow_real_errors(monkeypatch):
-    from lightgbm_tpu.ops import treegrow_windowed as tw
+    from lightgbm_tpu.ops import treegrow_fast as tf
 
     def broken(*args, **kwargs):
         raise ValueError("genuine bug, not a kernel failure")
 
-    monkeypatch.setattr(tw, "_grow_windowed_impl", broken)
-    from tests.test_nonfinite import _windowed_inputs
-
-    bins_t, grad, hess, kw, static = _windowed_inputs(seed=10)
+    monkeypatch.setattr(tf, "_grow_fast_impl", broken)
+    args, static = _rounds_inputs(seed=10)
     with pytest.raises(ValueError, match="genuine bug"):
-        tw.grow_tree_windowed(bins_t, grad, hess, **kw,
-                              **dict(static, use_pallas=True))
+        tf.grow_tree_fast(*args, use_pallas=True, **static)
     assert degrade.available(degrade.HIST)

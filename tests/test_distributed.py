@@ -102,17 +102,6 @@ def test_dryrun_multichip_entry():
     ge.dryrun_multichip(8)
 
 
-def test_dryrun_multislice_entry():
-    """The BENCH_MODE=multislice lever's hermetic subprocess dryrun: the
-    hierarchical round over a 2x2 nested mesh equals the single-mesh
-    sharded round at full top-k coverage (ISSUE 15)."""
-    import sys
-    sys.path.insert(0, "/root/repo")
-    import __graft_entry__ as ge
-
-    ge.dryrun_multislice_windowed(2, 2, "psum")
-
-
 def test_entry_compiles():
     import sys
     sys.path.insert(0, "/root/repo")
@@ -305,143 +294,3 @@ def test_data_parallel_monotone_intermediate(mode):
             rows[:, f_idx] = np.linspace(-2.5, 2.5, 60)
             d = np.diff(bst.predict(rows)) * sign
             assert d.min() >= -1e-6, (mode, f_idx, d.min())
-
-
-# ---------------------------------------------------------------------------
-# sharded fused windowed rounds (round 14 tentpole)
-# ---------------------------------------------------------------------------
-
-def _windowed_case(seed=5, n=1600, f=10, quant=0):
-    rng = np.random.RandomState(seed)
-    X = rng.randn(n, f)
-    y = X @ rng.randn(f) + 0.2 * rng.randn(n)
-    binner = DatasetBinner.fit(X, max_bin=31)
-    bins = binner.transform(X)
-    grad = jnp.asarray(0.6 * y, jnp.float32)
-    hess = jnp.ones((n,), jnp.float32)
-    kw = dict(num_leaves=15, num_bins=32,
-              params=SplitParams(min_data_in_leaf=5.0), leaf_tile=4,
-              use_pallas=False)
-    if quant:
-        kw.update(quantize_bins=quant, stochastic_rounding=False,
-                  quant_renew=True)
-    return X, bins, binner, grad, hess, kw
-
-
-def _assert_same_tree(tree_s, tree_d, leaf_s, leaf_d, n):
-    assert int(tree_s.num_leaves) == int(tree_d.num_leaves)
-    m = int(tree_s.num_leaves) - 1
-    for name in ("split_feature", "threshold_bin", "left_child",
-                 "right_child", "default_left"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(tree_s, name))[:m],
-            np.asarray(getattr(tree_d, name))[:m], err_msg=name)
-    np.testing.assert_allclose(
-        np.asarray(tree_s.leaf_value)[:m + 1],
-        np.asarray(tree_d.leaf_value)[:m + 1], rtol=2e-3, atol=2e-3)
-    np.testing.assert_array_equal(np.asarray(leaf_s), np.asarray(leaf_d)[:n])
-
-
-@pytest.mark.parametrize("quant", [0, 16], ids=["float", "quantized"])
-@pytest.mark.parametrize("merge", ["psum", "scatter"])
-def test_sharded_fused_windowed_equals_single_device(merge, quant):
-    """ISSUE 9 acceptance: loopback-mesh sharded fused windowed training
-    (in-dispatch psum / owned-feature psum_scatter merge) produces the
-    SAME tree as single-device windowed growth — split structure exactly,
-    leaf values to collective-ordering tolerance, shard-local leaf ids
-    equal to the serial ones — for float and int8-quantized training on
-    both merge strategies, with zero window retries and zero blocking
-    syncs."""
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-    from lightgbm_tpu.parallel.data_parallel import (
-        grow_tree_windowed_data_parallel)
-
-    X, bins, binner, grad, hess, kw = _windowed_case(quant=quant)
-    n, f = X.shape
-    qk = jax.random.PRNGKey(3) if quant else None
-    tree_s, leaf_s = grow_tree_windowed(
-        jnp.asarray(bins.T, jnp.int16), grad, hess,
-        jnp.ones((n,), bool), jnp.ones((n,), jnp.float32),
-        jnp.ones((f,), bool),
-        jnp.asarray(binner.num_bins_per_feature),
-        jnp.asarray(binner.missing_bin_per_feature), quant_key=qk, **kw)
-
-    mesh = make_mesh()
-    sd = ShardedData(mesh, bins, binner.num_bins_per_feature,
-                     binner.missing_bin_per_feature)
-    stats = {}
-    tree_d, leaf_d = grow_tree_windowed_data_parallel(
-        sd, sd.pad_rows(np.asarray(grad)), sd.pad_rows(np.asarray(hess)),
-        sd.row_valid, sd.pad_rows(np.ones(n, np.float32), fill=1.0),
-        jnp.ones((f,), bool), quant_key=qk, merge=merge, stats=stats, **kw)
-    assert stats["retries"] == 0 and stats["host_syncs"] == 0, stats
-    _assert_same_tree(tree_s, tree_d, leaf_s, leaf_d, n)
-
-
-def test_sharded_windowed_scatter_pads_undivisible_features():
-    """merge='scatter' needs F divisible by the mesh axis; a 10-feature
-    matrix on 8 devices pads to 16 dead features — the padded features
-    must never win a split and the tree must still match psum's."""
-    from lightgbm_tpu.parallel.data_parallel import (
-        grow_tree_windowed_data_parallel)
-
-    X, bins, binner, grad, hess, kw = _windowed_case(seed=8)
-    n, f = X.shape
-    assert f % 8 != 0  # the case under test
-    mesh = make_mesh()
-    sd = ShardedData(mesh, bins, binner.num_bins_per_feature,
-                     binner.missing_bin_per_feature)
-    args = (sd, sd.pad_rows(np.asarray(grad)), sd.pad_rows(np.asarray(hess)),
-            sd.row_valid, sd.pad_rows(np.ones(n, np.float32), fill=1.0),
-            jnp.ones((f,), bool))
-    t_ps, l_ps = grow_tree_windowed_data_parallel(*args, merge="psum", **kw)
-    t_sc, l_sc = grow_tree_windowed_data_parallel(*args, merge="scatter",
-                                                  **kw)
-    m = int(t_ps.num_leaves) - 1
-    assert np.asarray(t_sc.split_feature)[:m].max() < f
-    _assert_same_tree(t_ps, t_sc, l_ps[:n], l_sc, n)
-
-
-def test_sharded_windowed_scatter_refuses_bynode_sampling():
-    from lightgbm_tpu.parallel.data_parallel import (
-        grow_tree_windowed_data_parallel)
-
-    X, bins, binner, grad, hess, kw = _windowed_case()
-    n, f = X.shape
-    mesh = make_mesh()
-    sd = ShardedData(mesh, bins, binner.num_bins_per_feature,
-                     binner.missing_bin_per_feature)
-    kw["params"] = SplitParams(min_data_in_leaf=5.0,
-                               feature_fraction_bynode=0.5)
-    with pytest.raises(ValueError, match="scatter"):
-        grow_tree_windowed_data_parallel(
-            sd, sd.pad_rows(np.asarray(grad)), sd.pad_rows(np.asarray(hess)),
-            sd.row_valid, sd.pad_rows(np.ones(n, np.float32), fill=1.0),
-            jnp.ones((f,), bool), rng_key=jax.random.PRNGKey(0),
-            merge="scatter", **kw)
-
-
-def test_booster_sharded_windowed_data_and_voting(monkeypatch):
-    """Booster-level routing: tree_learner=data|voting with the windowed
-    gate forced (the real gate needs a TPU + wide shape) takes the
-    sharded fused path and trains an accurate model; voting maps to the
-    owned-feature scatter merge."""
-    from lightgbm_tpu.models.gbdt import GBDT
-
-    rng = np.random.RandomState(12)
-    X = rng.randn(4000, 6).astype(np.float32)
-    y = ((X @ rng.randn(6)) > 0).astype(np.float64)
-    monkeypatch.setattr(GBDT, "_use_windowed_dp",
-                        lambda self, ts: self._dp is not None)
-    for tl, want_merge in (("data", "psum"), ("voting", "scatter")):
-        ds = lgb.Dataset(X, label=y)
-        bst = lgb.Booster(
-            params={"objective": "binary", "num_leaves": 15,
-                    "verbosity": -1, "tree_learner": tl,
-                    "tree_growth_mode": "rounds"}, train_set=ds)
-        assert bst._gbdt._windowed_dp_merge() == want_merge
-        for _ in range(6):
-            bst.update()
-        p = bst.predict(X)
-        acc = np.mean((p > 0.5) == (y > 0))
-        assert acc > 0.9, (tl, acc)

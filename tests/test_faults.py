@@ -71,6 +71,26 @@ def test_rank_gating(monkeypatch):
     assert faults.fire("worker_death", 1)
 
 
+def test_would_fire_peeks_and_consumes_nothing(tmp_path, monkeypatch):
+    """``would_fire`` says what ``fire`` would do — round, rank gate, the
+    once-only registry and marker — and leaves all of it untouched, so a
+    crash site can wait for the state its scenario assumes and still die
+    (the launcher's ``worker_death`` waits for the fleet's ack)."""
+    monkeypatch.setenv("LGBMTPU_FAULT", "worker_death:1:5")
+    monkeypatch.setenv("LGBMTPU_FAULT_ONCE_DIR", str(tmp_path))
+    monkeypatch.setenv("LGBM_TPU_WORKER_ID", "0")
+    assert not faults.would_fire("worker_death", 5)  # another rank's
+    monkeypatch.setenv("LGBM_TPU_WORKER_ID", "1")
+    assert not faults.would_fire("worker_death", 4)  # another round's
+    for _ in range(3):
+        assert faults.would_fire("worker_death", 5)
+    assert not list(tmp_path.glob("lgbmtpu_fault_*.fired"))
+    assert faults.fire("worker_death", 5)
+    assert not faults.would_fire("worker_death", 5)  # fired: spent
+    faults.reset()  # the relaunched process: the marker still stops it
+    assert not faults.would_fire("worker_death", 5)
+
+
 def test_once_dir_markers_survive_process_registry(tmp_path, monkeypatch):
     """The cross-process once-only contract: a marker file left by the
     'first process' stops the 'second process' (fresh registry) from

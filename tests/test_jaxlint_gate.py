@@ -35,22 +35,21 @@ def test_every_suppression_carries_a_reason():
 
 
 def test_known_intentional_suppressions_are_still_needed():
-    """The suppressed set documents real, intentional exceptions.  Round 7
-    REMOVED the windowed grower's per-round sync pragma — the fused round
-    has no host pull left to suppress, and it must stay that way; the
-    fused-step factory pragmas in gbdt.py remain (this test pins the
-    floor, not the exact set)."""
+    """The suppressed set documents real, intentional exceptions: the
+    fused-step factory pragmas in gbdt.py remain, and the rounds grower
+    the cells run needs none (this test pins the floor, not the exact
+    set)."""
     report = _package_report()
     files = {Path(f.file).name for f, _ in report.suppressed}
     assert "gbdt.py" in files  # cached fused-step/eval jit factories (R2)
-    assert "treegrow_windowed.py" not in files, (
-        "the fused windowed round needs no sync pragma — a reappearing "
-        "suppression means a per-round host pull came back")
+    assert "treegrow_fast.py" not in files, (
+        "the rounds grower has no per-round host pull to suppress — a "
+        "suppression there means one came in")
 
 
 def test_all_rules_are_registered():
     assert {"R1", "R2", "R3", "R4", "R5", "R6", "R7", "R8", "R9", "R10",
-            "R11", "R12", "R13", "R14", "R15", "R16", "R17", "R18", "R19",
+            "R11", "R12", "R13", "R14", "R15", "R16", "R17", "R19",
             "R20", "R21", "L1", "L2", "L3", "L4", "L5"} <= set(RULES)
 
 

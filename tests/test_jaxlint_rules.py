@@ -1205,7 +1205,7 @@ def test_r9_negative_same_line_pull(tmp_path):
 
 
 def test_r9_negative_async_pull_protocol_and_no_dispatch(tmp_path):
-    """The windowed driver's shape: an async_pull_result between dispatch
+    """A pipelined driver's shape: an async_pull_result between dispatch
     and read accounts the section; a delta with no dispatch inside its
     window is plain host timing."""
     rep = _scan(tmp_path, {"mod.py": """
@@ -2094,97 +2094,6 @@ def test_r17_nested_def_neither_duplicates_nor_misses_enclosing_gather(
     """}, rules=["R17"])
     assert len(rep.findings) == 1, rep.findings
     assert "outer_bad" in rep.findings[0].message
-
-
-# ---------------------------------------------------------------------------
-# R18 host-loop-over-independent-boosters
-# ---------------------------------------------------------------------------
-
-def test_r18_positive_train_per_dataset_loop(tmp_path):
-    rep = _scan(tmp_path, {"mod.py": """
-        import lightgbm_tpu as lgb
-
-        def sweep(params, datasets):
-            boosters = []
-            for ds in datasets:
-                boosters.append(lgb.train(params, ds, num_boost_round=50))
-            return boosters
-    """}, rules=["R18"])
-    assert len(rep.findings) == 1
-    assert rep.findings[0].rule == "R18"
-    assert "host loop" in rep.findings[0].message
-
-
-def test_r18_positive_refit_and_iter_over_model_dict(tmp_path):
-    """Both non-train entry spellings fire, qualified or bare, keyed or
-    enumerated."""
-    rep = _scan(tmp_path, {"mod.py": """
-        from lightgbm_tpu.continual import refit_leaves
-
-        def renew_all(models, X, ys):
-            for name, g in models.items():
-                refit_leaves(g, X, ys[name])
-
-        def advance_all(lanes, grads):
-            for i in range(len(lanes)):
-                lanes[i].train_one_iter(grads[i])
-    """}, rules=["R18"])
-    assert len(rep.findings) == 2, rep.findings
-    assert {f.rule for f in rep.findings} == {"R18"}
-
-
-def test_r18_negative_loop_carried_dependence(tmp_path):
-    """Warm-start chains and a running score feeding the next refit are
-    sequential by construction — iteration i reads what iteration i-1
-    assigned."""
-    rep = _scan(tmp_path, {"mod.py": """
-        import lightgbm_tpu as lgb
-        from lightgbm_tpu.continual import refit_leaves
-
-        def warm_chain(params, datasets):
-            bst = None
-            for ds in datasets:
-                bst = lgb.train(params, ds, init_model=bst)
-            return bst
-
-        def staged_refit(g, chunks):
-            y = None
-            for X, y_next in chunks:
-                if y is not None:
-                    refit_leaves(g, X, y)
-                y = y_next
-    """}, rules=["R18"])
-    assert rep.findings == []
-
-
-def test_r18_negative_unrelated_train_methods(tmp_path):
-    """`.train()` on arbitrary objects (torch-style mode switches, a
-    scheduler) is not the package entry — the spelling heuristic keeps
-    them out of scope."""
-    rep = _scan(tmp_path, {"mod.py": """
-        def toggle(modules):
-            for m in modules:
-                m.train()
-
-        def drive(trainers, batches):
-            for t, b in zip(trainers, batches):
-                t.model.train(b)
-    """}, rules=["R18"])
-    assert rep.findings == []
-
-
-def test_r18_pragma_suppression(tmp_path):
-    rep = _scan(tmp_path, {"mod.py": """
-        import lightgbm_tpu as lgb
-
-        def baseline(params, datasets):
-            out = []
-            for ds in datasets:
-                out.append(lgb.train(params, ds))  # jaxlint: disable=R18 (fixture: the measured host-loop baseline itself)
-            return out
-    """}, rules=["R18"])
-    assert rep.findings == []
-    assert len(rep.suppressed) == 1
 
 
 # ---------------------------------------------------------------------------

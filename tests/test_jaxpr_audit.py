@@ -2,18 +2,14 @@
 "Jaxpr audit layer").
 
 Gate half: every registered contract (analysis/contracts.py) must verify
-clean on the container CPU — the sharded fused round shows exactly the
-declared collectives (ONE large merge per strategy) on the declared mesh
-axis, every live donated buffer is consumable, zero f64 casts, zero host
-callbacks, the live-set estimate under budget — and the runtime
-DispatchCounter ledger agrees the collectives all rode the single
-per-round dispatch.  This is the static gate for the regression class
-the AST rules cannot see (the shared ``_run_fused_rounds`` driver
-dispatches through a closure, R1/R6/R13 static-limits note).
+clean on the container CPU — exactly the declared collectives (none, for
+every contract the package has) on the declared mesh axis, every live
+donated buffer consumable, zero f64 casts, zero host callbacks, the
+live-set estimate under budget.  This is the static gate for the
+regression class the AST rules cannot see.
 
 Fixture half: each J rule is exercised on a deliberately broken tiny
-executable (all under 8192 rows, so windowed fixtures stay on one
-W-ladder rung), mirroring tests/test_jaxlint_rules.py's
+executable, mirroring tests/test_jaxlint_rules.py's
 positive/negative/waiver pattern.
 """
 
@@ -35,16 +31,10 @@ def report():
 
 def test_contract_catalogue_pins_the_flagships():
     assert {
-        "windowed_round_float", "windowed_round_quantized",
-        "windowed_round_sharded_psum", "windowed_round_sharded_scatter",
-        "windowed_round_hierarchical_psum",
-        "windowed_round_hierarchical_voting",
-        "windowed_round_2d_float", "windowed_round_2d_quantized",
         "predict_warm_single", "predict_warm_multiclass",
         "predict_warm_converted", "predict_coalesced_bucket",
         "ooc_root_chunk", "ooc_split_chunk", "continual_refit_leaves",
-        "fleet_round_batched",
-    } <= set(CONTRACTS)
+    } == set(CONTRACTS)
 
 
 def test_all_contracts_verify_clean(report):
@@ -54,45 +44,10 @@ def test_all_contracts_verify_clean(report):
         + "\n".join(f.format() for f in report.findings))
 
 
-def test_sharded_rounds_have_exactly_one_large_collective(report):
-    """The headline invariant: per merge strategy, ONE collective moves
-    histogram-sized bytes; everything else is scalar protocol traffic."""
-    for r in report.results:
-        if not r.name.startswith("windowed_round_sharded"):
-            continue
-        assert r.detail.get("large_collectives") == 1, (r.name, r.detail)
-
-
-def test_2d_round_histogram_phase_never_crosses_the_feature_axis(report):
-    """The wide-F headline: in the 2-D round, the histogram phase is a
-    row-axis psum ALONE — the owned feature block's histograms are
-    complete by layout, so the sequence shows ZERO hist-sized
-    feature-axis traffic, and the per-axis byte bill proves the feature
-    axis carries only the go/no-go row broadcast + election scalars."""
-    from lightgbm_tpu.analysis.contracts import _2D_FEATURE_BUDGET
-    for name in ("windowed_round_2d_float", "windowed_round_2d_quantized"):
-        r = {x.name: x for x in report.results}[name]
-        toks = r.detail["collectives"]
-        # exactly one @data-only psum (the histogram merge) and it is the
-        # largest collective in the round
-        data_only = [t for t in toks if t == "psum@data"]
-        assert len(data_only) == 3, (name, toks)  # 2 protocol + 1 hist
-        bills = r.detail["axis_bytes"]
-        assert bills["feature"] <= _2D_FEATURE_BUDGET, (name, bills)
-        assert r.detail["feature_bytes"] == bills["feature"]
-        # the row axis carries the histogram merge: orders of magnitude
-        # more bytes than the feature axis at any realistic shape
-        assert bills["data"] > bills["feature"], (name, bills)
-
-
 def test_single_device_bodies_are_collective_free(report):
+    assert {r.name for r in report.results} == set(CONTRACTS)
     for r in report.results:
-        if r.name in ("windowed_round_float", "windowed_round_quantized",
-                      "predict_warm_single", "predict_warm_multiclass",
-                      "predict_warm_converted", "predict_coalesced_bucket",
-                      "ooc_root_chunk", "ooc_split_chunk",
-                      "continual_refit_leaves", "fleet_round_batched"):
-            assert r.detail.get("collectives") == [], (r.name, r.detail)
+        assert r.detail.get("collectives") == [], (r.name, r.detail)
 
 
 def test_coalesced_dispatch_is_the_warm_predict_family():
@@ -127,39 +82,12 @@ def test_continual_refit_is_one_donated_collective_free_dispatch(report):
 def test_donations_all_consumable(report):
     """J2 detail: every live donated leaf structurally matched an output
     (and on the single-device lowering, actually carries the aliasing
-    attr — the sharded CPU lowering drops aliasing wholesale, which is
-    why the structural check is the platform-independent half)."""
+    attr)."""
     for r in report.results:
         live = r.detail.get("live_donated_leaves")
         if not live:
             continue
-        if r.name.startswith(("windowed_round_sharded",
-                              "windowed_round_hierarchical",
-                              "windowed_round_2d")):
-            continue  # aliasing attrs absent in multi-device CPU lowering
         assert r.detail.get("aliased_in_lowering") == live, (r.name, r.detail)
-
-
-def test_ledger_crosscheck_agrees(report):
-    """The sanitizer cross-check: the tiny sharded training's runtime
-    ledger shows 1 dispatch / 0 blocking syncs per round, so every
-    audited collective rode the one donated dispatch."""
-    for merge in ("psum", "scatter"):
-        summary = report.ledger[merge]
-        assert summary["dispatches"] == summary["rounds"] > 0, summary
-        assert summary["host_syncs"] == 0, summary
-        assert summary["collectives_per_round"] == len(
-            CONTRACTS[f"windowed_round_sharded_{merge}"].collectives)
-
-
-def test_windowed_fixture_shapes_stay_on_one_rung():
-    """All audited windowed fixtures sit under 8192 rows — the floor
-    W-ladder rung — so the traced executable is the same one-rung round
-    the budget pins exercise."""
-    from lightgbm_tpu.analysis.contracts import _N, _W
-    from lightgbm_tpu.ops.treegrow_windowed import _window_size
-    assert _N < 8192
-    assert _window_size(max(_N // 2, 1), _N) == _W == 8192
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +101,7 @@ def _fixture_contract(name, build, *, collectives=(), donated_args=(),
         name=name, description="fixture", build=build,
         collectives=tuple(collectives), donated_args=tuple(donated_args),
         max_const_bytes=max_const_bytes, max_live_bytes=max_live_bytes,
-        family="", spine=(0, 0), waivers=dict(waivers or {}),
+        waivers=dict(waivers or {}),
         file=__file__, line=0)
 
 
@@ -192,9 +120,8 @@ def _loopback_shard_map(body, n_out=1):
 
 
 def test_j1_two_collective_round_fails():
-    """A deliberately TWO-psum round body against a one-psum declaration:
-    the exact regression (a second in-dispatch merge) R13 cannot see
-    through the closure dispatch."""
+    """A deliberately TWO-psum body against a one-psum declaration: the
+    regression (a second in-dispatch merge) source lint cannot see."""
     import jax
     import jax.numpy as jnp
 
@@ -378,163 +305,5 @@ def test_waiver_suppresses_with_reason_and_p0_without():
 def test_cli_jaxpr_selection_and_exit_codes():
     from lightgbm_tpu.analysis.__main__ import main
     assert main(["--list-contracts"]) == 0
-    assert main(["--jaxpr", "--contract", "ooc_root_chunk",
-                 "--no-runtime"]) == 0
+    assert main(["--jaxpr", "--contract", "ooc_root_chunk"]) == 0
     assert main(["--jaxpr", "--contract", "no_such_contract"]) == 2
-
-
-# ---------------------------------------------------------------------------
-# J7: hbm-sweep-bound (ISSUE 11 — the megakernel's 3->1 claim, pinned)
-# ---------------------------------------------------------------------------
-
-def test_j7_megakernel_vs_three_pass_sweep_pins(report):
-    """The headline: at the W=N sweep fixture, the megakernel round reads
-    the bin matrix ONCE (+ the tile/f decisions-gather epsilon) where the
-    legacy three-pass round reads it three times — pinned on the traced
-    IR, not hoped."""
-    detail = {r.name: r.detail for r in report.results}
-    mk = detail["windowed_round_megakernel"]["bin_sweeps"]
-    legacy = detail["windowed_round_three_pass_sweeps"]["bin_sweeps"]
-    assert 1.0 <= mk <= 1.1, mk
-    assert 3.0 <= legacy <= 3.2, legacy
-    assert legacy / mk > 2.5  # the 3->1 fusion, as an IR-level ratio
-
-
-def test_j7_sharded_megakernel_keeps_merge_protocol(report):
-    """The sharded megakernel round's collective sequence is IDENTICAL to
-    the legacy sharded round's — the single in-dispatch histogram merge
-    unchanged (the ISSUE's sharded constraint)."""
-    detail = {r.name: r.detail for r in report.results}
-    assert (detail["windowed_round_sharded_megakernel_psum"]["collectives"]
-            == detail["windowed_round_sharded_psum"]["collectives"])
-    assert detail["windowed_round_sharded_megakernel_psum"][
-        "large_collectives"] == 1
-
-
-def test_j7_extra_sweep_fails():
-    """A deliberately second full read of the bin matrix (the regression
-    class: a new bin consumer added OUTSIDE the kernel) breaks the
-    1-sweep budget."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    def round_body(bins, rows):
-        w = bins[:, rows].T            # sweep 1: the window gather
-        again = bins[:, rows].T        # sweep 2: the smuggled re-read
-        return (w.astype(jnp.int32).sum()
-                + again.astype(jnp.int32).sum())
-
-    n, f = 1024, 16
-    c = dataclasses.replace(
-        _fixture_contract(
-            "fixture_extra_sweep",
-            lambda: Target(jax.jit(round_body),
-                           (jax.ShapeDtypeStruct((f, n), jnp.int16),
-                            jax.ShapeDtypeStruct((n,), jnp.int32)), {})),
-        bin_arg=0, max_bin_sweeps=2.5)
-    res = jaxpr_audit.audit_contract(c)
-    assert any(f.rule == "J7" for f in res.findings), res.findings
-    assert res.detail["bin_sweeps"] > 2.5
-
-
-def _axis_mapped_ici_sequence(tokens):
-    """Map a hierarchical round's collective tokens onto the legacy
-    single-axis vocabulary: drop dcn-only collectives (the top-k
-    election), rename both-axes scalar merges and ici merges to the
-    legacy 'data' axis."""
-    out = []
-    for t in tokens:
-        name, _, axes = t.partition("@")
-        ax = set(axes.split(","))
-        if ax == {"dcn"}:
-            continue  # the election block: dcn-only, by design
-        assert "ici" in ax, t
-        out.append(f"{name}@data")
-    return out
-
-
-def test_hierarchical_ici_sequence_equals_legacy_sharded(report):
-    """ISSUE 15 acceptance: per slice, the hierarchical round's ici
-    collective sequence is IDENTICAL to the legacy sharded round's —
-    the intra-slice merge (J1 sequence) is unchanged; only the dcn
-    election block is new."""
-    detail = {r.name: r.detail for r in report.results}
-    for hier, legacy in (
-            ("windowed_round_hierarchical_psum",
-             "windowed_round_sharded_psum"),
-            ("windowed_round_hierarchical_voting",
-             "windowed_round_sharded_scatter")):
-        assert (_axis_mapped_ici_sequence(detail[hier]["collectives"])
-                == detail[legacy]["collectives"]), (hier, legacy)
-
-
-def test_hierarchical_dcn_bytes_pinned(report):
-    """The cross-slice byte bill: both hierarchical contracts carry a
-    dcn_bytes detail under the declared dcn_max_bytes budget — ≤ top-k
-    histograms' worth per round — and exactly TWO large collectives
-    (one intra-slice merge + one top-k exchange), the dcn one bounded."""
-    from lightgbm_tpu.analysis.contracts import (
-        _BINS, _HIER_TOPK, _TILE)
-
-    k_hist_bytes = 2 * _TILE * 3 * _HIER_TOPK * _BINS * 4
-    for name in ("windowed_round_hierarchical_psum",
-                 "windowed_round_hierarchical_voting"):
-        c = CONTRACTS[name]
-        r = {x.name: x for x in report.results}[name]
-        assert c.dcn_max_bytes is not None
-        assert 0 < r.detail["dcn_bytes"] <= c.dcn_max_bytes, r.detail
-        # the election's histogram payload dominates; scalar slack only
-        assert r.detail["dcn_bytes"] <= k_hist_bytes + 1024, r.detail
-        assert r.detail["large_collectives"] == 2, r.detail
-
-
-def test_dcn_bytes_fixture_full_histogram_over_dcn_fails():
-    """A deliberately full-F histogram psum over the dcn axis against a
-    top-k-sized budget: the regression class the hierarchical merge
-    exists to prevent (and jaxlint R17 flags at the source level)."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from lightgbm_tpu.analysis.jaxpr_audit import dcn_axis_bytes
-    from jax import shard_map
-    from lightgbm_tpu.parallel.mesh import make_mesh_hierarchical
-
-    mesh = make_mesh_hierarchical(2, min(2, max(1, jax.device_count() // 2)))
-
-    def body(h):  # (C, 3, F, B) full histogram block
-        h = jax.lax.psum(h, "ici")          # intra-slice: fine
-        return jax.lax.psum(h, "dcn")       # full-F over DCN: the bug
-
-    fn = jax.jit(shard_map(
-        body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-        check_vma=False))
-    c = dataclasses.replace(
-        _fixture_contract(
-            "fixture_full_hist_over_dcn",
-            lambda: Target(
-                fn, (jax.ShapeDtypeStruct((8, 3, 64, 32), jnp.float32),),
-                {}),
-            collectives=("psum@ici", "psum@dcn")),
-        dcn_max_bytes=4096)
-    res = jaxpr_audit.audit_contract(c)
-    assert any(f.rule == "J1" and "dcn" in f.message
-               for f in res.findings), res.findings
-    assert res.detail["dcn_bytes"] == 8 * 3 * 64 * 32 * 4
-    # and the helper counts only dcn-crossing collectives
-    assert dcn_axis_bytes([("psum", ("ici",), 100),
-                           ("psum", ("ici", "dcn"), 8),
-                           ("psum", ("dcn",), 50)]) == 58
-
-
-def test_j7_detail_rides_the_artifact_verdict():
-    """bench.py embeds verdict(); the J7-pinned contracts must appear in
-    it so chip artifact rows carry the sweep proof next to J1-J6."""
-    from lightgbm_tpu.analysis.contracts import CONTRACTS
-    pinned = [n for n, c in CONTRACTS.items() if c.max_bin_sweeps]
-    assert "windowed_round_megakernel" in pinned
-    assert "windowed_round_three_pass_sweeps" in pinned

@@ -1,12 +1,9 @@
 """Non-finite guard rails (docs/ROBUSTNESS.md): boundary validation at
-Dataset construction, the windowed grower's info-vector guard (which must
-cost zero extra dispatches/syncs — the round-7 budget pin holds with
-guards on), and the deferred device-side guard on the fast/full-pass
-paths."""
+Dataset construction and the deferred device-side guard on the rounds
+and strict growers' paths (which must cost zero accounted syncs between
+the sync points the loop already has)."""
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 import pytest
 
 import lightgbm_tpu as lgb
@@ -82,67 +79,55 @@ def test_nan_features_are_still_fine():
 
 
 # ---------------------------------------------------------------------------
-# windowed grower: guard rides the async info vector
+# rounds grower: the guard is folded on device, pulled at sync points only
 # ---------------------------------------------------------------------------
 
-def _windowed_inputs(n=900, f=8, seed=5):
-    from lightgbm_tpu.binning import DatasetBinner
-    from lightgbm_tpu.ops.split import SplitParams
-
-    rng = np.random.RandomState(seed)
-    X = rng.randn(n, f)
-    y = X @ rng.randn(f) + 0.2 * rng.randn(n)
-    binner = DatasetBinner.fit(X, max_bin=31)
-    bins_t = jnp.asarray(binner.transform(X).T, jnp.int16)
-    grad = jnp.asarray(0.6 * y, jnp.float32)
-    kw = dict(
-        row_mask=jnp.ones((n,), bool),
-        sample_weight=jnp.ones((n,), jnp.float32),
-        feature_mask=jnp.ones((f,), bool),
-        num_bins_pf=jnp.asarray(binner.num_bins_per_feature),
-        missing_bin_pf=jnp.asarray(binner.missing_bin_per_feature),
-    )
-    static = dict(num_leaves=15, num_bins=32, params=SplitParams(
-        min_data_in_leaf=5.0), leaf_tile=4, use_pallas=False)
-    return bins_t, grad, jnp.ones((n,), jnp.float32), kw, static
+_ROUNDS = {"objective": "regression", "tree_growth_mode": "rounds",
+           "fused_training": False, "num_leaves": 15, "min_data_in_leaf": 5,
+           "verbosity": -1}
 
 
-def test_windowed_guard_raises_round_stamped_without_syncs():
-    """NaN gradients must abort windowed growth with a round-stamped
-    error, and the guard must have ridden the async info vector: zero
-    blocking host pulls even on the failure path."""
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
+def test_rounds_grower_guard_raises_iteration_stamped_without_syncs(
+        monkeypatch):
+    """NaN gradients entering the rounds grower's unfused loop at
+    iteration 2 abort the run with that iteration in the message, and the
+    guard rode the device: no accounted host pull in the updates before
+    the sync point (here: serialization) that reads it."""
     from lightgbm_tpu.utils.sanitizer import DispatchCounter
 
-    bins_t, grad, hess, kw, static = _windowed_inputs()
-    bad = grad.at[0].set(np.nan)
+    X, y = _data(n=900, f=8, seed=5)
+    monkeypatch.setenv("LGBMTPU_FAULT", "nonfinite_grad:2")
+    bst = lgb.Booster(params=dict(_ROUNDS), train_set=lgb.Dataset(X, label=y))
+    assert bst._gbdt._use_fast
     with DispatchCounter() as d:
-        with pytest.raises(NonFiniteError, match=r"windowed round \d"):
-            grow_tree_windowed(bins_t, bad, hess, **kw, **static,
-                               guard_label=" (boosting iteration 1)")
+        for _ in range(4):
+            bst.update()
     assert d.host_syncs == 0
+    with pytest.raises(NonFiniteError, match="iteration 2"):
+        bst.model_to_string()
 
 
-def test_windowed_clean_budget_pin_with_guards_on():
-    """The acceptance pin restated locally: with the finite guard folded
-    into the info vector, a steady-state windowed round is still exactly
-    ONE dispatch and ZERO blocking syncs (the wider retrace pin lives in
+def test_rounds_grower_clean_budget_pin_with_guards_on():
+    """With the finite guard folded into every iteration's epilogue, a
+    steady-state rounds-grower update still compiles nothing and pulls
+    nothing through the accounted ledger (the wider retrace pin lives in
     tests/test_retrace.py)."""
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
     from lightgbm_tpu.utils.sanitizer import DispatchCounter
 
-    bins_t, grad, hess, kw, static = _windowed_inputs(seed=6)
-    tree, leaf = grow_tree_windowed(bins_t, grad, hess, **kw, **static)
-    jax.block_until_ready(leaf)  # warmup compiles
+    X, y = _data(n=900, f=8, seed=6)
+    bst = lgb.Booster(params=dict(_ROUNDS), train_set=lgb.Dataset(X, label=y))
+    for _ in range(2):
+        bst.update()
+    np.asarray(bst._gbdt._score)  # warmup compiles, drained
 
-    stats = {}
     with DispatchCounter() as d:
-        tree, leaf = grow_tree_windowed(bins_t, grad, hess, **kw, **static,
-                                        stats=stats)
-        jax.block_until_ready(leaf)
-    assert int(tree.num_leaves) > 1
-    d.assert_round_budget(stats["rounds"], what="windowed rounds, guards on")
-    assert stats["host_syncs"] == 0 and stats["retries"] == 0, stats
+        for _ in range(3):
+            bst.update()
+        np.asarray(bst._gbdt._score)
+    assert d.host_syncs == 0
+    d.assert_no_recompile("rounds-grower updates, guards on")
+    assert bst.num_trees() == 5
+    bst.model_to_string()  # the guard's sync point: clean
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Observability subsystem (round 10, docs/OBSERVABILITY.md): registry
 semantics, event schema, snapshot round-trip, fleet aggregation — plus THE
-acceptance pin: with telemetry default-on, the round-7 windowed budget
-(1 dispatch / 0 blocking syncs / 0 retraces per steady-state round) and the
+acceptance pin: with telemetry default-on, the rounds grower's training
+budget (0 accounted syncs / 0 retraces per steady-state update) and the
 round-9 serving budget (warm predict = 1 dispatch + 1 pull) hold unchanged
 while the run leaves a non-empty, schema-valid metrics snapshot covering
 train, predict, and a robustness event.
@@ -302,24 +302,18 @@ def test_telemetry_param_disables_registry():
 # ---------------------------------------------------------------------------
 
 def test_budgets_hold_with_telemetry_on_and_snapshot_covers_run(tmp_path):
-    """ISSUE 5 acceptance, extended by ISSUE 6: train (windowed
-    steady-state round budget) + predict (warm serving budget) with the
-    registry active, SPAN TRACING recording, and the HTTP endpoint
+    """ISSUE 5 acceptance, extended by ISSUE 6: train (the rounds
+    grower's steady-state update budget) + predict (warm serving budget)
+    with the registry active, SPAN TRACING recording, and the HTTP endpoint
     serving live — then assert a schema-valid snapshot covering train,
     predict, and a robustness event (an injected kernel degrade).  The
-    round-11 contract is that live introspection adds zero dispatches,
-    zero blocking syncs, and zero retraces to both budgets."""
+    round-11 contract is that live introspection adds zero accounted
+    syncs and zero retraces to both budgets."""
     import json as _json
     import urllib.request
 
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.binning import DatasetBinner
     from lightgbm_tpu.obs import server as obs_server
     from lightgbm_tpu.obs import trace as obs_trace
-    from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
     from lightgbm_tpu.utils import degrade
     from lightgbm_tpu.utils.sanitizer import DispatchCounter
 
@@ -327,63 +321,26 @@ def test_budgets_hold_with_telemetry_on_and_snapshot_covers_run(tmp_path):
     obs_trace.reset_trace()
     srv = obs_server.MetricsServer(port=0).start()  # live while we train
 
-    # -- train side: the round-7 budget pin with telemetry recording -----
-    n, f = 900, 8
-    rng = np.random.RandomState(5)
-    X = rng.randn(n, f)
-    yv = X @ rng.randn(f) + 0.2 * rng.randn(n)
-    binner = DatasetBinner.fit(X, max_bin=31)
-    bins_t = jnp.asarray(binner.transform(X).T, jnp.int16)
-    kw = dict(
-        row_mask=jnp.ones((n,), bool),
-        sample_weight=jnp.ones((n,), jnp.float32),
-        feature_mask=jnp.ones((f,), bool),
-        num_bins_pf=jnp.asarray(binner.num_bins_per_feature),
-        missing_bin_pf=jnp.asarray(binner.missing_bin_per_feature),
-    )
-    static = dict(num_leaves=15, num_bins=32,
-                  params=SplitParams(min_data_in_leaf=5.0), leaf_tile=4,
-                  use_pallas=False)
-    g0 = jnp.asarray(0.6 * yv, jnp.float32)
-    g1 = jnp.asarray(0.6 * yv + 0.05, jnp.float32)
-    hess = jnp.ones((n,), jnp.float32)
-    tree, leaf = grow_tree_windowed(bins_t, g0, hess, **kw, **static)
-    jax.block_until_ready(leaf)  # warmup compiles
-
-    stats = {}
+    # -- train side: the rounds grower's unfused loop, telemetry on ------
+    rounds_bst, _, _ = _tiny_train(
+        {"tree_growth_mode": "rounds", "fused_training": False,
+         "num_leaves": 15, "min_data_in_leaf": 5}, rounds=2)  # warmup
+    assert rounds_bst._gbdt._use_fast
+    np.asarray(rounds_bst._gbdt._score)
     with DispatchCounter() as d:
-        tree, leaf = grow_tree_windowed(bins_t, g1, hess, **kw, **static,
-                                        stats=stats)
-        jax.block_until_ready(leaf)
-    d.assert_round_budget(stats["rounds"],
-                          what="windowed + telemetry + tracing + server")
-    assert stats["host_syncs"] == 0 and stats["retries"] == 0, stats
-    d.assert_no_recompile("windowed steady state with telemetry on")
-    # the grower left per-round + per-tree spans, all closed at the
-    # accounted async-info resolves (ZERO extra syncs, pinned just above)
-    assert obs_trace.spans("windowed_round"), "no windowed_round spans"
-    assert obs_trace.spans("windowed_tree"), "no windowed_tree spans"
-    # reconciliation: every dispatched round has its span — the pipeline's
-    # final in-flight round resolves in the drain loop and must be traced
-    # there too (its spans carry drained=True)
-    total_rounds = sum(s["attrs"]["rounds"]
-                       for s in obs_trace.spans("windowed_tree"))
-    assert len(obs_trace.spans("windowed_round")) == total_rounds
-    assert any(s["attrs"].get("drained")
-               for s in obs_trace.spans("windowed_round"))
-    # round-12 W-ladder context: every round span carries its rung, the
-    # transition that led there, and the whint it emitted — the rung must
-    # agree with the W the round ran on, and the deltas must chain
-    # (rung[i] - rung[i-1]) within one tree's span sequence
-    from lightgbm_tpu.ops.treegrow_windowed import _window_rung
-    wspans = obs_trace.spans("windowed_round")
-    for s in wspans:
-        a = s["attrs"]
-        assert a["rung"] == _window_rung(a["W"], n) and "whint" in a
-    for prev, cur in zip(wspans, wspans[1:]):
-        if not cur["attrs"]["first"]:
-            assert (cur["attrs"]["rung_delta"]
-                    == cur["attrs"]["rung"] - prev["attrs"]["rung"])
+        for _ in range(3):
+            rounds_bst.update()
+        np.asarray(rounds_bst._gbdt._score)
+    assert d.host_syncs == 0, d.host_syncs
+    d.assert_no_recompile("rounds-grower updates with telemetry on")
+    # every update left its host-causal span
+    assert len(obs_trace.spans("boost_round")) == 5
+    # the pass counters come off arrays the flush holds on the host
+    assert rounds_bst.num_trees() == 5
+    passes = obs.counter("train_hist_passes_total").value
+    assert passes >= 5, passes
+    assert (obs.counter("train_hist_rows_streamed_total").value
+            == passes * 800)
 
     # -- predict side: the round-9 warm budget with telemetry recording --
     bst, Xb, _ = _tiny_train(rounds=4)
@@ -399,7 +356,7 @@ def test_budgets_hold_with_telemetry_on_and_snapshot_covers_run(tmp_path):
     # -- the HTTP endpoint served the whole run and sees both families --
     prom_live = urllib.request.urlopen(
         srv.url("/metrics"), timeout=10).read().decode()
-    assert "lgbmtpu_train_windowed_rounds_total" in prom_live
+    assert "lgbmtpu_train_hist_passes_total" in prom_live
     assert "lgbmtpu_predict_requests_total" in prom_live
     assert 'lgbmtpu_predict_warm_latency_ms{bucket="' in prom_live
     hz = urllib.request.urlopen(srv.url("/healthz"), timeout=10)
@@ -424,20 +381,19 @@ def test_budgets_hold_with_telemetry_on_and_snapshot_covers_run(tmp_path):
     snap = obs.snapshot()
     obs.validate_snapshot(snap)
     c = snap["counters"]
-    assert c["train_windowed_rounds_total"] >= stats["rounds"]  # train
-    assert c["train_boost_rounds_total"] == 4
+    assert c["train_hist_passes_total"] == passes  # train
+    assert c["train_boost_rounds_total"] == 5 + 4
     assert c["predict_requests_total"] >= 2  # predict
     assert c["predict_bucket_hits_total"] >= 1
     assert snap["histograms"]["predict_warm_latency_ms"]["count"] >= 1
-    assert snap["histograms"]["train_window_rows"]["count"] >= 1
     assert c["degrade_disabled_total"] == 1  # robustness
     assert c["device_dispatches_total"] >= 1  # sanitizer collector merged
     kinds = {e["kind"] for e in obs.events()}
-    assert {"boost_round", "windowed_tree", "degrade"} <= kinds
+    assert {"boost_round", "degrade"} <= kinds
     # and the snapshot round-trips to a readable artifact
     path = str(tmp_path / "acceptance.json")
     obs.write_snapshot(path, snap)
-    assert "lgbmtpu_train_windowed_rounds_total" in obs.render_prometheus(
+    assert "lgbmtpu_train_hist_passes_total" in obs.render_prometheus(
         obs.load_snapshot(path))
 
 

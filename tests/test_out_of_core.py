@@ -12,8 +12,9 @@ change a trained model by a single bit.
   scatter-add fold is order-preserving — bitwise vs IN-MEMORY training
   on the scatter histogram strategy (max_bin > 64), across chunk sizes
   {1 row, odd, pow2, N}.
-* the windowed grower's 1-dispatch/0-sync steady-state budget stays
-  green when fed from a stream-assembled (out_of_core resident) matrix.
+* the rounds grower's steady state (no sync, no retrace) and its trees
+  stay the same when fed from a stream-assembled (out_of_core resident)
+  matrix.
 """
 
 import os
@@ -289,20 +290,20 @@ def test_spill_dispatch_accounting(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the windowed budget pin with out_of_core on (resident regime)
+# the rounds grower on an out_of_core resident matrix
 # ---------------------------------------------------------------------------
 
-def test_windowed_budget_green_on_stream_assembled_matrix(tmp_path):
-    """ISSUE acceptance: the steady-state windowed budget (1 dispatch /
-    0 syncs / 0 retraces per round) holds when the grower's bins come
-    from an out_of_core stream-assembled device matrix — the chunk feed
-    happens at ingest, the round loop's async-info protocol is
-    untouched."""
+def test_rounds_grower_green_on_stream_assembled_matrix(tmp_path):
+    """ISSUE acceptance: the rounds grower's steady state (0 accounted
+    syncs / 0 retraces for a further tree) holds when its bins come from
+    an out_of_core stream-assembled device matrix, and the tree is the
+    in-memory matrix's tree bit for bit — the chunk feed happens at
+    ingest, the grower's loop is untouched."""
     import jax
     import jax.numpy as jnp
 
     from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
+    from lightgbm_tpu.ops.treegrow_fast import grow_tree_fast
     from lightgbm_tpu.utils.sanitizer import DispatchCounter
 
     rng = np.random.RandomState(11)
@@ -320,32 +321,35 @@ def test_windowed_budget_green_on_stream_assembled_matrix(tmp_path):
     np.testing.assert_array_equal(
         np.asarray(ooc.bins_device), np.asarray(mem.bins_device))
 
-    bins_t = ooc.bins_device_t()
+    ones = jnp.ones((n,), jnp.float32)
     kw = dict(
         row_mask=jnp.ones((n,), bool),
-        sample_weight=jnp.ones((n,), jnp.float32),
+        sample_weight=ones,
         feature_mask=jnp.ones((f,), bool),
-        num_bins_pf=jnp.asarray(ooc.binner.num_bins_per_feature),
-        missing_bin_pf=jnp.asarray(ooc.binner.missing_bin_per_feature),
+        num_bins_per_feature=jnp.asarray(ooc.binner.num_bins_per_feature),
+        missing_bin_per_feature=jnp.asarray(
+            ooc.binner.missing_bin_per_feature),
     )
     static = dict(num_leaves=15, num_bins=32, params=SplitParams(
         min_data_in_leaf=5.0), leaf_tile=4, use_pallas=False)
     grads = [jnp.asarray(0.6 * y + 0.05 * k, jnp.float32) for k in range(2)]
-    tree, leaf = grow_tree_windowed(bins_t, grads[0], kw["sample_weight"],
-                                    **kw, **static)
+    tree, leaf = grow_tree_fast(ooc.bins_device, grads[0], ones, **kw,
+                                **static)
     jax.block_until_ready(leaf)
 
-    stats = {}
     with DispatchCounter() as d:
-        tree, leaf = grow_tree_windowed(bins_t, grads[1],
-                                        kw["sample_weight"], **kw, **static,
-                                        stats=stats)
+        tree, leaf = grow_tree_fast(ooc.bins_device, grads[1], ones, **kw,
+                                    **static)
         jax.block_until_ready(leaf)
-    assert stats["rounds"] >= 3, stats
-    d.assert_round_budget(stats["rounds"], what="windowed rounds on OOC bins")
-    assert stats["host_syncs"] == 0, stats
-    assert stats["retries"] == 0, stats
-    d.assert_no_recompile("windowed rounds on a stream-assembled matrix")
+    assert int(tree.num_leaves) == 15
+    assert d.host_syncs == 0, d.host_syncs
+    d.assert_no_recompile("rounds grower on a stream-assembled matrix")
+    want, want_leaf = grow_tree_fast(mem.bins_device, grads[1], ones, **kw,
+                                     **static)
+    for got_a, want_a in zip(jax.tree_util.tree_leaves(tree),
+                             jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got_a), np.asarray(want_a))
+    np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want_leaf))
 
 
 # ---------------------------------------------------------------------------

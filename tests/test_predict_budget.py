@@ -486,13 +486,17 @@ def test_row_sharded_predict_multiclass_bitwise():
 
 
 def test_row_sharded_predict_on_training_mesh_and_invalidates():
-    """A 2-D (feature x row) TRAINING mesh serves directly — P(data)
-    shards rows and replicates over the feature axis — and mutation
-    invalidates the mesh-resident tables with the pack itself."""
-    from lightgbm_tpu.parallel.mesh import make_mesh_2d
+    """A 2-D mesh with a second axis beside the data axis serves directly
+    — P(data) shards rows and replicates over the other axis — and
+    mutation invalidates the mesh-resident tables with the pack itself."""
+    import jax
+    from jax.sharding import Mesh
+
+    from lightgbm_tpu.parallel.mesh import DATA_AXIS
 
     bst, X, _ = _binary_booster()
-    mesh = make_mesh_2d(4, 2)
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2),
+                (DATA_AXIS, "model"))
     want = bst.predict(X, raw_score=True)
     assert np.array_equal(want, bst.predict(X, raw_score=True, mesh=mesh))
     bst.update()  # bump the pack version

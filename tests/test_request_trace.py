@@ -107,8 +107,8 @@ def test_record_span_without_identity_still_adopts_same_thread_parent():
     """The training-loop form is unchanged: on ONE thread, a record_span
     with no explicit identity nests under the open ambient span."""
     with _trc.span("boost_round", iteration=3) as sp:
-        _trc.record_span("windowed_round", 1e-4, trees=1)
-    rec = _trc.spans("windowed_round")[-1]
+        _trc.record_span("tree_growth", 1e-4, trees=1)
+    rec = _trc.spans("tree_growth")[-1]
     assert rec["trace"] == sp.ctx.trace_id
     assert rec["psid"] == sp.ctx.span_id
 
@@ -351,7 +351,7 @@ def test_launcher_aggregates_per_rank_trace_files(tmp_path):
     _trc.record_span("boost_round", 0.01, ctx=ctx, iteration=0)
     _trc.write_trace(str(tmp_path / "worker0.trace.json"))
     _trc.reset_trace()
-    _trc.record_span("windowed_round", 0.005, parent=ctx, trees=1)
+    _trc.record_span("tree_growth", 0.005, parent=ctx, trees=1)
     _trc.write_trace(str(tmp_path / "worker1.trace.json"))
 
     merged_path = aggregate_fleet_trace(str(tmp_path), 2)
@@ -361,7 +361,7 @@ def test_launcher_aggregates_per_rank_trace_files(tmp_path):
     assert srcs == {"worker0.trace.json", "worker1.trace.json"}
     # rank 1's span joined rank 0's trace across files
     sl = _trc.trace_slice(ctx.trace_id, doc["lgbmtpu"]["spans"])
-    assert {s["name"] for s in sl} == {"boost_round", "windowed_round"}
+    assert {s["name"] for s in sl} == {"boost_round", "tree_growth"}
 
     # a missing rank file is a missing rank, not a crash; none -> None
     assert aggregate_fleet_trace(str(tmp_path), 4) is not None

@@ -1,8 +1,7 @@
 """Retrace regression tests (runtime half of the jaxlint pass): the
 compile-counter in utils/sanitizer.py pins "N boosting rounds at a fixed
-(shape, dtype) config compile exactly once" — the per-round recompile class
-docs/NEXT.md suspects in the windowed admit phase becomes an executable
-assertion instead of benchmark archaeology."""
+(shape, dtype) config compile exactly once" — the per-round recompile
+class becomes an executable assertion instead of benchmark archaeology."""
 
 import numpy as np
 import jax
@@ -110,139 +109,49 @@ def test_booster_steady_state_does_not_retrace():
     c.assert_no_recompile("Booster.update steady state")
 
 
-def _windowed_inputs(n=900, f=8, seed=5):
-    from lightgbm_tpu.binning import DatasetBinner
-    from lightgbm_tpu.ops.split import SplitParams
-
-    rng = np.random.RandomState(seed)
-    X = rng.randn(n, f)
-    y = X @ rng.randn(f) + 0.2 * rng.randn(n)
-    binner = DatasetBinner.fit(X, max_bin=31)
-    bins_t = jnp.asarray(binner.transform(X).T, jnp.int16)
-    grads = [jnp.asarray(0.6 * y + 0.05 * k, jnp.float32) for k in range(3)]
-    kw = dict(
-        row_mask=jnp.ones((n,), bool),
-        sample_weight=jnp.ones((n,), jnp.float32),
-        feature_mask=jnp.ones((f,), bool),
-        num_bins_pf=jnp.asarray(binner.num_bins_per_feature),
-        missing_bin_pf=jnp.asarray(binner.missing_bin_per_feature),
-    )
-    static = dict(num_leaves=15, num_bins=32, params=SplitParams(
-        min_data_in_leaf=5.0), leaf_tile=4, use_pallas=False)
-    return bins_t, grads, jnp.ones((n,), jnp.float32), kw, static
-
-
-def test_windowed_steady_state_one_dispatch_zero_syncs_no_retrace():
-    """The round-7 fused-round contract (ISSUE acceptance): after warmup,
-    windowed rounds at fixed shape trace ZERO times and cost exactly ONE
-    device dispatch and ZERO blocking host pulls per round — pinned by
-    the DispatchCounter, not inferred from benchmarks."""
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-    from lightgbm_tpu.utils.sanitizer import DispatchCounter
-
-    bins_t, grads, hess, kw, static = _windowed_inputs()
-    # warmup: compiles _w_init, the fused round at this shape's single
-    # window-ladder rung, and _w_finalize
-    tree, leaf = grow_tree_windowed(bins_t, grads[0], hess, **kw, **static)
-    jax.block_until_ready(leaf)
-
-    stats = {}
-    with DispatchCounter() as d:
-        tree, leaf = grow_tree_windowed(bins_t, grads[1], hess, **kw,
-                                        **static, stats=stats)
-        jax.block_until_ready(leaf)
-    # steady state: 1 dispatch per round, 0 blocking syncs, 0 mispredicted
-    # windows, and the whole tree was warm-cache (zero traces/compiles)
-    assert stats["rounds"] >= 3, stats  # a 15-leaf tree takes several rounds
-    d.assert_round_budget(stats["rounds"], what="windowed steady state")
-    assert stats["dispatches"] == stats["rounds"], stats
-    assert stats["host_syncs"] == 0, stats
-    assert stats["retries"] == 0, stats
-    # info reads resolve one round behind and never block the device queue
-    assert stats["async_resolves"] <= stats["rounds"], stats
-    d.assert_no_recompile("3+ windowed rounds at fixed shape")
-
-
-def test_windowed_budget_gate_enforces(monkeypatch):
-    """LGBMTPU_DISPATCH_BUDGET=1 arms the in-driver gate; a blocking pull
-    smuggled into the loop breaks the budget and raises."""
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-    from lightgbm_tpu.utils import sanitizer as san
-
-    bins_t, grads, hess, kw, static = _windowed_inputs(seed=6)
-    monkeypatch.setenv("LGBMTPU_DISPATCH_BUDGET", "1")
-    # clean run passes the gate
-    tree, leaf = grow_tree_windowed(bins_t, grads[0], hess, **kw, **static)
-    assert int(tree.num_leaves) > 1
-
-    # a sync_pull inside the loop (e.g. a re-introduced per-round host
-    # read) must trip the gate
-    orig = san.async_pull_result
-
-    def leaky(x):
-        san.sync_pull(x)  # the regression class: a blocking pull per round
-        return orig(x)
-
-    monkeypatch.setattr(san, "async_pull_result", leaky)
-    with pytest.raises(san.BudgetError):
-        grow_tree_windowed(bins_t, grads[1], hess, **kw, **static)
-
-
-def test_windowed_megakernel_one_dispatch_zero_syncs_no_retrace(monkeypatch):
-    """ISSUE 11 acceptance: the MEGAKERNEL round (ops/round_pallas.py,
-    interpret mode off-chip) holds the same steady-state budget as the
-    three-pass round — 1 dispatch, 0 blocking syncs, 0 retraces per
-    round, telemetry + span tracing default-ON.  The kernel rides INSIDE
-    the donated round dispatch; window sizes are data-dependent loop
-    bounds in-kernel, so the W ladder cannot force retraces either."""
+def test_rounds_grower_steady_state_zero_syncs_no_retrace_telemetry_on():
+    """The rounds grower's steady state with telemetry and span tracing
+    default-ON: after the warm-up tree, a further tree at fixed shape
+    traces and compiles nothing and leaves the accounted ledger empty —
+    the grower pulls nothing to the host, so nothing the obs layer hooks
+    can have added a sync either."""
     from lightgbm_tpu.obs import metrics as obs_metrics
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
-    from lightgbm_tpu.utils.sanitizer import DispatchCounter
-
-    assert obs_metrics.enabled()
-    monkeypatch.setenv("LGBMTPU_MEGAKERNEL", "interpret")
-    bins_t, grads, hess, kw, static = _windowed_inputs(seed=8)
-    tree, leaf = grow_tree_windowed(bins_t, grads[0], hess, **kw, **static)
-    jax.block_until_ready(leaf)
-    assert int(tree.num_leaves) > 1
-
-    stats = {}
-    with DispatchCounter() as d:
-        tree, leaf = grow_tree_windowed(bins_t, grads[1], hess, **kw,
-                                        **static, stats=stats)
-        jax.block_until_ready(leaf)
-    assert stats["rounds"] >= 3, stats
-    d.assert_round_budget(stats["rounds"], what="megakernel windowed rounds")
-    assert stats["dispatches"] == stats["rounds"], stats
-    assert stats["host_syncs"] == 0, stats
-    assert stats["retries"] == 0, stats
-    d.assert_no_recompile("3+ megakernel windowed rounds at fixed shape")
-
-
-def test_sharded_windowed_one_dispatch_zero_syncs_per_rank_telemetry_on():
-    """ISSUE 9 acceptance: the SHARDED fused windowed round (8-device
-    loopback mesh, in-dispatch psum merge) keeps the 1-dispatch/0-sync/
-    0-retrace steady-state budget PER RANK — single-controller, so the
-    host's one dispatch IS every rank's dispatch — with telemetry and
-    span tracing default-ON, pinned by the same DispatchCounter the
-    single-device round uses."""
-    from lightgbm_tpu.obs import metrics as obs_metrics
-    from lightgbm_tpu.obs import trace as obs_trace
-    from lightgbm_tpu.parallel.data_parallel import (
-        ShardedData, grow_tree_windowed_data_parallel)
-    from lightgbm_tpu.parallel.mesh import make_mesh
     from lightgbm_tpu.utils.sanitizer import DispatchCounter
 
     assert obs_metrics.enabled()  # telemetry default-on: the pin's point
+    bins, grads, hess, kw, static = _grower_inputs(n=900, seed=5)
+    tree, leaf = grow_tree_fast(bins, grads[0], hess, **kw, **static)
+    jax.block_until_ready(leaf)
+    assert int(tree.num_leaves) > 1
+
+    with DispatchCounter() as d:
+        tree, leaf = grow_tree_fast(bins, grads[1], hess, **kw, **static)
+        jax.block_until_ready(leaf)
+    assert int(tree.num_leaves) == 15
+    assert d.host_syncs == 0 and d.async_resolves == 0, (
+        d.host_syncs, d.async_resolves)
+    d.assert_no_recompile("a second rounds-grower tree at fixed shape")
+
+
+def test_sharded_rounds_grower_zero_syncs_no_retrace_telemetry_on():
+    """The data-parallel rounds grower (8-device loopback mesh, the psum
+    merge inside the grower's program) keeps the same steady state —
+    single-controller, so the host's dispatches ARE every rank's — with
+    telemetry default-ON: the second tree compiles nothing and the
+    accounted ledger stays empty."""
+    from lightgbm_tpu.obs import metrics as obs_metrics
+    from lightgbm_tpu.parallel.data_parallel import (
+        ShardedData, grow_tree_fast_data_parallel)
+    from lightgbm_tpu.parallel.mesh import make_mesh
+    from lightgbm_tpu.utils.sanitizer import DispatchCounter
+
+    assert obs_metrics.enabled()
     rng = np.random.RandomState(9)
     n, f = 1024, 8
     X = rng.randn(n, f)
     y = X @ rng.randn(f) + 0.2 * rng.randn(n)
-    from lightgbm_tpu.binning import DatasetBinner
-
     binner = DatasetBinner.fit(X, max_bin=31)
-    mesh = make_mesh()
-    sd = ShardedData(mesh, binner.transform(X),
+    sd = ShardedData(make_mesh(), binner.transform(X),
                      binner.num_bins_per_feature,
                      binner.missing_bin_per_feature)
     grads = [sd.pad_rows((0.6 * y + 0.05 * k).astype(np.float32))
@@ -252,62 +161,17 @@ def test_sharded_windowed_one_dispatch_zero_syncs_per_rank_telemetry_on():
     kw = dict(num_leaves=15, num_bins=32,
               params=SplitParams(min_data_in_leaf=5.0), leaf_tile=4,
               use_pallas=False)
-    # warmup: compiles sharded init, the fused round at this shard size's
-    # ladder rung(s), and finalize
-    tree, leaf = grow_tree_windowed_data_parallel(
+    tree, leaf = grow_tree_fast_data_parallel(
         sd, grads[0], hess, sd.row_valid, sw, jnp.ones((f,), bool), **kw)
     jax.block_until_ready(leaf)
     assert int(tree.num_leaves) > 1
 
-    spans_before = len(obs_trace.spans("windowed_round"))
-    stats = {}
     with DispatchCounter() as d:
-        tree, leaf = grow_tree_windowed_data_parallel(
+        tree, leaf = grow_tree_fast_data_parallel(
             sd, grads[1], hess, sd.row_valid, sw, jnp.ones((f,), bool),
-            stats=stats, **kw)
+            **kw)
         jax.block_until_ready(leaf)
-    assert stats["rounds"] >= 3, stats
-    d.assert_round_budget(stats["rounds"], what="sharded windowed rounds")
-    assert stats["host_syncs"] == 0 and stats["retries"] == 0, stats
-    assert stats["async_resolves"] <= stats["rounds"], stats
-    d.assert_no_recompile("sharded windowed steady state")
-    # the obs/span hooks rode the SAME accounted resolves: every round of
-    # the second tree left a windowed_round span, none added a sync
-    assert (len(obs_trace.spans("windowed_round")) - spans_before
-            == stats["rounds"])
-
-
-def test_fleet_steady_state_one_dispatch_zero_syncs_no_retrace():
-    """ISSUE 17 acceptance: the vmapped fleet round holds the solo
-    steady-state budget at ANY B — exactly ONE donated dispatch and ZERO
-    blocking host pulls per ladder round, ZERO retries, ZERO compiles
-    past warmup — with telemetry + span tracing ON.  Read from the
-    fleet_round event ledger, whose dispatches/host_syncs fields are the
-    driver's own DispatchCounter totals (ops/treegrow_windowed.py
-    _run_fused_rounds), so this is the counter pin, not an inference."""
-    from lightgbm_tpu.obs import metrics as _obs
-
-    rng = np.random.RandomState(17)
-    n, f, R = 300, 5, 5
-    X = rng.rand(n, f)
-    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
-              "min_data_in_leaf": 5, "seed": 3}
-    for B in (2, 16):
-        labels = (rng.rand(B, n) > 0.5).astype(np.float64)
-        ds = lgb.Dataset(X, label=labels[0], params={"verbosity": -1})
-        ev0 = len(_obs.events("fleet_round"))
-        fb = lgb.train_fleet(dict(params), ds, labels, num_boost_round=R)
-        events = _obs.events("fleet_round")[ev0:]
-        assert len(events) == R, "one fleet_round event per iteration"
-        assert all(e["models"] == B for e in events)
-        # warmup may compile (_fleet_init / the round at this rung /
-        # _fleet_finalize + the per-fleet prep/update jits); iterations
-        # past it must be fully warm
-        warm = [e for e in events if e["iteration"] > 2]
-        assert len(warm) == R - 2
-        for e in warm:
-            assert e["dispatches"] == e["rounds"], e
-            assert e["host_syncs"] == 0, e
-            assert e["retries"] == 0, e
-            assert e["compiles"] == 0, e
-        assert int(fb.booster(B - 1).num_trees()) == R
+    assert int(tree.num_leaves) == 15
+    assert d.host_syncs == 0 and d.async_resolves == 0, (
+        d.host_syncs, d.async_resolves)
+    d.assert_no_recompile("sharded rounds-grower steady state")

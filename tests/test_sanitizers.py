@@ -2,8 +2,8 @@
 ASan/UBSan build; the jit-purity equivalent here is training under
 jax.enable_checks (internal invariant checking) and jax.debug_nans
 (NaN propagation detection) — across every grower the engine can select:
-strict, rounds, int8-quantized rounds, windowed, and a loopback
-data-parallel round.  The static half of the sanitizer story is jaxlint
+strict, rounds, int8-quantized rounds, the rounds grower at its own
+call, and a loopback data-parallel round.  The static half of the sanitizer story is jaxlint
 (lightgbm_tpu/analysis, gated by test_jaxlint_gate.py); the retrace half
 is utils/sanitizer.py (gated by test_retrace.py)."""
 
@@ -53,29 +53,30 @@ def test_train_quantized_under_checks_and_debug_nans():
                       "use_quantized_grad": True})
 
 
-def _windowed_inputs(n=1500, f=10, seed=0):
+def _grower_inputs(n=1500, f=10, seed=0):
     from lightgbm_tpu.binning import DatasetBinner
 
     rng = np.random.RandomState(seed)
     X = rng.randn(n, f)
     y = X @ rng.randn(f)
     binner = DatasetBinner.fit(X, max_bin=63)
-    bins_t = jnp.asarray(binner.transform(X).T, jnp.int16)
-    return binner, bins_t, jnp.asarray(0.6 * y, jnp.float32)
+    bins = jnp.asarray(binner.transform(X), jnp.int16)
+    return binner, bins, jnp.asarray(0.6 * y, jnp.float32)
 
 
-def test_windowed_grower_under_enable_checks():
-    """The windowed grower donates its hist state and drives growth from a
-    host loop — the donation/threading invariants are exactly what
+def test_rounds_grower_call_under_enable_checks():
+    """The rounds grower at its own call (64 bins: the einsum histogram
+    route, four leaves a pass) threads a per-leaf histogram state through
+    a device loop — the carry/threading invariants are exactly what
     enable_checks' internal assertions exercise."""
     from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.ops.treegrow_windowed import grow_tree_windowed
+    from lightgbm_tpu.ops.treegrow_fast import grow_tree_fast
 
-    binner, bins_t, grad = _windowed_inputs()
-    n, f = bins_t.shape[1], bins_t.shape[0]
+    binner, bins, grad = _grower_inputs()
+    n, f = bins.shape
     with jax.enable_checks(True):
-        tree, leaf = grow_tree_windowed(
-            bins_t, grad, jnp.ones((n,), jnp.float32),
+        tree, leaf = grow_tree_fast(
+            bins, grad, jnp.ones((n,), jnp.float32),
             jnp.ones((n,), bool), jnp.ones((n,), jnp.float32),
             jnp.ones((f,), bool),
             jnp.asarray(binner.num_bins_per_feature),
@@ -84,9 +85,9 @@ def test_windowed_grower_under_enable_checks():
             params=SplitParams(min_data_in_leaf=5.0),
             leaf_tile=4, use_pallas=False)
     nl = int(tree.num_leaves)
-    assert nl > 1
+    assert nl == 15
     assert np.isfinite(np.asarray(tree.leaf_value[:nl])).all()
-    assert not np.isnan(np.asarray(leaf)).any()
+    assert int(np.asarray(leaf).max()) == nl - 1
 
 
 def test_data_parallel_round_under_enable_checks():

@@ -6,16 +6,15 @@ artifact metric (bench.py records trace_eqns per run); this test is the
 tier-1 half — a generous ceiling that catches structural trace bloat
 (an accidentally unrolled loop, a per-leaf-tile op explosion) at PR time
 without being brittle to jax version drift.  Measured round-7 baselines:
-grow_tree_fast tile8 ~1.74k eqns, tile16 ~2.23k; fused windowed round
-tile8 ~2.13k (benchmarks/probe_trace_ops.py)."""
+grow_tree_fast tile8 ~1.74k eqns, tile16 ~2.23k
+(benchmarks/probe_trace_ops.py)."""
 
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from benchmarks.probe_trace_ops import (count_eqns, fast_grower_eqns,  # noqa: E402
-                                        windowed_round_eqns)
+from benchmarks.probe_trace_ops import count_eqns, fast_grower_eqns  # noqa: E402
 
 
 def test_fast_grower_trace_budget():
@@ -23,8 +22,14 @@ def test_fast_grower_trace_budget():
     assert fast_grower_eqns(leaf_tile=16) < 3000
 
 
-def test_windowed_fused_round_trace_budget():
-    assert windowed_round_eqns(leaf_tile=8) < 2800
+def test_fast_grower_round_body_is_a_device_loop():
+    """The round body is traced once: the trace may not grow with the
+    leaf budget, the rows or the bins (an unrolled round, a per-leaf or a
+    per-bin Python loop would show here long before the ceiling above)."""
+    base = fast_grower_eqns(leaf_tile=8)
+    assert fast_grower_eqns(leaf_tile=8, num_leaves=255) == base
+    assert fast_grower_eqns(leaf_tile=8, n=16384) == base
+    assert fast_grower_eqns(leaf_tile=8, num_bins=256) == base
 
 
 def test_count_eqns_descends_subjaxprs():
