@@ -724,25 +724,37 @@ class Dataset:
         return np.concatenate([[0], np.cumsum(self.group)]).astype(np.int64)
 
     def bins_device_t(self) -> jnp.ndarray:
-        """(F, N) feature-major shadow of bins_device — the fast grower's
-        partition reads become contiguous row slices (docs/PERF_NOTES.md).
-        Built lazily: only TPU training paths request it."""
+        """(F, ceil(N / C), C / 128, 128) int16 feature-major shadow of
+        bins_device, C = ops/hist_pallas.ROW_TILE, the rows padded up to
+        whole row tiles with bin 0: ``shadow.reshape(F, -1)[:, :N]`` is
+        ``bins.T``.  The feature axis and the row tiles' axis lead and are
+        no axes of the device's (8, 128) tiles, so a feature is a run of
+        whole tiles, nothing is padded but the last row tile, and the
+        rounds grower's partition reads a split's column and nothing else
+        (PERF.md section 6, PR 31).  Built lazily: only TPU training paths
+        request it."""
         if getattr(self, "_bins_device_t", None) is None:
+            from .ops.hist_pallas import ROW_TILE
+
+            src = self.bins if self.bins is not None else self.bins_device
+            if src is None:
+                raise LightGBMError(
+                    "bins_device_t needs a device-resident matrix, but "
+                    "this out_of_core dataset exceeds max_rows_in_hbm "
+                    "(spill regime) and only streams bins in chunks — "
+                    "raise max_rows_in_hbm or drop out_of_core")
+            n, f = src.shape
+            tiles = -(-n // ROW_TILE)
             if self.bins is None:
-                if self.bins_device is None:
-                    raise LightGBMError(
-                        "bins_device_t needs a device-resident matrix, but "
-                        "this out_of_core dataset exceeds max_rows_in_hbm "
-                        "(spill regime) and only streams bins in chunks — "
-                        "raise max_rows_in_hbm or drop out_of_core")
                 # out-of-core resident: the host matrix was never
                 # materialized — transpose the assembled device matrix
-                self._bins_device_t = jnp.asarray(
-                    jnp.transpose(self.bins_device))
+                shadow = jnp.pad(jnp.transpose(src),
+                                 ((0, 0), (0, tiles * ROW_TILE - n)))
             else:
-                self._bins_device_t = jnp.asarray(
-                    np.ascontiguousarray(self.bins.T), jnp.int16
-                )
+                shadow = np.zeros((f, tiles * ROW_TILE), src.dtype)
+                shadow[:, :n] = src.T
+            self._bins_device_t = jnp.asarray(
+                shadow.reshape(f, tiles, ROW_TILE // 128, 128), jnp.int16)
         return self._bins_device_t
 
     def num_data(self) -> int:

@@ -329,13 +329,18 @@ def _row_tile(n: int, row_tile: int) -> int:
 
 def pass_counts(mask: jnp.ndarray, row_tile: int = ROW_TILE) -> jnp.ndarray:
     """(row_tiles,) int32: the rows of each row tile of the kernel that
-    ``mask`` (N,) keeps.  The kernel's cost follows these counts.  A count
-    may be too high (rows the mask keeps but no slot of the pass takes: the
-    kernel multiplies empty places), never too low."""
+    ``mask`` keeps.  ``mask`` is (N,), or the rows laid out a row tile an
+    index of the leading axis, the rows past N false (the rounds grower's
+    leaf ids lie so: basic.Dataset.bins_device_t).  The kernel's cost
+    follows these counts.  A count may be too high (rows the mask keeps but
+    no slot of the pass takes: the kernel multiplies empty places), never
+    too low."""
+    keep = mask.astype(jnp.int32)
+    if mask.ndim > 1:
+        return jnp.sum(keep.reshape(mask.shape[0], -1), axis=1)
     n = mask.shape[0]
     t = _row_tile(n, row_tile)
     full = n // t
-    keep = mask.astype(jnp.int32)
     counts = jnp.sum(keep[:full * t].reshape(full, t), axis=1)
     if full * t < n:  # the ragged last tile
         counts = jnp.concatenate([counts, jnp.sum(keep[full * t:])[None]])

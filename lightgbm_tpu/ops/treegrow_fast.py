@@ -38,6 +38,7 @@ on the strict grower (their cost is comms-, not pass-, shaped).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -87,7 +88,8 @@ def predict_leaf_arrays(
 
 
 class FastState(NamedTuple):
-    leaf_id: jnp.ndarray  # (N,) i32
+    leaf_id: jnp.ndarray  # (N,) i32; with a shadow (bins_t) the rows ride
+    # its tiles, (N/C, C/128, 128), padded rows and all, until the tree returns
     hist: jnp.ndarray  # (L, 3, F, B) f32 — channel-first: the minor (F, B)
     # tile pair pads ~nothing on TPU, vs 42.7x for a trailing dim of 3
     best: BestSplit  # vectorized over L (gain=KMIN for unevaluated leaves)
@@ -173,6 +175,48 @@ def _batched_best(
     )
 
 
+def partition_rows(
+    columns: jnp.ndarray,  # the bins, a feature an index of ``axis``
+    axis: int,
+    lid: jnp.ndarray,  # i32 leaf of every row, shaped as a column is
+    s: BestSplit,  # (L,) the split each leaf would take
+    accept: jnp.ndarray,  # (L,) bool: the leaves that split this round
+    inv_rank: jnp.ndarray,  # (L,) i32: the leaf at each admission rank
+    right_of: jnp.ndarray,  # (L,) i32: the id a leaf's right child gets
+    missing_bin_per_feature: jnp.ndarray,
+    leaf_tile: int,
+    categorical: bool,
+) -> jnp.ndarray:
+    """The rows' leaves after a round's <= leaf_tile accepted splits: the
+    left child keeps its parent's id.
+
+    A static loop over the slots with dynamic-slice COLUMN reads: per-row
+    take_along_axis gathers lower catastrophically on TPU (*log*, 1M rows:
+    ~30 ms a round).  Over the feature-major shadow (axis 0, basic.Dataset.
+    bins_device_t) a column is a run of whole (8, 128) tiles shaped as the
+    ids are, and the slots' compares and selects compile to ONE fusion that
+    reads the round's columns and the ids once: 0.30 ms a round at 10.5M
+    rows x 8 slots on a v5e, the HBM's rate, and 0.09 at 400k x 10 (PERF.md
+    section 6, PR 31; tests/test_hist_pallas_mosaic.py holds the compile to
+    that).  The (F, N) shadow with (N,) ids before it took a fusion a slot
+    on one sublane in eight: 4.8 and 0.20 ms."""
+    leaf_id = lid
+    for r in range(leaf_tile):
+        leaf_r = inv_rank[r]
+        live = accept[leaf_r]  # rank r admitted?
+        feat_r = s.feature[leaf_r]
+        fcol = jax.lax.dynamic_index_in_dim(
+            columns, feat_r, axis=axis, keepdims=False).astype(jnp.int32)
+        miss_r = fcol == missing_bin_per_feature[feat_r]
+        gl = jnp.where(miss_r, s.default_left[leaf_r],
+                       fcol <= s.threshold_bin[leaf_r])
+        if categorical:
+            gl = jnp.where(s.is_cat[leaf_r], s.cat_mask[leaf_r][fcol], gl)
+        sel = live & (lid == leaf_r)
+        leaf_id = jnp.where(sel & ~gl, right_of[leaf_r], leaf_id)
+    return leaf_id
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -200,9 +244,10 @@ def _grow_fast_impl(
     efb_bins: jnp.ndarray = None,  # (N, F_b) bundled bin matrix (io/efb.py)
     efb_gather: jnp.ndarray = None,  # (F, B) int32 into flat (F_b*B)+zero-pad
     efb_default: jnp.ndarray = None,  # (F, B) bool default slots
-    bins_t: jnp.ndarray = None,  # (F, N) feature-major copy: partition's
-    # per-feature column reads become contiguous row slices (measured:
-    # 8 dynamic column slices of (N, F) cost ~1.1 ms/round on v5e)
+    bins_t: jnp.ndarray = None,  # (F, N/C, C/128, 128) feature-major
+    # shadow (basic.Dataset.bins_device_t): a feature is a run of whole
+    # tiles, so the partition reads a split's columns and nothing else, and
+    # the per-row state of the rounds rides the same (N/C, C/128, 128) tiles
     feature_contri: jnp.ndarray = None,  # (F,) split-gain multipliers
     forced_leaf: jnp.ndarray = None,  # (K,) i32 — forced-split schedule
     forced_feature: jnp.ndarray = None,  # (K,) i32   (reference: ForceSplits
@@ -237,6 +282,17 @@ def _grow_fast_impl(
     n, f = bins.shape
     # bins stay in their storage dtype (int16 on device — half the HBM of
     # int32 at Epsilon scale); kernels and column slices upcast per tile
+    # The rounds' per-row state (leaf ids, pass slots, the bag) lies as the
+    # shadow's rows do, the padded rows out of every bag; to_rows is (N,)
+    # again, for whoever needs a row a row
+    mask_t = row_mask
+    if bins_t is not None:
+        tiles = bins_t.shape[1:]
+        mask_t = jnp.pad(row_mask, (0, math.prod(tiles) - n)).reshape(tiles)
+
+    def to_rows(x):
+        return x if bins_t is None else x.reshape(-1)[:n]
+
     with phase_scope("grow.root"):
         grad = grad.astype(jnp.float32) * sample_weight
         hess = hess.astype(jnp.float32) * sample_weight
@@ -296,14 +352,17 @@ def _grow_fast_impl(
                          payload_base(grad, hess, row_mask, hist_precision))
 
     def multi_hist(leaf_slot, tile):
-        """(N,)-slot -> (tile, 3, F, B) f32: per-slot histograms, one pass;
-        and the sub-blocks the Pallas kernel multiplied for it."""
-        keep = row_mask & (leaf_slot >= 0)
+        """A slot a row (as the leaf ids lie) -> (tile, 3, F, B) f32:
+        per-slot histograms, one pass; and the sub-blocks the Pallas kernel
+        multiplied for it."""
+        keep = mask_t & (leaf_slot >= 0)
         counts, blocks = None, jnp.asarray(0, jnp.int32)
-        if hist_base is not None:
-            with phase_scope("grow.slots"):  # once a pass, for every chunk
+        with phase_scope("grow.slots"):
+            if hist_base is not None:  # once a pass, for every chunk
                 counts = pass_counts(keep)
                 blocks = blocks_multiplied(counts, hist_bins.shape, num_bins)
+            # the histogram routes take a row a row
+            keep, leaf_slot = to_rows(keep), to_rows(leaf_slot)
         if use_pallas and quantize_bins:
             if num_bins <= 64:
                 # same measured strategy selection as the float path: XLA's
@@ -351,7 +410,7 @@ def _grow_fast_impl(
     # ---- root ----
     with phase_scope("grow.root"):
         hist0, blocks0 = multi_hist(
-            jnp.where(row_mask, 0, -1).astype(jnp.int32), 1)
+            jnp.where(mask_t, 0, -1).astype(jnp.int32), 1)
         hist0 = hist0[0]
         sum0 = jnp.sum(hist0[:, 0, :], axis=1)  # totals from feature 0: (3,)
         g0, h0, c0 = sum0[0], sum0[1], sum0[2]
@@ -423,7 +482,7 @@ def _grow_fast_impl(
 
     with phase_scope("grow.root"):  # the state the loop carries
         state = FastState(
-            leaf_id=jnp.zeros((n,), jnp.int32),
+            leaf_id=jnp.zeros(mask_t.shape, jnp.int32),
             hist=jnp.zeros((L, 3, f, num_bins), jnp.float32).at[0].set(hist0),
             best=best0,
             leaf_sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
@@ -523,30 +582,11 @@ def _grow_fast_impl(
         right_of = state.num_leaves_cur + acc_rank  # right-child leaf id
 
         # ---------- row partition: all accepted splits at once ----------
-        # Loop over the <= leaf_tile accepted slots with dynamic-slice COLUMN
-        # reads — per-row take_along_axis gathers lower catastrophically on
-        # TPU (measured ~30 ms/round), while 16 strided column slices +
-        # elementwise selects cost ~0.2 ms.
         lid = state.leaf_id
-        leaf_id = lid
-        for r in range(leaf_tile):
-            leaf_r = inv_rank[r]
-            live = accept[leaf_r]  # rank r admitted?
-            feat_r = s.feature[leaf_r]
-            if bins_t is not None:
-                fcol = jax.lax.dynamic_index_in_dim(
-                    bins_t, feat_r, axis=0, keepdims=False
-                ).astype(jnp.int32)
-            else:
-                fcol = jax.lax.dynamic_index_in_dim(
-                    bins, feat_r, axis=1, keepdims=False
-                ).astype(jnp.int32)
-            miss_r = fcol == missing_bin_per_feature[feat_r]
-            gl = jnp.where(miss_r, s.default_left[leaf_r], fcol <= s.threshold_bin[leaf_r])
-            if categorical_mask is not None:
-                gl = jnp.where(s.is_cat[leaf_r], s.cat_mask[leaf_r][fcol], gl)
-            sel = live & (lid == leaf_r)
-            leaf_id = jnp.where(sel & ~gl, right_of[leaf_r], leaf_id)
+        leaf_id = partition_rows(
+            *((bins, 1) if bins_t is None else (bins_t, 0)),
+            lid, s, accept, inv_rank, right_of, missing_bin_per_feature,
+            leaf_tile, categorical_mask is not None)
 
         # ---------- bookkeeping for accepted splits ----------
         idx = jnp.arange(L, dtype=jnp.int32)
@@ -678,18 +718,20 @@ def _grow_fast_impl(
             # mirror of the strict grower's per-split charge (reference:
             # CostEfficientGradientBoosting::UpdateUsedFeature)
             lazy_used = state.lazy_used
+            lid_rows, leaf_id_rows = to_rows(lid), to_rows(leaf_id)
             for r in range(leaf_tile):
                 leaf_r = inv_rank[r]
                 live_r = accept[leaf_r]
                 feat_r = s.feature[leaf_r]
-                sel = live_r & (lid == leaf_r) & row_mask
+                sel = live_r & (lid_rows == leaf_r) & row_mask
                 lazy_used = lazy_used.at[:, feat_r].set(
                     lazy_used[:, feat_r] | sel)
             # one pass counts all LEFT children (they keep the parent's
             # slot); the right child is the parent remainder with the
             # split feature zeroed on both sides
             oh_left = jnp.stack(
-                [(accept[inv_rank[r]] & (leaf_id == inv_rank[r]) & row_mask)
+                [(accept[inv_rank[r]] & (leaf_id_rows == inv_rank[r])
+                  & row_mask)
                  for r in range(leaf_tile)], axis=1).astype(jnp.float32)
             counts_left = jnp.einsum(
                 "nt,nf->tf", oh_left, (~lazy_used).astype(jnp.float32))
@@ -784,7 +826,7 @@ def _grow_fast_impl(
         # table gathers at (N,) lower poorly on TPU (see partition above)
         with phase_scope("grow.slots"):
             lid = state.leaf_id
-            leaf_slot = jnp.full((n,), -1, jnp.int32)
+            leaf_slot = jnp.full(lid.shape, -1, jnp.int32)
             for r in range(leaf_tile):
                 has_r = state.small_slot == r  # (L,)
                 leaf_r = jnp.argmax(has_r).astype(jnp.int32)
@@ -943,14 +985,15 @@ def _grow_fast_impl(
         state = state._replace(progress=jnp.asarray(True))
 
     state = jax.lax.while_loop(cond, body, state)
+    leaf_id = to_rows(state.leaf_id)
 
     with phase_scope("grow.leaf_values"):
         if quant_renew and quantize_bins and not use_intermediate:
             # recompute leaf outputs from the TRUE f32 gradients (reference:
             # GBDT::Train -> RenewIntGradTreeOutput after quantized growth)
             mrow = row_mask.astype(jnp.float32)
-            Gt = psum(jnp.zeros((L,), jnp.float32).at[state.leaf_id].add(grad_true * mrow))
-            Ht = psum(jnp.zeros((L,), jnp.float32).at[state.leaf_id].add(hess_true * mrow))
+            Gt = psum(jnp.zeros((L,), jnp.float32).at[leaf_id].add(grad_true * mrow))
+            Ht = psum(jnp.zeros((L,), jnp.float32).at[leaf_id].add(hess_true * mrow))
             leaf_value = leaf_output(Gt, Ht, params)
             if monotone_constraints is not None:
                 leaf_value = jnp.clip(leaf_value, state.leaf_out_lo, state.leaf_out_hi)
@@ -981,8 +1024,8 @@ def _grow_fast_impl(
     if use_lazy:
         # hand the cross-tree charge state back (reference: the
         # feature_used_in_data bitset persists across trees)
-        return tree, state.leaf_id, state.lazy_used
-    return tree, state.leaf_id
+        return tree, leaf_id, state.lazy_used
+    return tree, leaf_id
 
 
 def grow_tree_fast(*args, use_pallas: bool = True, **kwargs):

@@ -60,3 +60,42 @@ def test_mosaic_takes_the_kernel(one_chip, case):
             s((n, f), jnp.int16), s((8, n), dtype), s((1, n), jnp.int32),
             s((1,), jnp.int32), s((tiles,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_partition_over_the_shadow_is_one_pass(one_chip):
+    """The rounds grower's partition at Higgs' shape, over the feature-major
+    shadow as ``Dataset.bins_device_t`` lays it out: one fusion reads the
+    shadow, nothing is a row of one sublane, and XLA counts the round's
+    eight columns and the ids once each way, with half as much to spare
+    (PERF.md section 6, PR 31: eight fusions and 588 MB before)."""
+    import re
+
+    from lightgbm_tpu.ops.treegrow import _empty_best
+    from lightgbm_tpu.ops.treegrow_fast import partition_rows
+
+    n, f, slots, leaves = 10_500_000, 28, 8, 255
+    tile = (-(-n // hp.ROW_TILE), hp.ROW_TILE // 128, 128)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    best = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: _empty_best(leaves, 255)))
+
+    def partition(bins_t, lid, best, accept, inv_rank, right_of, missing):
+        return partition_rows(bins_t, 0, lid, best, accept, inv_rank,
+                              right_of, missing, slots, False)
+
+    compiled = jax.jit(partition).lower(
+        s((f, *tile), jnp.int16), s(tile, jnp.int32), best,
+        s((leaves,), jnp.bool_), s((leaves,), jnp.int32),
+        s((leaves,), jnp.int32), s((f,), jnp.int32)).compile()
+    text = compiled.as_text()
+    readers = re.findall(r"= \S+ ([\w\-]+)\([^)]*%bins_t", text)
+    assert readers == ["fusion"], readers
+    assert not re.search(r"\[(1,)?10500\d\d\d\]", text)  # all rows in a row
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    rows = tile[0] * hp.ROW_TILE
+    assert cost["bytes accessed"] <= 1.5 * (slots * 2 * rows + 8 * rows)
