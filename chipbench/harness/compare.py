@@ -9,7 +9,9 @@ reference's side comes from ``reference/<name>.py``.
 Numbers compared (each a gap of the program's reading from the reference's,
 as a share of the reference's, worst tree first):
 
-``loss_gap``      binary log loss of the training rows after each tree
+``loss_gap``      the objective's own loss on the training rows after each
+                  tree: the function the configuration names under ``loss``
+                  (``LOSSES``), binary log loss where it names none
 ``update1_gap``   norm of the first tree's change of the score (what the
                   first boosting step hands on: the gradient as applied)
 ``updateK_gap``   norm of the score's change after all K trees
@@ -34,11 +36,54 @@ from __future__ import annotations
 import numpy as np
 
 
-def log_loss(score: np.ndarray, label: np.ndarray) -> float:
+def _mean(x: np.ndarray, data: dict) -> float:
+    """Over the rows, by their weights where the data holds any."""
+    return float(np.average(x, weights=data.get("weight")))
+
+
+def binary_logloss(score, data: dict) -> float:
     s = np.asarray(score, np.float64)
-    y = np.asarray(label, np.float64)
+    y = np.asarray(data["label"], np.float64)
     # log(1 + exp(s)) - y s, stable on both sides
-    return float(np.mean(np.logaddexp(0.0, s) - y * s))
+    return _mean(np.logaddexp(0.0, s) - y * s, data)
+
+
+def l2(score, data: dict) -> float:
+    d = np.asarray(score, np.float64) - np.asarray(data["label"], np.float64)
+    return _mean(d * d, data)
+
+
+def ndcg(score, data: dict, at: int = 10) -> float:
+    """One minus the mean over queries of NDCG@``at``, as LightGBM's metric
+    counts it: gain ``2^label - 1``, discount ``1 / log2(rank + 2)``, ties in
+    the score in row order, a query with no relevant row counted as 1."""
+    group = np.asarray(data["group"], np.int64)
+    gain = np.exp2(np.asarray(data["label"], np.float64)) - 1.0
+    query = np.repeat(np.arange(len(group)), group)
+    rank = np.arange(len(query)) - np.repeat(np.cumsum(group) - group, group)
+    top = rank < at
+    discount = 1.0 / np.log2(rank[top] + 2.0)
+
+    def dcg(key):
+        # lexsort is stable: rows of a query with equal keys keep their order
+        order = np.lexsort((-np.asarray(key, np.float64), query))
+        return np.bincount(query[top], gain[order][top] * discount,
+                           minlength=len(group))
+
+    got, best = dcg(score), dcg(gain)
+    some = best > 0
+    return 1.0 - float(np.mean(np.where(some, got / np.where(some, best, 1.0),
+                                        1.0)))
+
+
+LOSSES = {"binary_logloss": binary_logloss, "l2": l2, "ndcg": ndcg}
+
+
+def loss_named(config: dict) -> dict:
+    """The keywords ``numbers`` takes for a configuration's ``loss`` (default
+    ``binary_logloss``) and, where it states one, ``loss_at`` (NDCG's cut)."""
+    at = {"at": int(config["loss_at"])} if "loss_at" in config else {}
+    return dict(loss=config.get("loss", "binary_logloss"), **at)
 
 
 def _norm(x) -> float:
@@ -49,17 +94,23 @@ def _rel(a: float, b: float) -> float:
     return abs(a - b) / max(abs(b), 1e-300)
 
 
-def numbers(prog: dict, ref: dict, label: np.ndarray) -> dict:
+def numbers(prog: dict, ref: dict, data: dict, loss: str = "binary_logloss",
+            **loss_args) -> dict:
     """The compared numbers, unrounded.  Uses as many trees as the reference
-    followed."""
+    followed.  ``data`` is what the generator drew: the label, and the
+    weights and query sizes where there are any, for the loss named
+    (``loss_args`` are that function's own, NDCG's ``at``)."""
+    if loss not in LOSSES:
+        raise KeyError(f"no loss {loss!r} (has {sorted(LOSSES)})")
+    loss_of = LOSSES[loss]
     k = len(ref["trees"])
-    n = len(label)
+    n = len(data["label"])
     if len(prog["trees"]) < k or len(prog["scores"]) < k + 1:
         raise ValueError("the program's side holds fewer trees than the "
                          "reference followed")
-    loss = max(_rel(log_loss(prog["scores"][i], label),
-                    log_loss(ref["scores"][i], label))
-               for i in range(1, k + 1))
+    loss_gap = max(_rel(loss_of(prog["scores"][i], data, **loss_args),
+                        loss_of(ref["scores"][i], data, **loss_args))
+                   for i in range(1, k + 1))
 
     def update(side, i):
         return _norm(np.asarray(side["scores"][i], np.float64)
@@ -76,7 +127,7 @@ def numbers(prog: dict, ref: dict, label: np.ndarray) -> dict:
     leaves = max(abs(int(p["num_leaves"]) - int(r["num_leaves"]))
                  for p, r in zip(prog["trees"][:k], ref["trees"]))
     return {
-        "loss_gap": loss,
+        "loss_gap": loss_gap,
         "update1_gap": _rel(update(prog, 1), update(ref, 1)),
         "updateK_gap": _rel(update(prog, k), update(ref, k)),
         "gain_gap": gain,

@@ -54,18 +54,34 @@ def make_data(cell: dict, seed: int) -> dict:
     return cell["datagen"].generate(cell["config"], seed)
 
 
-def fit_mappers(data: dict, params: dict):
+# what a generator may return beside bins, label and values, and the type
+# the cache file and the reference are handed it in
+META = {"group": np.int64, "weight": np.float64, "position": np.int64}
+
+
+def metadata(data: dict) -> dict:
+    """The optional arrays the generator returned, by keyword.  Empty for
+    data that holds none: the calls they are forwarded to are then the
+    calls of a plain deployment, argument for argument."""
+    return {name: np.asarray(data[name], dtype)
+            for name, dtype in META.items() if data.get(name) is not None}
+
+
+def fit_mappers(data: dict, params: dict, categorical_features=()):
     """The program's own binner, fitted on the small value table (each value
     repeated enough to pass ``min_data_in_bin``), so the thresholds a tree
-    records are the program's.  Returns the binner and the lookup from drawn
-    bin to the program's bin, ``lut[b, f]``."""
+    records are the program's.  A column in ``categorical_features`` gets a
+    categorical mapper; its values are the category ids.  Returns the binner
+    and the lookup from drawn bin to the program's bin, ``lut[b, f]``."""
     from lightgbm_tpu.binning import DatasetBinner
 
     values = data["values"]
     min_in_bin = int(params.get("min_data_in_bin", 3))
+    kw = ({"categorical_features": categorical_features}
+          if categorical_features else {})
     binner = DatasetBinner.fit(np.repeat(values, min_in_bin + 1, axis=0),
                                max_bin=int(params["max_bin"]),
-                               min_data_in_bin=min_in_bin)
+                               min_data_in_bin=min_in_bin, **kw)
     lut = np.stack([binner.mappers[f].transform(values[:, f])
                     for f in range(values.shape[1])], axis=1)
     return binner, lut
@@ -85,15 +101,19 @@ def build_dataset(cell: dict, data: dict, cache_dir: str, seed: int,
                   spans: Optional[Spans] = None):
     """The binned ``Dataset`` through the public constructor for binned
     data: ``io/stream.create_bin_cache`` then ``lgb.Dataset(path)``.  The
-    file is removed once loaded."""
+    file is removed once loaded.  Query sizes, weights and positions ride in
+    the file, and a ``Dataset`` that does not give them back as drawn is not
+    a measurement."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.io.stream import create_bin_cache
 
     spans = spans if spans is not None else Spans()
     params = cell["config"]["params"]
     with spans.timed("mappers_s"):
-        binner, lut = fit_mappers(data, params)
+        binner, lut = fit_mappers(
+            data, params, cell["config"].get("categorical_features", ()))
         bins = program_bins(data, lut)
+    meta = metadata(data)
     os.makedirs(cache_dir, exist_ok=True)
     path = os.path.join(cache_dir,
                         f"{cell['config']['name']}-{int(seed)}.bin")
@@ -102,13 +122,17 @@ def build_dataset(cell: dict, data: dict, cache_dir: str, seed: int,
         with spans.timed("dataset_file_s"):
             create_bin_cache(path, bins, binner.mappers,
                              label=np.asarray(data["label"], np.float64),
-                             feature_names=names)
+                             feature_names=names, **meta)
         with spans.timed("dataset_load_s"):
             ds = lgb.Dataset(path, params={"max_bin": int(params["max_bin"])})
             ds.construct()
     finally:
         if os.path.exists(path):
             os.remove(path)
+    for name, want in meta.items():
+        if not np.array_equal(ds.get_field(name), want):
+            raise RuntimeError(f"the Dataset does not hold the {name} the "
+                               "generator drew")
     return ds
 
 
@@ -191,6 +215,7 @@ def window(bst, tokens: deque, seconds: float, tree_s_guess: float,
     with CompileCounter() as cc:
         t0 = time.perf_counter()
         issued = 0
+        issued_at = []  # host seconds into the window at each tree's issue
         while True:
             pace(tokens, in_flight)
             elapsed = time.perf_counter() - t0
@@ -202,6 +227,7 @@ def window(bst, tokens: deque, seconds: float, tree_s_guess: float,
                 break
             if tracer is not None:
                 tracer.before_tree(issued, done)
+            issued_at.append(elapsed)
             issue(bst, tokens)
             issued += 1
         jax.block_until_ready(score_of(bst))
@@ -210,7 +236,7 @@ def window(bst, tokens: deque, seconds: float, tree_s_guess: float,
             tracer.close()
     return {"trees": issued, "t0": t0, "t1": t1, "seconds": t1 - t0,
             "compiles": cc.compiles - cc.cache_hits,
-            "cache_loads": cc.cache_hits}
+            "cache_loads": cc.cache_hits, "issued_at_s": issued_at}
 
 
 def booster_flags(bst, ds) -> dict:
@@ -270,10 +296,12 @@ def free_program() -> None:
 # ---------------------------------------------------------------------------
 
 def run_reference(cell: dict, data: dict, n_trees: int, **kw) -> dict:
+    """The plain reference on what the generator drew: ``group=``,
+    ``weight=`` and ``position=`` only where the data holds them."""
     cfg = cell["config"]
     return cell["reference"].train(
         data["bins"], data["label"], cfg["params"], n_trees=n_trees,
-        leaf_tile=int(cfg["grower"]["leaf_tile"]), **kw)
+        leaf_tile=int(cfg["grower"]["leaf_tile"]), **metadata(data), **kw)
 
 
 def drive(cell: dict, seed: int, seconds: float, cache_dir: str,
@@ -329,7 +357,7 @@ def drive(cell: dict, seed: int, seconds: float, cache_dir: str,
     rows = [work.tree_rows(t, n_rows) for t in models]
     out = {"spans": spans, "window": win, "flags": flags, "warm_trees": warm_n,
            "tree_rows": rows, "n_rows": n_rows, "n_features": n_features,
-           "tracer": tracer, "label": data["label"], "program": prog,
+           "tracer": tracer, "program": prog,
            "data": data, "reference_trees": k}
     return out, (bst, ds)
 
@@ -343,5 +371,6 @@ def judge(cell: dict, run: dict) -> tuple[bool, dict]:
     t = time.perf_counter()
     ref = run_reference(cell, run["data"], run["reference_trees"])
     run["spans"]["reference_s"] = time.perf_counter() - t
-    nums = compare.numbers(run["program"], ref, run["label"])
+    nums = compare.numbers(run["program"], ref, run["data"],
+                           **compare.loss_named(cell["config"]))
     return compare.judge(nums, cell["config"]["limits"])

@@ -8,7 +8,9 @@ drew from the seed and the training parameters of the configuration's file.
 Semantics (LightGBM's serial tree learner, as the configuration states them):
 
 * score starts at ``log(p / (1 - p))`` of the label mean; per tree
-  ``g = sigmoid(score) - y`` and ``h = p (1 - p)``;
+  ``g = sigmoid(score) - y`` and ``h = p (1 - p)``; with a weight a row the
+  mean is the weighted one and both are multiplied by the row's weight (a
+  leaf's row count stays a count);
 * a leaf's best split is the (feature, bin) with the largest
   ``GL^2/(HL+l2) + GR^2/(HR+l2) - G^2/(H+l2)`` among thresholds that leave
   both sides ``min_data_in_leaf`` rows and ``min_sum_hessian_in_leaf``
@@ -186,8 +188,8 @@ def _add_leaf_values(score, leaf_id, value):
 
 
 def train(bins: np.ndarray, label: np.ndarray, params: dict, *, n_trees: int,
-          leaf_tile: int, row_block: int = 2048, payload_terms: int = 3
-          ) -> dict:
+          leaf_tile: int, row_block: int = 2048, payload_terms: int = 3,
+          weight=None) -> dict:
     """Boost ``n_trees`` trees; returns the score after 0..n_trees trees
     (host float32), and per tree the sum of split gains, the root split's
     gain, the leaf count and each leaf's row count."""
@@ -206,7 +208,9 @@ def train(bins: np.ndarray, label: np.ndarray, params: dict, *, n_trees: int,
     label_d = jnp.pad(jnp.asarray(label, jnp.float32), (0, n_pad - n))
     root_id = jnp.where(jnp.arange(n_pad) < n, 0, -1).astype(jnp.int32)
 
-    mean = float(np.asarray(label, np.float64).mean())
+    weight_d = None if weight is None else jnp.pad(
+        jnp.asarray(weight, jnp.float32), (0, n_pad - n))
+    mean = float(np.average(np.asarray(label, np.float64), weights=weight))
     score = jnp.full((n_pad,), np.log(mean / (1.0 - mean)), jnp.float32)
     scores = [np.asarray(score[:n])]
     trees = []
@@ -218,6 +222,8 @@ def train(bins: np.ndarray, label: np.ndarray, params: dict, *, n_trees: int,
 
     for _ in range(n_trees):
         g, h = _gradients(score, label_d)
+        if weight_d is not None:
+            g, h = g * weight_d, h * weight_d
         leaf_id = root_id
         hists = jnp.zeros((num_leaves, 3, f, n_bins), jnp.float32)
         hist0 = hist(bins_d, root_id, g, h)[0]

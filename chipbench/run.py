@@ -140,7 +140,9 @@ def main(argv=None) -> int:
         "workload": cell["name"], "seed": args.seed, "setup_s": setup_s,
         "window_s": win["seconds"], "trees": win["trees"],
         "spans": dict(spans), "flags": run["flags"],
+        "compiles_in_window": win["compiles"],
         "cache_loads_in_window": win["cache_loads"],
+        "issued_at_s": win["issued_at_s"],
         "work": {"bound": least[0]["bound"],
                  "rows_per_tree": [w["rows"] for w in least]},
         "total_s": time.perf_counter() - _T_START}

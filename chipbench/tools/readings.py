@@ -64,6 +64,7 @@ def main() -> int:
     control = cell["config"]["control"]
     cache = str(HERE.parent / ".chipbench_cache")
     limits = cell["config"]["limits"]
+    loss = compare.loss_named(cell["config"])
     verdicts: dict = {}
     out = open(args.out, "a") if args.out else None
     for i in range(args.seeds):
@@ -90,7 +91,7 @@ def main() -> int:
             row["control_flags"] = cflags
         row["correct"] = {}
         for name, s in sides.items():
-            row[name] = compare.numbers(s, ref, data["label"])
+            row[name] = compare.numbers(s, ref, data, **loss)
             ok, compared = compare.judge(row[name], limits)
             row["correct"][name] = ok
             verdicts.setdefault(name, []).append(ok)

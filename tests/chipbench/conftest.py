@@ -1,5 +1,6 @@
 """Fixtures of the chipbench tests: a toy configuration and a second traffic
-mix, added as files under a temporary root, with no edit to ``chipbench/``."""
+mix, added as files under a temporary root, with no edit to ``chipbench/``;
+and the program's counters and spans emptied around a test that reads them."""
 
 import copy
 import json
@@ -48,3 +49,20 @@ def toy_roots(tmp_path):
 def toy_cell(toy_roots):
     return loader.load_cell({"name": "toy-train", "config": "toy",
                              "traffic": "short-train", "chips": 1}, toy_roots)
+
+
+@pytest.fixture
+def program_state():
+    """The process-wide registry and span ring, emptied around a test and
+    left switched on as every other test finds them."""
+    from lightgbm_tpu.obs import metrics as obs
+    from lightgbm_tpu.obs import trace as obs_trace
+
+    def empty():
+        obs.set_enabled(obs.DEFAULT_ENABLED)
+        obs.reset()
+        obs_trace.reset_trace()
+
+    empty()
+    yield
+    empty()

@@ -6,6 +6,8 @@ import copy
 import json
 
 from chipbench.harness import loader, readers
+from lightgbm_tpu.obs import metrics as obs
+from lightgbm_tpu.obs import trace as obs_trace
 
 
 def test_added_config_and_traffic_are_found_first(toy_roots, toy_cell):
@@ -26,9 +28,9 @@ CTX = {"spans": {"warm_trees_s": 1.5, "first_update_s": 2.0},
 
 
 def test_a_later_cell_joins_the_committed_metrics_by_appended_names(
-        toy_roots):
+        toy_roots, program_state):
     """A second cell on a configuration that is there (with a traffic mix of
-    its own: a pair appears once) reads all seven committed metrics once
+    its own: a pair appears once) reads every committed metric once
     ``BENCHMARK.json`` names it on their lists: no edit under
     ``chipbench/``."""
     bench = copy.deepcopy(loader.load_benchmark())
@@ -36,7 +38,9 @@ def test_a_later_cell_joins_the_committed_metrics_by_appended_names(
              "traffic": "short-train", "chips": 1, "why": "a later cell"}
     bench["workloads"].append(later)
     specs = loader.load_layer_metrics()
-    assert len(specs) == 7 and all("workloads" not in s for s in specs)
+    files = list((loader.CHIPBENCH / "layer_metrics").glob("*.json"))
+    assert len(specs) == len(files) >= len(bench["per_layer"])
+    assert all("workloads" not in s for s in specs)
     cell = loader.load_cell(later, toy_roots)
     assert cell["config"]["rows"] == 10500000
     assert cell["traffic"]["name"] == "short-train"
@@ -45,6 +49,13 @@ def test_a_later_cell_joins_the_committed_metrics_by_appended_names(
     ctx = dict(CTX, spans=spans, traced=range(1, 3), trace={
         "chips": 1, "busy_s": 3.0, "window_s": 4.0,
         "events": [["_hist_pallas_raw.3", 0, 500_000_000]]})
+    # what the readers of the program's own counters and spans find
+    obs.counter("train_boost_rounds_total").inc(4)
+    obs.counter("train_hist_passes_total").inc(120)
+    obs.counter("train_hist_rows_streamed_total").inc(120000)
+    obs.counter("train_hist_rows_needed_total").inc(16000)
+    for _ in range(4):
+        obs_trace.record_span("boost_round", 0.002)
     # not yet on the lists: it reports nothing, whatever there is to read
     assert readers.read_all(specs, ctx, later["name"],
                             bench["per_layer"]) == {}
@@ -60,7 +71,7 @@ def test_a_later_cell_joins_the_committed_metrics_by_appended_names(
     for m in bench["per_layer"]:
         del m["workloads"]
     got = readers.read_all(specs, ctx, "any-cell", bench["per_layer"])
-    assert len(got) == 7
+    assert len(got) == len(bench["per_layer"])
 
 
 def test_an_added_layer_metric_is_read_with_its_own_reader(toy_roots):

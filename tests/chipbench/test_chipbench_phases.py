@@ -16,20 +16,6 @@ CTX = {"spans": {}, "counters": {}, "window": {"seconds": 2.0, "trees": 3},
        "least_s": [], "trace": None, "traced": range(0)}
 
 
-@pytest.fixture
-def program_state():
-    """The process-wide registry and span ring, emptied around a test and
-    left switched on as every other test finds them."""
-    def empty():
-        obs.set_enabled(obs.DEFAULT_ENABLED)
-        obs.reset()
-        obs_trace.reset_trace()
-
-    empty()
-    yield
-    empty()
-
-
 def read_new(ctx=CTX, cell="higgs-train"):
     bench = loader.load_benchmark()
     specs = [s for s in loader.load_layer_metrics() if s["name"] in NEW]

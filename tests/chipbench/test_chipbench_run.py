@@ -65,11 +65,10 @@ def test_control_one_precision_down_is_not_correct(toy_cell, seed):
     ref = stages.run_reference(toy_cell, data, 2)
     limits = toy_cell["config"]["limits"]
     same = stages.run_reference(toy_cell, data, 2)
-    ok, _ = compare.judge(compare.numbers(same, ref, data["label"]), limits)
+    ok, _ = compare.judge(compare.numbers(same, ref, data), limits)
     assert ok
     low = stages.run_reference(toy_cell, data, 2, payload_terms=1)
-    ok, compared = compare.judge(compare.numbers(low, ref, data["label"]),
-                                 limits)
+    ok, compared = compare.judge(compare.numbers(low, ref, data), limits)
     assert not ok, compared
 
 
