@@ -485,9 +485,9 @@ class MultiErrorMetric(Metric):
 
 
 def pad_queries(query_boundaries: np.ndarray):
-    """Queries as a dense (Q, S) padded block (the TPU formulation of the
-    reference's per-query loops; same layout objectives._RankingObjective
-    uses).  Returns (pad_idx, pad_mask)."""
+    """Queries as a dense (Q, S) block padded to the longest query, for the
+    ranking metrics (the objectives bucket their queries by length:
+    objectives._RankingObjective).  Returns (pad_idx, pad_mask)."""
     qb = np.asarray(query_boundaries)
     nq = len(qb) - 1
     lens = np.diff(qb)

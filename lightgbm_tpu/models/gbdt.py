@@ -304,6 +304,7 @@ class GBDT:
                 if arrays.hist_passes is not None:
                     self._count_hist_passes(int(arrays.hist_passes),
                                             int(arrays.hist_blocks), tree)
+                self._count_rank_work()
 
     def _count_hist_passes(self, passes: int, blocks: int, tree: Tree) -> None:
         """What the tree's histogram passes read against what the tree
@@ -321,6 +322,17 @@ class GBDT:
         _obs.counter("train_hist_rows_needed_total").inc(
             n_rows + tree.smaller_child_rows())
         _obs.counter("train_hist_blocks_multiplied_total").inc(blocks)
+
+    def _count_rank_work(self) -> None:
+        """What a ranking objective's gradient step worked through for the
+        tree: the rows, the lanes of its query buckets (padding too) and the
+        pair terms formed.  Numbers of the layout, fixed at ``set_query``."""
+        work = getattr(self.objective, "rank_work", None)
+        if work is not None:
+            rows, lanes, pairs = work
+            _obs.counter("train_rank_rows_total").inc(rows)
+            _obs.counter("train_rank_lanes_total").inc(lanes)
+            _obs.counter("train_rank_pairs_total").inc(pairs)
 
     # -- non-finite guard rail (docs/ROBUSTNESS.md) --------------------
     def _guard_accumulate(self, arrays) -> None:

@@ -21,13 +21,15 @@ Each objective exposes:
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .config import Config
+from .obs import metrics as _obs
+from .utils.profiling import phase_scope
 
 Array = jnp.ndarray
 
@@ -376,27 +378,107 @@ class CrossEntropy(Objective):
         return 1.0 / (1.0 + jnp.exp(-score))
 
 
+# the pair terms one piece of a bucket forms at a time, in lanes: 16M
+# float32 lanes are 64 MB a temporary, whatever XLA chooses to keep of them
+_PAIR_PIECE_LANES = 1 << 24
+
+
+class _QueryBucket(NamedTuple):
+    """The queries of one width ``S``, a power of two: every query longer
+    than ``S / 2`` and no longer than ``S``, a query a row of the block."""
+
+    rows: Array  # (Q, S) int32: the row a lane holds; num_data in a padded lane
+    lens: Array  # (Q,) int32: rows of each query; lanes from there on are padding
+    label: Array  # (Q, S) float32: the rows' labels, 0 in a padded lane
+
+
+class _QueryLayout(NamedTuple):
+    """Every query in the bucket of its width, and the way back."""
+
+    buckets: Tuple[_QueryBucket, ...]
+    lane_of_row: Array  # (N,) int32: a row's lane, the buckets' lanes end to end
+
+
+def _lane_ids(bucket: _QueryBucket) -> Array:
+    return jax.lax.broadcasted_iota(jnp.int32, bucket.rows.shape, 1)
+
+
+def _valid_lanes(bucket: _QueryBucket) -> Array:
+    return _lane_ids(bucket) < bucket.lens[:, None]
+
+
+def _rows_to_lanes(values: Array, bucket: _QueryBucket) -> Array:
+    """(N,) by row to the bucket's (Q, S); a padded lane reads the last row
+    and is masked by whoever reads it."""
+    return jnp.take(values, bucket.rows, mode="clip")
+
+
+def _lanes_to_rows(layout: _QueryLayout, per_bucket) -> Array:
+    """The buckets' (Q, S) blocks back to (N,) by row.  Every row lies in
+    one lane, so the way back is a gather too and nothing is added up."""
+    flat = jnp.concatenate([x.reshape(-1) for x in per_bucket])
+    return flat.at[layout.lane_of_row].get(mode="promise_in_bounds")
+
+
 class _RankingObjective(Objective):
-    """Shared per-query padding machinery (reference: RankingObjective in
-    rank_objective.hpp — per-query parallel gradient computation).  Queries
-    are laid out as a dense (Q, S) block padded to the longest query; masked
-    lanes contribute zeros (SURVEY.md §10.3 item 3)."""
+    """The query layout the ranking objectives share (reference:
+    RankingObjective in rank_objective.hpp, a thread a query).
+
+    Queries are bucketed by length: a query of ``n`` rows lies in the bucket
+    of width ``S``, the power of two with ``S / 2 < n <= S``, as one row of a
+    dense ``(Q_b, S)`` block of row indices; lanes from ``n`` on are padding,
+    so the padded lanes of all buckets are under twice the rows, where one
+    block padded to the longest query is queries x longest (docs/RANKING.md).
+    Built once on the host, a dozen widths and no loop over the queries.
+    Every round gathers the scores into the buckets, works a bucket at a
+    time and gathers the gradients back by row, in one jitted step."""
 
     # per-iteration host state (xendcg's RNG iteration counter) must not be
     # baked into a traced step; LambdaRank overrides this — its position
     # biases ride the fused step as an explicit carry (fused_state protocol)
     fusable = False
+    # (rows, padded lanes, pair lanes) one gradient step works through:
+    # models/gbdt.py adds them to the train_rank_* counters a tree
+    rank_work: Optional[Tuple[int, int, int]] = None
 
     def set_query(self, query_boundaries: np.ndarray, labels: np.ndarray):
-        from .metrics import pad_queries
+        qb = np.asarray(query_boundaries, np.int64)
+        self.query_boundaries = qb
+        lens = np.diff(qb)
+        n = int(qb[-1]) if len(qb) else 0
+        self.max_query = int(lens.max()) if len(lens) else 0
+        width = np.ones_like(lens)
+        width[lens > 1] = 1 << np.ceil(
+            np.log2(lens[lens > 1])).astype(np.int64)
+        width = np.where(width < lens, 2 * width, width)  # log2 rounded down
+        label_pad = np.append(np.asarray(labels, np.float32).ravel()[:n],
+                              np.float32(0.0))
+        lane_of_row = np.zeros(n, np.int64)
+        buckets, self._bucket_queries, lanes = [], [], 0
+        for w in np.unique(width):  # a width a turn: a dozen, not a query each
+            queries = np.flatnonzero(width == w)
+            lane = np.arange(w)
+            valid = lane[None, :] < lens[queries, None]
+            rows = np.where(valid, qb[queries, None] + lane[None, :], n)
+            lane_of_row[rows[valid]] = lanes + np.flatnonzero(valid.ravel())
+            buckets.append(_QueryBucket(
+                rows=jnp.asarray(rows, jnp.int32),
+                lens=jnp.asarray(lens[queries], jnp.int32),
+                label=jnp.asarray(label_pad[rows])))
+            self._bucket_queries.append(queries)
+            lanes += rows.size
+        if max(lanes, n) >= 2 ** 31:
+            raise ValueError("the query layout indexes its lanes in int32: "
+                             f"{lanes} lanes are too many")
+        self._layout = _QueryLayout(tuple(buckets),
+                                    jnp.asarray(lane_of_row, jnp.int32))
+        self.rank_work = (n, lanes, self._pair_lanes())
+        _obs.gauge("rank_buckets").set(len(buckets))
+        _obs.gauge("rank_longest_query").set(self.max_query)
 
-        self.query_boundaries = np.asarray(query_boundaries)
-        nq = len(self.query_boundaries) - 1
-        lens = np.diff(self.query_boundaries)
-        self.max_query = int(lens.max()) if nq else 0
-        pad_idx, pad_mask = pad_queries(self.query_boundaries)
-        self._pad_idx = jnp.asarray(pad_idx)
-        self._pad_mask = jnp.asarray(pad_mask)
+    def _pair_lanes(self) -> int:
+        """Pair terms a gradient step forms over all buckets."""
+        return 0
 
 
 class RankXENDCG(_RankingObjective):
@@ -420,23 +502,37 @@ class RankXENDCG(_RankingObjective):
         self._seed = int(getattr(cfg, "objective_seed", 5))
 
     def get_gradients(self, score, label, weight):
-        idx, msk = self._pad_idx, self._pad_mask
-        s = score[idx.reshape(-1)].reshape(idx.shape)
-        l = label[idx.reshape(-1)].reshape(idx.shape)
+        """``label`` is the one ``set_query`` was given: the layout holds it
+        by lane already.  The uniforms are drawn a row, so the layout does
+        not decide which row gets which."""
         key = jax.random.PRNGKey(self._seed + self._iter)
         self._iter += 1
-        u = jax.random.uniform(key, idx.shape, dtype=jnp.float32)
-        g, h = _xendcg_query(s, l, msk, u)
-        # .add, not .set: pad_idx's padding lanes all alias row 0 and carry
-        # masked-out zeros — a duplicate-index .set would zero row 0's grads
-        grad = jnp.zeros_like(score).at[idx.reshape(-1)].add(g.reshape(-1))
-        hess = jnp.zeros_like(score).at[idx.reshape(-1)].add(h.reshape(-1))
-        return grad, hess
+        return _xendcg_step(score, self._layout, key)
 
 
 @jax.jit
+def _xendcg_step(score, layout, key):
+    """XE-NDCG gradients of every row: one gather in, a bucket at a time,
+    one gather out."""
+    u_row = jax.random.uniform(key, score.shape, dtype=jnp.float32)
+    grads, hesss = [], []
+    for bucket in layout.buckets:
+        with phase_scope("rank.gather"):
+            s = _rows_to_lanes(score, bucket)
+            u = _rows_to_lanes(u_row, bucket)
+        with phase_scope("rank.pairs"):
+            g, h = _xendcg_query(s, bucket.label, _valid_lanes(bucket), u)
+        grads.append(g)
+        hesss.append(h)
+    if not grads:
+        return jnp.zeros_like(score), jnp.zeros_like(score)
+    with phase_scope("rank.scatter"):
+        return (_lanes_to_rows(layout, grads), _lanes_to_rows(layout, hesss))
+
+
 def _xendcg_query(scores, labels, mask, u):
-    """Vectorized XE-NDCG gradients over padded queries: (Q, S) in/out."""
+    """XE-NDCG gradients of the queries of one (Q, S) block, a query a row,
+    lanes outside ``mask`` padding: (Q, S) in and out."""
     neg_inf = jnp.float32(-1e30)
     masked = jnp.where(mask, scores, neg_inf)
     rho = jax.nn.softmax(masked, axis=1)
@@ -485,11 +581,33 @@ class CrossEntropyLambda(Objective):
 class LambdarankNDCG(_RankingObjective):
     """reference: LambdarankNDCG in rank_objective.hpp.
 
-    Pairwise NDCG-weighted lambdas inside each query, truncated to
-    `lambdarank_truncation_level`.  Queries are processed as padded fixed-width
-    blocks (SURVEY.md §10.3 item 3): queries are bucketed by length and the
-    pairwise (i, j) interaction computed as dense (Q, S, S) tensors — the
-    TPU-friendly formulation of the reference's per-query scalar loops.
+    Pairwise NDCG-weighted lambdas inside each query, over the query
+    buckets of :class:`_RankingObjective`.  In a query of ``n`` rows the
+    rows are ranked by score, descending, **stable: ties in row order** (at
+    the first round every score is 0 and from the second every row of a leaf
+    ties, so the tie rule decides every discount).  With ``r`` the rank,
+    ``T`` the truncation level, ``D(r) = 1 / log2(r + 2)`` for ``r < T`` and
+    0 beyond, ``G`` the label gain and ``M`` the query's inverse maximum DCG
+    at ``min(n, T)`` (0 where that DCG is 0): every pair of rows with
+    different labels of which at least one ranks inside ``T`` has, with
+    ``hi`` the row of the larger label,
+
+        delta  = |G(l_hi) - G(l_lo)| * |D(r_hi) - D(r_lo)| * M
+        rho    = 1 / (1 + exp(sigmoid * (s_hi - s_lo)))
+        lambda = sigmoid * rho * delta
+        h      = sigmoid^2 * rho * (1 - rho) * delta
+
+    ``g_hi -= lambda``, ``g_lo += lambda``, both hessians ``+= h``; under
+    ``lambdarank_norm``, with ``L`` the sum of ``lambda`` over the query's
+    pairs, each once, all of a query's gradients and hessians are scaled by
+    ``log2(1 + L) / L``.  The better-ranked row of such a pair is one of the
+    ``T`` best, so a bucket forms ``(Q_b, min(T, S), S)`` pair terms, a piece
+    of its queries at a time, and never ``(Q_b, S, S)``.
+
+    Unlike upstream (4.x, from memory; no copy is on this machine), which
+    keeps the discount of a row ranked beyond ``T``, divides ``delta`` by
+    ``0.01 + |s_hi - s_lo|`` under ``lambdarank_norm`` and counts each pair
+    twice in ``L``.
     """
 
     name = "lambdarank"
@@ -506,24 +624,39 @@ class LambdarankNDCG(_RankingObjective):
         if not gains:
             gains = [float(2**i - 1) for i in range(31)]
         self.label_gain = np.asarray(gains, dtype=np.float64)
-        self._query_info = None  # set via set_query
 
     def set_query(self, query_boundaries: np.ndarray, labels: np.ndarray):
-        """Precompute inverse max DCG per query (reference:
-        inverse_max_dcgs_ in LambdarankNDCG::Init)."""
-        from .metrics import dcg_at_k
-
+        """The layout, and each query's inverse maximum DCG at
+        ``min(n, T)`` (reference: inverse_max_dcgs_ in
+        LambdarankNDCG::Init): the labels sorted within their queries, best
+        first, the gains of the ``T`` first times their discounts summed by
+        query."""
         super().set_query(query_boundaries, labels)
-        nq = len(self.query_boundaries) - 1
-        inv = np.zeros(nq, dtype=np.float64)
-        trunc = self.truncation
-        for q in range(nq):
-            lo, hi = self.query_boundaries[q], self.query_boundaries[q + 1]
-            ql = labels[lo:hi]
-            ideal = np.sort(ql)[::-1]
-            m = dcg_at_k(ideal, min(len(ql), trunc), self.label_gain)
-            inv[q] = 1.0 / m if m > 0 else 0.0
-        self.inverse_max_dcg = inv
+        qb = self.query_boundaries
+        lens = np.diff(qb)
+        lab = np.clip(np.asarray(labels).ravel()[:int(qb[-1])].astype(
+            np.int64), 0, len(self.label_gain) - 1)
+        query = np.repeat(np.arange(len(lens)), lens)
+        best_first = lab[np.lexsort((-lab, query))]
+        rank = np.arange(len(lab)) - np.repeat(qb[:-1], lens)
+        top = rank < self.truncation
+        max_dcg = np.bincount(
+            query[top], self.label_gain[best_first[top]]
+            / np.log2(rank[top] + 2.0), minlength=len(lens))
+        self.inverse_max_dcg = np.where(
+            max_dcg > 0, 1.0 / np.where(max_dcg > 0, max_dcg, 1.0), 0.0)
+        self._inv_mdcg = tuple(
+            jnp.asarray(self.inverse_max_dcg[q], jnp.float32)
+            for q in self._bucket_queries)
+        # the gains of the labels that occur: the step looks a gain up by a
+        # chain of selects, a link a table entry
+        self._gain = jnp.asarray(self.label_gain[:int(lab.max()) + 1]
+                                 if len(lab) else self.label_gain[:1],
+                                 jnp.float32)
+
+    def _pair_lanes(self) -> int:
+        return sum(int(b.rows.shape[0]) * min(self.truncation, b.rows.shape[1])
+                   * int(b.rows.shape[1]) for b in self._layout.buckets)
 
     def set_positions(self, positions: np.ndarray):
         """Enable position-bias correction (reference: rank_objective.hpp —
@@ -534,52 +667,33 @@ class LambdarankNDCG(_RankingObjective):
         lambdarank_position_bias_regularization, so the TREES learn the
         position-debiased ranking while the biases absorb presentation
         effects (unbiased LambdaRank)."""
-        positions = np.asarray(positions, np.int64).ravel()
-        idx = np.asarray(self._pad_idx)
-        self._pos_pad = jnp.asarray(positions[idx])  # (Q, S)
+        positions = np.append(np.asarray(positions, np.int64).ravel(), 0)
+        # by lane, as the labels lie; a padded lane reads position 0, masked
+        self._pos = tuple(jnp.asarray(positions[np.asarray(b.rows)], jnp.int32)
+                          for b in self._layout.buckets)
         self.num_positions = int(positions.max()) + 1
         self.pos_bias = jnp.zeros((self.num_positions,), jnp.float32)
         self.pos_reg = float(getattr(self.cfg, "lambdarank_position_bias_regularization", 0.0))
 
-    _pos_pad = None
+    _pos = None
 
-    def _gradients_core(self, score, label, pos_bias):
+    def _gradients_core(self, score, pos_bias):
         """PURE lambda computation: position bias enters as an argument and
         the refit bias is returned, so this body can trace inside the fused
         step with the bias as a carry."""
-        idx, msk = self._pad_idx, self._pad_mask
-        s = score[idx.reshape(-1)].reshape(idx.shape)
-        l = label[idx.reshape(-1)].reshape(idx.shape)
-        if pos_bias is not None:
-            # scores seen by the lambda computation include the position bias
-            s = s + jnp.where(msk, pos_bias[self._pos_pad], 0.0)
-        gains = jnp.asarray(self.label_gain, dtype=jnp.float32)
-        inv_mdcg = jnp.asarray(self.inverse_max_dcg, dtype=jnp.float32)
-        g, h = _lambdarank_pairwise(
-            s, l, msk, gains, inv_mdcg, self.sigmoid, self.truncation, self.norm
-        )
-        new_bias = pos_bias
-        if pos_bias is not None:
-            # Newton refit of the biases from this iteration's lambdas
-            # (reference: UpdatePositionBiasFactors once per iteration)
-            P = self.num_positions
-            gm = jnp.where(msk, g, 0.0).reshape(-1)
-            hm = jnp.where(msk, h, 0.0).reshape(-1)
-            pp = self._pos_pad.reshape(-1)
-            Gp = jnp.zeros((P,), jnp.float32).at[pp].add(gm)
-            Hp = jnp.zeros((P,), jnp.float32).at[pp].add(hm)
-            reg = self.pos_reg
-            new_bias = pos_bias - (Gp + reg * pos_bias) / (Hp + reg + 1e-9)
-        # .add, not .set: pad_idx's padding lanes all alias row 0 and carry
-        # masked-out zeros — a duplicate-index .set would zero row 0's grads
-        grad = jnp.zeros_like(score).at[idx.reshape(-1)].add(g.reshape(-1))
-        hess = jnp.zeros_like(score).at[idx.reshape(-1)].add(h.reshape(-1))
-        return grad, hess, new_bias
+        return _lambdarank_step(
+            score, self._layout, self._inv_mdcg, self._gain,
+            None if pos_bias is None else self._pos, pos_bias,
+            sigmoid=float(self.sigmoid), truncation=int(self.truncation),
+            norm=bool(self.norm),
+            pos_reg=0.0 if pos_bias is None else self.pos_reg)
 
     def get_gradients(self, score, label, weight):
-        bias = self.pos_bias if self._pos_pad is not None else None
-        grad, hess, new_bias = self._gradients_core(score, label, bias)
-        if self._pos_pad is not None:
+        """``label`` is the one ``set_query`` was given: the layout holds it
+        by lane already."""
+        bias = self.pos_bias if self._pos is not None else None
+        grad, hess, new_bias = self._gradients_core(score, bias)
+        if self._pos is not None:
             self.pos_bias = new_bias
         return grad, hess
 
@@ -588,59 +702,173 @@ class LambdarankNDCG(_RankingObjective):
     # here that Newton refit happens in-trace and the carry is written back
     # when the step retires)
     def fused_state(self):
-        return self.pos_bias if self._pos_pad is not None else None
+        return self.pos_bias if self._pos is not None else None
 
     def fused_gradients(self, score, label, weight, state):
-        return self._gradients_core(score, label, state)
+        return self._gradients_core(score, state)
 
     def set_fused_state(self, state) -> None:
         if state is not None:
             self.pos_bias = state
 
 
-@functools.partial(jax.jit, static_argnames=("sigmoid", "truncation", "norm"))
-def _lambdarank_pairwise(scores, labels, mask, label_gain, inv_mdcg, sigmoid, truncation, norm):
-    """Dense pairwise lambda computation over padded queries.
+@functools.partial(jax.jit, static_argnames=("sigmoid", "truncation", "norm",
+                                             "pos_reg"))
+def _lambdarank_step(score, layout, inv_mdcg, label_gain, pos, pos_bias, *,
+                     sigmoid, truncation, norm, pos_reg):
+    """The lambdas of every row and, with position biases, their Newton
+    refit: scores gathered into the buckets, the ``truncation`` best-ranked
+    rows of every query picked out in rank order, their pair terms against
+    the rows ranked after them, gathered out by row.  ``inv_mdcg`` and
+    ``pos`` are a bucket each."""
+    grads, hesss = [], []
+    bias_g = bias_h = None
+    if pos_bias is not None:
+        bias_g = bias_h = jnp.zeros_like(pos_bias)
+    for i, bucket in enumerate(layout.buckets):
+        with phase_scope("rank.gather"):
+            s = _rows_to_lanes(score, bucket)
+            if pos_bias is not None:
+                # the lambdas see the score with its row's position bias
+                s = s + jnp.where(_valid_lanes(bucket), pos_bias[pos[i]], 0.0)
+        with phase_scope("rank.sort"):
+            window = _rank_window(s, bucket, min(truncation, s.shape[1]))
+        with phase_scope("rank.pairs"):
+            g, h = _pair_lambdas(
+                (s, bucket.label, bucket.lens, inv_mdcg[i]) + window,
+                label_gain, sigmoid=sigmoid, norm=norm)
+        if pos_bias is not None:
+            flat = pos[i].reshape(-1)
+            bias_g = bias_g.at[flat].add(g.reshape(-1))
+            bias_h = bias_h.at[flat].add(h.reshape(-1))
+        grads.append(g)
+        hesss.append(h)
+    if pos_bias is not None:
+        # Newton refit of the biases from this iteration's lambdas
+        # (reference: UpdatePositionBiasFactors once per iteration)
+        pos_bias = pos_bias - (bias_g + pos_reg * pos_bias) / (
+            bias_h + pos_reg + 1e-9)
+    if not grads:
+        return jnp.zeros_like(score), jnp.zeros_like(score), pos_bias
+    with phase_scope("rank.scatter"):
+        return (_lanes_to_rows(layout, grads), _lanes_to_rows(layout, hesss),
+                pos_bias)
 
-    scores/labels/mask: (Q, S).  Returns (grad, hess): (Q, S).
-    """
-    q, s_len = scores.shape
-    neg_inf = jnp.float32(-1e30)
-    masked_scores = jnp.where(mask, scores, neg_inf)
-    # rank of each item within its query by current score (descending)
-    order = jnp.argsort(-masked_scores, axis=1, stable=True)  # (Q, S) item idx by rank
-    ranks = jnp.argsort(order, axis=1)  # rank of each position
 
-    lg = label_gain[jnp.clip(labels.astype(jnp.int32), 0, label_gain.shape[0] - 1)]
-    lg = jnp.where(mask, lg, 0.0)
-    disc = 1.0 / jnp.log2(ranks.astype(jnp.float32) + 2.0)
-    disc = jnp.where(ranks < truncation, disc, jnp.where(mask, 0.0, 0.0))
-    # keep pairs where at least one side ranks inside the truncation window
-    in_window = ranks < truncation
+def _rank_window(s, bucket, tw):
+    """The ``tw`` best-ranked rows of every query of the bucket, by score,
+    descending, **ties in row order**: ``tw`` turns, each taking the first
+    lane that holds the largest score left.  No sort: a discount is 0 beyond
+    the truncation level, so a rank past the window is never asked for.
+    Returns ``rank`` (Q, S) int32, a lane's rank where it is under ``tw``
+    and ``tw`` elsewhere, and the window's scores and labels (Q, tw) in rank
+    order; a window slot from a query's length on holds nothing and is
+    masked by whoever reads it."""
+    lane = _lane_ids(bucket)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], tw), 1)
 
-    d_s = scores[:, :, None] - scores[:, None, :]
-    d_gain = lg[:, :, None] - lg[:, None, :]
-    d_disc = disc[:, :, None] - disc[:, None, :]
-    delta_ndcg = jnp.abs(d_gain) * jnp.abs(d_disc) * inv_mdcg[:, None, None]
-    better = (labels[:, :, None] > labels[:, None, :]) & mask[:, :, None] & mask[:, None, :]
-    better = better & (in_window[:, :, None] | in_window[:, None, :])
+    def turn(a, carry):
+        left, rank, win_s, win_label = carry
+        best = jnp.max(left, axis=1, keepdims=True)
+        first = jnp.min(jnp.where(left == best, lane, s.shape[1]), axis=1,
+                        keepdims=True)
+        taken = (lane == first) & (a < bucket.lens[:, None])
+        label = jnp.sum(jnp.where(taken, bucket.label, 0.0), axis=1,
+                        keepdims=True)
+        return (jnp.where(taken, -jnp.inf, left), jnp.where(taken, a, rank),
+                jnp.where(slot == a, best, win_s),
+                jnp.where(slot == a, label, win_label))
 
-    rho = 1.0 / (1.0 + jnp.exp(sigmoid * d_s))  # sigmoid(-sig*(si-sj))
-    lam = sigmoid * rho * delta_ndcg
-    hes = sigmoid * sigmoid * rho * (1.0 - rho) * delta_ndcg
-    lam = jnp.where(better, lam, 0.0)
-    hes = jnp.where(better, hes, 0.0)
+    zeros = jnp.zeros(slot.shape, jnp.float32)
+    _, rank, win_s, win_label = jax.lax.fori_loop(
+        0, tw, turn, (jnp.where(_valid_lanes(bucket), s, -jnp.inf),
+                      jnp.full(s.shape, tw, jnp.int32), zeros, zeros))
+    return rank, win_s, win_label
 
-    grad = -jnp.sum(lam, axis=2) + jnp.sum(jnp.swapaxes(lam, 1, 2), axis=2)
-    hess = jnp.sum(hes, axis=2) + jnp.sum(jnp.swapaxes(hes, 1, 2), axis=2)
 
+def _pair_lambdas(arrays, label_gain, **kw):
+    """Lambdas and hessians of one bucket: the whole bucket where its pair
+    terms fit ``_PAIR_PIECE_LANES``, else its queries in equal pieces, one
+    after the other.  ``arrays`` are ``_pair_lambdas_piece``'s, a query a
+    row each."""
+    q, w = arrays[0].shape
+    per_piece = max(1, _PAIR_PIECE_LANES // (arrays[-1].shape[1] * w))
+    if q <= per_piece:
+        return _pair_lambdas_piece(*arrays, label_gain, **kw)
+    pieces = -(-q // per_piece)
+    per_piece = -(-q // pieces)
+
+    def in_pieces(x):
+        # the queries padded on are empty: no lane of theirs is valid
+        pad = [(0, pieces * per_piece - q)] + [(0, 0)] * (x.ndim - 1)
+        return jnp.pad(x, pad).reshape((pieces, per_piece) + x.shape[1:])
+
+    g, h = jax.lax.map(
+        lambda a: _pair_lambdas_piece(*a, label_gain, **kw),
+        tuple(in_pieces(x) for x in arrays))
+    return g.reshape(-1, w)[:q], h.reshape(-1, w)[:q]
+
+
+def _pair_lambdas_piece(s, label, lens, inv_mdcg, rank, win_s, win_label,
+                        label_gain, *, sigmoid, norm):
+    """``s``, ``label``, ``rank``: (Q, S), a query a row, lanes from ``lens``
+    on padding; ``win_s``, ``win_label``: (Q, window), the best-ranked rows
+    in rank order (``_rank_window``).  Pairs the window row of rank ``a``
+    with every lane ranked after it: each pair with a member inside the
+    truncation level, once, as (Q, window, S) terms.  Returns (grad, hess),
+    (Q, S) by lane."""
+    w, tw = s.shape[1], win_s.shape[1]
+    slot = jnp.arange(tw, dtype=jnp.int32)
+
+    def gain_of(x):  # a short table: selects, no gather
+        level = jnp.clip(x.astype(jnp.int32), 0, label_gain.shape[0] - 1)
+        gain = jnp.zeros_like(x)
+        for k in range(label_gain.shape[0]):
+            gain = jnp.where(level == k, label_gain[k], gain)
+        return gain
+
+    def discount(r):  # 0 from the truncation level on, and past the window
+        return jnp.where(r < tw, 1.0 / jnp.log2(r.astype(jnp.float32) + 2.0),
+                         0.0)
+
+    def a(x):  # the window's rows against
+        return x[:, :, None]
+
+    def b(x):  # every lane
+        return x[:, None, :]
+
+    lane = jnp.arange(w, dtype=jnp.int32)
+    pair = ((b(rank) > slot[None, :, None])
+            & (slot[None, :, None] < lens[:, None, None])
+            & (lane[None, None, :] < lens[:, None, None])
+            & (a(win_label) != b(label)))
+    a_is_hi = a(win_label) > b(label)
+    d_s = a(win_s) - b(s)
+    d_s = jnp.where(a_is_hi, d_s, -d_s)  # s_hi - s_lo
+    delta = (jnp.abs(a(gain_of(win_label)) - b(gain_of(label)))
+             * jnp.abs(discount(slot)[None, :, None] - b(discount(rank)))
+             * inv_mdcg[:, None, None])
+    rho = 1.0 / (1.0 + jnp.exp(sigmoid * d_s))
+    lam = jnp.where(pair, sigmoid * rho * delta, 0.0)
+    hes = jnp.where(pair, sigmoid * sigmoid * rho * (1.0 - rho) * delta, 0.0)
+    to_b = jnp.where(a_is_hi, lam, -lam)  # the row of the larger label loses
+    # a window row's own sums go to the lane that holds its rank
+    is_a = b(rank) == slot[None, :, None]
+
+    def to_lanes(x):
+        # the barrier keeps XLA from rewriting the sum over the lanes and
+        # its way back as a window reduction over a stored (Q, window, S)
+        x = jax.lax.optimization_barrier(x)
+        return jnp.sum(jnp.where(is_a, a(x), 0.0), axis=1)
+
+    grad = jnp.sum(to_b, axis=1) - to_lanes(jnp.sum(to_b, axis=2))
+    hess = jnp.sum(hes, axis=1) + to_lanes(jnp.sum(hes, axis=2))
     if norm:
-        total = jnp.sum(jnp.abs(lam), axis=(1, 2), keepdims=False)[:, None]
-        scale = jnp.where(total > 0, jnp.log2(1.0 + total) / jnp.maximum(total, 1e-20), 1.0)
+        total = jnp.sum(lam, axis=(1, 2))[:, None]
+        scale = jnp.where(total > 0, jnp.log2(1.0 + total)
+                          / jnp.maximum(total, 1e-20), 1.0)
         grad = grad * scale
         hess = hess * scale
-    grad = jnp.where(mask, grad, 0.0)
-    hess = jnp.where(mask, hess, 0.0)
     return grad, hess
 
 

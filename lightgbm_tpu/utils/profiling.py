@@ -39,6 +39,10 @@ from .log import log_info
 # docs/OBSERVABILITY.md lists them.
 DEVICE_PHASES = (
     "gbdt.gradients",  # objective.get_gradients of the round
+    "rank.gather",  # ranking objectives: scores by row into the query buckets
+    "rank.sort",  # each query's best-ranked rows taken in rank order
+    "rank.pairs",  # the pair terms of the truncation window (xendcg: softmax)
+    "rank.scatter",  # gradients and hessians from the buckets' lanes by row
     "grow.root",  # the root's full pass and totals
     "grow.partition",  # admission, split apply, row routing (leaf_id)
     "grow.slots",  # slot per row for the pass

@@ -99,3 +99,50 @@ def test_partition_over_the_shadow_is_one_pass(one_chip):
     cost = cost[0] if isinstance(cost, list) else cost
     rows = tile[0] * hp.ROW_TILE
     assert cost["bytes accessed"] <= 1.5 * (slots * 2 * rows + 8 * rows)
+
+
+def test_rank_step_keeps_every_array_under_a_gigabyte_at_istella_s_shape(
+        one_chip):
+    """The lambdarank gradient step at the ranking cell's shape (4,883,750
+    rows in 15,480 queries of up to 2,048 rows, truncation 30), compiled for
+    the chip: a bucket's pair terms are (piece, 30, width) and never
+    (queries, width, width), no array of the step reaches 1 GB, and XLA's
+    temporaries stay under it too.  The dense form wrote (Q, S, S) tensors,
+    260 TB each at this shape had XLA ever stored one."""
+    import re
+
+    import numpy as np
+
+    from lightgbm_tpu import objectives
+    from lightgbm_tpu.config import Config
+
+    n, queries, longest = 4_883_750, 15_480, 2048
+    rng = np.random.default_rng(34)
+    lens = np.clip(np.rint(rng.lognormal(
+        np.log(n / queries) - 0.32, 0.8, queries)), 1, longest).astype(int)
+    lens[0] = longest
+    qb = np.concatenate([[0], np.cumsum(lens)])
+    obj = objectives.LambdarankNDCG(Config(objective="lambdarank"))
+    obj.set_query(qb, rng.integers(0, 5, qb[-1]).astype(np.float32))
+    rows, lanes, pairs = obj.rank_work
+    assert lanes < 2 * rows and pairs <= 30 * lanes
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree.map(s, (jnp.zeros((rows,), jnp.float32), obj._layout,
+                            obj._inv_mdcg, obj._gain))
+    compiled = objectives._lambdarank_step.lower(
+        *args, None, None, sigmoid=1.0, truncation=30, norm=True,
+        pos_reg=0.0).compile()
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 1e9
+    widest = 0
+    for dtype, dims in re.findall(r"\b(f32|s32|u32|pred)\[([\d,]+)\]",
+                                  compiled.as_text()):
+        size = int(np.prod([int(d) for d in dims.split(",")]))
+        widest = max(widest, size * (1 if dtype == "pred" else 4))
+    assert 4 * rows <= widest < 1e9
+    text = compiled.as_text()
+    assert re.search(r"f32\[\d+,30,2048\]", text)
+    assert not re.search(r"\[\d+,2048,2048\]", text)
