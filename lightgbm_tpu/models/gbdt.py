@@ -8,9 +8,11 @@ score_updater.hpp.
 TPU-first structure: the boosting loop stays in Python (it is inherently
 sequential — one tree depends on the previous scores), but every O(N) step is
 a jitted device op: gradient computation, tree growth (ops/treegrow.py), and
-the score update, which is a pure gather `score += leaf_value[leaf_id]` since
+the score update `score += leaf_value[leaf_id]`, which needs no traversal since
 tree growth maintains per-row leaf ids for ALL rows (the partition-based fast
-path of ScoreUpdater::AddScore).
+path of ScoreUpdater::AddScore).  It is written as a compare-and-select over
+the tree's leaf values and not as a gather (`_add_leaf_scores`): a gather
+costs this chip 4 to 10 ns an index whatever the table's size.
 """
 
 from __future__ import annotations
@@ -114,9 +116,40 @@ def _dummy_tree() -> Tree:
     )
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _add_leaf_scores(score, leaf_value, leaf_id, shrinkage):
-    return score + leaf_value[leaf_id] * shrinkage
+# rows of one (8, 128) float32 tile: a 1-D array of whole tiles is the same
+# bytes on the chip as its (rows / 128, 128) reshape, so the reshape is free
+_SCORE_ROW_TILE = 1024
+
+
+@functools.partial(jax.jit, static_argnames=("col",), donate_argnums=(0,))
+def _add_leaf_scores(score, leaf_value, shrinkage, leaf_id, col=0):
+    """The score update of one tree: ``score`` (its column ``col`` where an
+    iteration grows K trees and the score is ``(N, K)``) plus
+    ``(leaf_value * shrinkage)[leaf_id]``, with no gather.  Each row compares
+    its leaf id with every leaf and sums what the compare selects: one term
+    is the leaf's value and the others are 0, so the sum is the float32 the
+    gather reads (``np.array_equal``; a leaf value of -0.0 adds as +0.0).
+    The product is rounded on the ``(L,)`` table before anything is selected,
+    as the eager form rounded it.
+
+    The leaves lie on the major axis and the rows on whole tiles, so XLA fuses
+    compare, select and sum into one pass over the rows and stores no
+    ``(L, N)`` array: 2.1 ms for 10.5M rows and 255 leaves on a v5e where the
+    gather took 86 to 102 ms (PERF.md section 6, PR 35).  It costs N x L
+    operations; the gather is the cheaper only beyond some 4,000 leaves,
+    where the tree's passes cost eighty times either.  ``score`` is
+    donated."""
+    with _profiling.phase_scope("gbdt.score_update"):
+        table = leaf_value * shrinkage
+        n = leaf_id.shape[0]
+        ids = jnp.pad(leaf_id, (0, -n % _SCORE_ROW_TILE)).reshape(-1, 128)
+        leaves = jnp.arange(table.shape[0], dtype=ids.dtype)[:, None, None]
+        picked = jnp.where(ids[None] == leaves, table[:, None, None],
+                           jnp.float32(0))
+        row_delta = jnp.sum(picked, axis=0).reshape(-1)[:n]
+        if score.ndim == 1:
+            return score + row_delta
+        return score.at[:, col].add(row_delta)
 
 
 def _f32_threshold_upper(t: np.ndarray) -> np.ndarray:
@@ -1176,12 +1209,8 @@ class GBDT:
                     fs[2] if fs else None,
                     **grow_kwargs,
                 )
-                with _profiling.phase_scope("gbdt.score_update"):
-                    row_delta = (arrays.leaf_value * shrinkage)[leaf_id]
-                    if k == 1:
-                        new_score = new_score + row_delta
-                    else:
-                        new_score = new_score.at[:, c].add(row_delta)
+                new_score = _add_leaf_scores(
+                    new_score, arrays.leaf_value, shrinkage, leaf_id, col=c)
                 arrays_all.append(arrays)
                 leaf_all.append(leaf_id)
             return (tuple(arrays_all), tuple(leaf_all), new_score, g, h,
@@ -1279,11 +1308,9 @@ class GBDT:
                     leaf_v = predict_leaf_arrays(
                         arrays, vs.bins_device, ts.missing_bin_pf_device,
                     )
-                    vals = (arrays.leaf_value * jnp.float32(shrinkage))[leaf_v]
-                    if k == 1:
-                        self._valid_scores[vi] = self._valid_scores[vi] + vals
-                    else:
-                        self._valid_scores[vi] = self._valid_scores[vi].at[:, c].add(vals)
+                    self._valid_scores[vi] = _add_leaf_scores(
+                        self._valid_scores[vi], arrays.leaf_value, shrinkage,
+                        leaf_v, col=c)
             self.iter_ += 1
             self._invalidate_pred_cache("train_one_iter")
             if self._report_finish_every_iter:
@@ -1508,6 +1535,7 @@ class GBDT:
                     arrays, leaf_id = grow_out
             else:
                 fs = self._forced_schedule()
+                # jaxlint: disable=R13 (what this loop donates is the score to its update, not a fused round's state; the strict grower's merge is a dispatch of its own by design)
                 grow_out = grow_tree(
                     ts.bins_device,
                     gc,
@@ -1589,14 +1617,16 @@ class GBDT:
                     jnp.asarray(all_const, dtype=bool), arrays.num_leaves <= 1
                 )
                 self._pending.append((arrays, shrinkage, linear_fit))
-                # eager operations: a cached primitive is not traced again,
-                # so the scope may be missing from these; the phase reduction
-                # files them under outside_grower by their module
-                with _profiling.phase_scope("gbdt.score_update"):
-                    if linear_fit is not None:
-                        row_delta = lin_pred * jnp.float32(shrinkage)
-                    else:
-                        row_delta = (arrays.leaf_value * jnp.float32(shrinkage))[leaf_id]
+                if linear_fit is None:
+                    self._score = _add_leaf_scores(
+                        self._score, arrays.leaf_value, shrinkage, leaf_id,
+                        col=c)
+                else:
+                    # a fitted model a leaf is no lookup.  Eager operations: a
+                    # cached primitive is not traced again, so a scope would
+                    # be missing from them; the phase reduction files them
+                    # under outside_grower by their module
+                    row_delta = lin_pred * jnp.float32(shrinkage)
                     if k == 1:
                         self._score = self._score + row_delta
                     else:
@@ -1607,15 +1637,17 @@ class GBDT:
                     leaf_v = predict_leaf_arrays(
                         arrays, vs.bins_device, ts.missing_bin_pf_device,
                     )
-                    if linear_fit is not None:
-                        from ..ops.linear import predict_linear_rows
+                    if linear_fit is None:
+                        self._valid_scores[vi] = _add_leaf_scores(
+                            self._valid_scores[vi], arrays.leaf_value,
+                            shrinkage, leaf_v, col=c)
+                        continue
+                    from ..ops.linear import predict_linear_rows
 
-                        vals = predict_linear_rows(
-                            vs.raw_device, leaf_v, coef, const, fidx, nf,
-                            arrays.leaf_value,
-                        ) * jnp.float32(shrinkage)
-                    else:
-                        vals = (arrays.leaf_value * jnp.float32(shrinkage))[leaf_v]
+                    vals = predict_linear_rows(
+                        vs.raw_device, leaf_v, coef, const, fidx, nf,
+                        arrays.leaf_value,
+                    ) * jnp.float32(shrinkage)
                     if k == 1:
                         self._valid_scores[vi] = self._valid_scores[vi] + vals
                     else:
@@ -1638,28 +1670,31 @@ class GBDT:
             pad = self.cfg.num_leaves - dev_leaf_vals.shape[0]
             if pad > 0:
                 dev_leaf_vals = jnp.concatenate([dev_leaf_vals, jnp.zeros(pad, jnp.float32)])
-            delta = dev_leaf_vals
-            if linear_fit is not None:
+            # the host tree's values carry the shrinkage already
+            if linear_fit is None:
+                self._score = _add_leaf_scores(
+                    self._score, dev_leaf_vals, 1.0, leaf_id, col=c)
+            else:
                 row_delta = lin_pred * jnp.float32(tree.shrinkage)
-            else:
-                row_delta = delta[leaf_id]
-            if k == 1:
-                self._score = self._score + row_delta
-            else:
-                self._score = self._score.at[:, c].add(row_delta)
+                if k == 1:
+                    self._score = self._score + row_delta
+                else:
+                    self._score = self._score.at[:, c].add(row_delta)
             self.models.append(tree)  # jaxlint: disable=L3 (append+version-bump protocol: the pack key carries (version, len) so a mid-build append is caught at insert; locking here would nest the models-property device flush under the pack lock — an L2)
             # valid scores
             for vi, vs in enumerate(self.valid_sets):
                 leaf_v = vs.predict_leaf_binned_tree(tree)
-                if linear_fit is not None:
-                    from ..ops.linear import predict_linear_rows
+                if linear_fit is None:
+                    self._valid_scores[vi] = _add_leaf_scores(
+                        self._valid_scores[vi], dev_leaf_vals, 1.0, leaf_v,
+                        col=c)
+                    continue
+                from ..ops.linear import predict_linear_rows
 
-                    vals = predict_linear_rows(
-                        vs.raw_device, jnp.asarray(leaf_v), coef, const, fidx, nf,
-                        arrays.leaf_value,
-                    ) * jnp.float32(tree.shrinkage)
-                else:
-                    vals = jnp.asarray(tree.leaf_value, jnp.float32)[leaf_v]
+                vals = predict_linear_rows(
+                    vs.raw_device, jnp.asarray(leaf_v), coef, const, fidx, nf,
+                    arrays.leaf_value,
+                ) * jnp.float32(tree.shrinkage)
                 if k == 1:
                     self._valid_scores[vi] = self._valid_scores[vi] + vals
                 else:
