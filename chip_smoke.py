@@ -39,6 +39,7 @@ import numpy as np
 import lightgbm_tpu as lgb
 from lightgbm_tpu.obs import metrics as obs_metrics
 from lightgbm_tpu.obs import server as obs_server
+from lightgbm_tpu.ops import split as split_mod
 from lightgbm_tpu.ops.hist_pallas import (histogram_pallas_multi,
                                           histogram_pallas_multi_quantized,
                                           recommended_leaf_tile)
@@ -61,6 +62,20 @@ WIDE_FEATURES = 2000
 # widths).  The floors catch a broken grower, not a drifting one.
 NARROW_AUC_FLOOR = 0.85
 WIDE_AUC_FLOOR = 0.75
+# the categorical leg: (integer columns with missing values, levels of each
+# categorical column, parameters).  Criteo's 13 + 26 (its published level
+# counts capped at 255 bins) at the benchmark cell's settings, where PR 36
+# found the fault the leg guards against, and a narrower table on the chip's
+# defaults; other column counts compile to other programs
+CLICK_ROWS = 500_000
+CLICK_CELL = dict(tree_growth_mode="rounds", hist_precision="f32",
+                  use_quantized_grad=False, fused_training=False)
+CLICK_SHAPES = {
+    "13+26": (13, (255, 255, 255, 255, 255, 24, 255, 255, 3, 255, 255, 255,
+                   255, 27, 255, 255, 10, 255, 255, 4, 255, 18, 15, 255, 105,
+                   255), CLICK_CELL),
+    "4+8": (4, (3, 4, 24, 105, 255, 27, 10, 255), {}),
+}
 # tolerances of __graft_entry__'s sharded-vs-serial tree comparison
 LEAF_RTOL = LEAF_ATOL = 2e-3
 
@@ -470,6 +485,104 @@ def leg_probes(dispatches: int = 500, pulls: int = 50, matmul_dim: int = 4096,
 
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# leg 7: categorical columns and missing values, every leaf against its rows
+# ---------------------------------------------------------------------------
+
+def click_data(n_rows: int, n_int: int, levels, seed: int = 36):
+    """Integer counts with missing values, then categorical ids with Zipf
+    frequencies; the label from a seeded effect a column and level."""
+    rng = np.random.RandomState(seed)
+    X = np.empty((n_rows, n_int + len(levels)), np.float32)
+    z = np.zeros(n_rows)
+    for j in range(n_int):
+        X[:, j] = np.floor(np.exp(1.5 * rng.randn(n_rows)))
+        z += rng.randn() * np.log1p(X[:, j])
+        X[rng.rand(n_rows) < 0.15 * (j % 5), j] = np.nan
+    for j, lv in enumerate(levels, start=n_int):
+        p = 1.0 / np.arange(1, lv + 1) ** 1.1
+        X[:, j] = rng.choice(lv, n_rows, p=p / p.sum())
+        z += (0.5 * rng.randn(lv))[X[:, j].astype(np.int64)]
+    y = (z + rng.randn(n_rows) > np.quantile(z, 0.744)).astype(np.float64)
+    return X, y
+
+
+def leaves_off_their_rows(bst, X) -> int:
+    """Leaves whose tracked count is not the number of rows that the tree's
+    own rules send there (``Tree.predict_leaf_batch``, a host walk).  The
+    grower counts a leaf from its histogram sums and routes its rows by the
+    split it stores, so the two part wherever the stored split is not the
+    one that was counted."""
+    off = 0
+    for t in bst._gbdt.models:
+        nl = int(t.num_leaves)
+        rows = np.bincount(t.predict_leaf_batch(X), minlength=nl)
+        off += int((rows != np.asarray(t.leaf_count)[:nl]).sum())
+    return off
+
+
+def cat_mask_by_gather(rank_asc, rank_desc, slot, v, best_t):
+    """``split.winner_cat_mask`` as it stood until PR 36: equal to it on
+    every CPU path, compiled wrongly by XLA:TPU under libtpu 0.0.34 inside
+    the growers' vmap.  Kept so that a later libtpu can be read against it."""
+    bins_idx = jnp.arange(rank_asc.shape[1], dtype=jnp.int32)
+    return jnp.where(v == 0, bins_idx == best_t,
+                     jnp.where(v == 1, rank_asc[slot] <= best_t,
+                               rank_desc[slot] <= best_t))
+
+
+def leg_categorical(n_rows: int = CLICK_ROWS, shapes=CLICK_SHAPES,
+                    rounds: int = 4, num_leaves: int = 255, **overrides):
+    """Trees over categorical and integer-with-missing columns: every family
+    of split is taken and every leaf holds the rows it counted.  Then the
+    first shape again with the faulty form planted (reported, not asserted:
+    0 says this installation compiles the old form right)."""
+    out = {}
+    for name, (n_int, levels, cell) in shapes.items():
+        X, y = click_data(n_rows, n_int, levels)
+        cats = list(range(n_int, n_int + len(levels)))
+        params = dict(objective="binary", num_leaves=num_leaves, max_bin=255,
+                      learning_rate=0.1, min_data_in_leaf=1,
+                      min_sum_hessian_in_leaf=20.0, verbosity=-1,
+                      **dict(cell, **overrides))
+
+        def grow():
+            train = lgb.Dataset(X, label=y, categorical_feature=cats)
+            return timed_train(params, train, rounds)
+
+        bst, times, _ = grow()
+        trees = bst._gbdt.models
+        nodes = sum(int(t.num_leaves) - 1 for t in trees)
+        cat_nodes = sum(int(t.num_cat) for t in trees)
+        left_by_default = sum(
+            int((t.default_left()[:t.num_leaves - 1]
+                 & ~t.is_categorical_node()[:t.num_leaves - 1]).sum())
+            for t in trees)
+        off = leaves_off_their_rows(bst, X)
+        assert off == 0, (name, off)
+        assert 0 < cat_nodes < nodes and left_by_default > 0, (
+            name, nodes, cat_nodes, left_by_default)
+        out[name] = dict(times, rows=n_rows, rounds=rounds, nodes=nodes,
+                         cat_nodes=cat_nodes, left_by_default=left_by_default,
+                         leaves_off_their_rows=off, flags=booster_flags(bst))
+        if "gather_form_leaves_off" not in out:  # once, on the first shape
+            sound = split_mod.winner_cat_mask
+            split_mod.winner_cat_mask = cat_mask_by_gather
+            jax.clear_caches()
+            try:
+                out["gather_form_leaves_off"] = leaves_off_their_rows(
+                    grow()[0], X)
+            finally:
+                split_mod.winner_cat_mask = sound
+                jax.clear_caches()
+        say(f"categorical {name} x{n_rows}: {nodes} nodes, {cat_nodes} "
+            f"categorical, {left_by_default} missing-left, every leaf holds "
+            f"its rows; first iter {times['first_iter_s']}s")
+    say(f"categorical: the form of before PR 36 puts "
+        f"{out['gather_form_leaves_off']} leaves off their rows")
+    return out
+
+
 def check_no_fallback(legs: dict) -> None:
     """The run passed on the device and the kernels it names, or it did not
     pass: any fired net is a failure."""
@@ -477,10 +590,12 @@ def check_no_fallback(legs: dict) -> None:
         assert degrade.disabled_reason(key) is None, (
             key, degrade.disabled_reason(key))
     assert obs_metrics.counter("degrade_disabled_total").value == 0
-    for name, leg in legs.items():
-        flags = leg.get("flags") if isinstance(leg, dict) else None
-        if flags is None:
-            continue
+    flagged = {name: leg["flags"] for name, leg in legs.items()
+               if isinstance(leg, dict) and "flags" in leg}
+    flagged.update({f"categorical {name}": shape["flags"]
+                    for name, shape in legs.get("categorical", {}).items()
+                    if isinstance(shape, dict)})
+    for name, flags in flagged.items():
         assert flags["on_tpu"], (name, flags)
         assert flags["use_fast"] or flags["use_fast_dp"], (name, flags)
         assert not flags["fused_disabled"], (name, flags)
@@ -521,6 +636,7 @@ def main() -> int:
         legs["multichip"] = {"skipped": f"{jax.device_count()} device"}
         say("multichip: skipped, 1 device")
     legs["probes"] = leg_probes()
+    legs["categorical"] = leg_categorical()
     check_no_fallback(legs)
 
     report = {"versions": vers, "cache_dir": cache_dir,

@@ -338,6 +338,11 @@ class GBDT:
                     self._count_hist_passes(int(arrays.hist_passes),
                                             int(arrays.hist_blocks), tree)
                 self._count_rank_work()
+                # the tree's nodes, and those that split on a categorical
+                # column: from the host tree the flush has just built
+                _obs.counter("train_split_nodes_total").inc(
+                    tree.num_leaves - 1)
+                _obs.counter("train_cat_split_nodes_total").inc(tree.num_cat)
 
     def _count_hist_passes(self, passes: int, blocks: int, tree: Tree) -> None:
         """What the tree's histogram passes read against what the tree
@@ -473,24 +478,7 @@ class GBDT:
                 and getattr(train_set, "position", None) is not None
             ):
                 self.objective.set_positions(train_set.position)
-        self._split_params = SplitParams(
-            lambda_l1=self.cfg.lambda_l1,
-            lambda_l2=self.cfg.lambda_l2,
-            min_data_in_leaf=self.cfg.min_data_in_leaf,
-            min_sum_hessian_in_leaf=self.cfg.min_sum_hessian_in_leaf,
-            min_gain_to_split=self.cfg.min_gain_to_split,
-            max_delta_step=self.cfg.max_delta_step,
-            path_smooth=self.cfg.path_smooth,
-            cat_l2=self.cfg.cat_l2,
-            cat_smooth=self.cfg.cat_smooth,
-            max_cat_threshold=self.cfg.max_cat_threshold,
-            max_cat_to_onehot=self.cfg.max_cat_to_onehot,
-            feature_fraction_bynode=self.cfg.feature_fraction_bynode,
-            extra_trees=bool(self.cfg.extra_trees),
-            monotone_penalty=self.cfg.monotone_penalty,
-            cegb_tradeoff=self.cfg.cegb_tradeoff,
-            cegb_penalty_split=self.cfg.cegb_penalty_split,
-        )
+        self._split_params = self._make_split_params()
         cat_mask = np.asarray(self.binner.categorical_mask)
         self._allowed_features = jnp.ones(cat_mask.shape, dtype=bool)
         # feature_pre_filter (reference: DatasetLoader — ignore features that
@@ -552,6 +540,10 @@ class GBDT:
         # pass None when no categorical features so the all-numerical jit
         # graph skips the categorical candidate evaluation entirely
         self._categorical_mask = jnp.asarray(cat_mask) if cat_mask.any() else None
+        _obs.gauge("cat_features").set(int(cat_mask.sum()))
+        _obs.gauge("cat_bins_longest").set(max(
+            (m.num_bins for m in self.binner.mappers if m.is_categorical),
+            default=0))
         # monotone constraints (reference: monotone_constraints.hpp, "basic")
         f = train_set.num_feature()
         mc = list(self.cfg.monotone_constraints or [])
@@ -805,10 +797,12 @@ class GBDT:
                         process_local=self._pre_partition,
                     )
 
-    def reset_split_params(self) -> None:
-        """Refresh jit-static split hyperparams after a config mutation
-        (reference: GBDT::ResetConfig via reset_parameter callbacks)."""
-        self._split_params = SplitParams(
+    def _make_split_params(self) -> SplitParams:
+        """The jit-static split hyperparameters, from the configuration and
+        the binner: at set-up and after every config mutation alike, so that
+        a reset keeps the categorical search on its own columns."""
+        cat = np.flatnonzero(np.asarray(self.binner.categorical_mask))
+        return SplitParams(
             lambda_l1=self.cfg.lambda_l1,
             lambda_l2=self.cfg.lambda_l2,
             min_data_in_leaf=self.cfg.min_data_in_leaf,
@@ -825,7 +819,13 @@ class GBDT:
             monotone_penalty=self.cfg.monotone_penalty,
             cegb_tradeoff=self.cfg.cegb_tradeoff,
             cegb_penalty_split=self.cfg.cegb_penalty_split,
+            cat_features=tuple(int(c) for c in cat) if cat.size else None,
         )
+
+    def reset_split_params(self) -> None:
+        """Refresh jit-static split hyperparams after a config mutation
+        (reference: GBDT::ResetConfig via reset_parameter callbacks)."""
+        self._split_params = self._make_split_params()
         # the fused step bakes SplitParams plus several other config fields
         # as traced constants — but learning_rate is a runtime argument, so
         # the common reset_parameter(learning_rate=...) schedule must NOT

@@ -20,10 +20,13 @@ constraints: counts >= min_data_in_leaf, hess >= min_sum_hessian_in_leaf,
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ..utils.profiling import phase_scope
 
 KEPSILON = 1e-15  # reference: feature_histogram.hpp kEpsilon added to hessians
 KMIN_SCORE = -1e30
@@ -42,6 +45,16 @@ class SplitParams(NamedTuple):
     cat_smooth: float = 10.0
     max_cat_threshold: int = 32
     max_cat_to_onehot: int = 4
+    # the categorical columns' indices: the categorical candidates are
+    # computed for these columns alone.  models/gbdt.py states them from the
+    # binner at set-up and after every reset (``_make_split_params``).  None
+    # means every column the mask marks at run time, computed over all of
+    # them: kept only for a grower whose columns are a shard or an elected
+    # subset (ops/treegrow.py's feature and voting modes clear the field)
+    # and for a direct caller of these functions.  Part of the jit key like
+    # every field here, so a data set without categorical columns compiles
+    # the program it always did
+    cat_features: Optional[tuple] = None
     # node-level sampling (reference: ColSampler bynode / extra_trees)
     feature_fraction_bynode: float = 1.0
     extra_trees: bool = False
@@ -277,91 +290,10 @@ def gain_plane(
 
     if categorical_mask is not None:
         gain = jnp.where(categorical_mask[:, None], KMIN_SCORE, gain)
-
-    # ------------------------------------------------------------------
-    # Categorical candidates (reference: feature_histogram.hpp ->
-    # FindBestThresholdCategoricalInner).  Two families:
-    #   one-hot   (<= max_cat_to_onehot used bins): each bin alone vs rest;
-    #   many-vs-many: bins sorted by sum_g/(sum_h+cat_smooth), prefix of the
-    #     sorted order (scanned from both ends, bounded by max_cat_threshold)
-    #     goes left.  cat_l2 is added to lambda_l2 in the gain.
-    # The missing bin is excluded from left subsets (NaN/unseen -> right),
-    # matching Tree::CategoricalDecision's not-in-bitset => right.
-    # ------------------------------------------------------------------
-    if categorical_mask is not None:
-        l2c = params.lambda_l2 + params.cat_l2
-
-        def cgain(g_, h_):
-            return _gain_l2(g_, h_, params.lambda_l1, l2c, params.max_delta_step)
-
-        gain_parent_cat = cgain(parent_g, parent_h)
-        used = (hist_nm[2] > 0) & ~is_missing_bin  # (F, B)
-        num_used = jnp.sum(used, axis=1)  # (F,)
-        ratio = jnp.where(
-            used,
-            hist_nm[0] / (hist_nm[1] + params.cat_smooth),
-            jnp.inf,
-        )
-
-        def cat_ok(l_c, r_c, l_h, r_h):
-            return (
-                (l_c >= params.min_data_in_leaf)
-                & (r_c >= params.min_data_in_leaf)
-                & (l_h >= params.min_sum_hessian_in_leaf)
-                & (r_h >= params.min_sum_hessian_in_leaf)
-            )
-
-        def eval_sorted(keys):
-            order = jnp.argsort(keys, axis=1)  # (F, B) bin ids, unused last
-            rank = jnp.argsort(order, axis=1)  # rank of each bin in the order
-            sh = jnp.take_along_axis(hist_nm, order[None], axis=2)  # (3, F, B)
-            cum = jnp.cumsum(sh, axis=2)  # prefix stats; index k-1 = prefix len k
-            k_len = bins_idx[None, :] + 1  # (1, B) prefix length at index b
-            lg_, lh_, lc_ = cum[0], cum[1], cum[2]
-            rg_, rh_, rc_ = parent_g - lg_, parent_h - lh_, parent_count - lc_
-            # reference additionally caps each scan direction at half the
-            # used bins ((used_bin + 1) / 2 in
-            # FindBestThresholdCategoricalInner), so both-direction scans
-            # never consider the same partition twice.
-            ok = (
-                (k_len <= params.max_cat_threshold)
-                & (k_len <= (num_used[:, None] + 1) // 2)
-                & (k_len < num_used[:, None])
-                & cat_ok(lc_, rc_, lh_, rh_)
-            )
-            g_ = cgain(lg_, lh_) + cgain(rg_, rh_) - gain_parent_cat
-            g_ = jnp.where(ok, g_, KMIN_SCORE)
-            return g_, rank, (lg_, lh_, lc_)
-
-        gain_asc, rank_asc, st_asc = eval_sorted(ratio)
-        gain_desc, rank_desc, st_desc = eval_sorted(
-            jnp.where(used, -ratio, jnp.inf)
-        )
-        # one-hot: bin b alone goes left
-        oh_l = hist_nm  # (3, F, B)
-        oh_ok = (
-            used
-            & cat_ok(
-                oh_l[2], parent_count - oh_l[2],
-                oh_l[1], parent_h - oh_l[1],
-            )
-        )
-        gain_oh = (
-            cgain(oh_l[0], oh_l[1])
-            + cgain(parent_g - oh_l[0], parent_h - oh_l[1])
-            - gain_parent_cat
-        )
-        gain_oh = jnp.where(oh_ok, gain_oh, KMIN_SCORE)
-
-        onehot_mode = (num_used <= params.max_cat_to_onehot)[:, None]  # (F, 1)
-        gain_mvm = jnp.maximum(gain_asc, gain_desc)
-        variant_mvm = jnp.where(gain_desc > gain_asc, 2, 1)
-        gain_cat = jnp.where(onehot_mode, gain_oh, gain_mvm)
-        variant = jnp.where(onehot_mode, 0, variant_mvm)  # (F, B)
-        cat_col = categorical_mask[:, None]
-        if feature_mask is not None:
-            cat_col = cat_col & feature_mask[:, None]
-        gain = jnp.where(cat_col, gain_cat, gain)
+        with phase_scope("grow.cat_search"):
+            gain, cat_ctx = _categorical_candidates(
+                gain, hist_nm, is_missing_bin, parent_g, parent_h,
+                parent_count, params, categorical_mask, feature_mask)
 
     # ------------------------------------------------------------------
     # gain adjustments applied BEFORE the min_gain_to_split gate, matching
@@ -410,17 +342,163 @@ def gain_plane(
         categorical_mask=categorical_mask,
     )
     if categorical_mask is not None:
-        ctx.update(
-            variant=variant, rank_asc=rank_asc, rank_desc=rank_desc,
-            st_asc=st_asc, st_desc=st_desc, oh_l=oh_l,
-        )
+        ctx.update(cat_ctx)
     return gain, ctx
+
+
+def rank_in_order(keys: jnp.ndarray) -> jnp.ndarray:
+    """``argsort(argsort(keys, axis=-1), axis=-1)`` of a stable ascending
+    sort (equal keys in index order, NaN last), with no sort: an entry's
+    rank is the count of entries that come before it, a ``(B, B)`` compare a
+    row.  At 256 keys a row the compares are cheap on the VPU where XLA's
+    sort on the TPU is its slowest operation (PERF.md section 6, PR 36)."""
+    b = keys.shape[-1]
+    mine, other = keys[..., :, None], keys[..., None, :]
+    nan_mine, nan_other = jnp.isnan(mine), jnp.isnan(other)
+    less = (other < mine) | (nan_mine & ~nan_other)
+    equal = (other == mine) | (nan_mine & nan_other)
+    i = jnp.arange(b, dtype=jnp.int32)
+    earlier = i[None, :] < i[:, None]  # [mine, other]: other's index first
+    return jnp.sum(less | (equal & earlier), axis=-1, dtype=jnp.int32)
+
+
+def _categorical_candidates(gain, hist_nm, is_missing_bin, parent_g, parent_h,
+                            parent_count, params: SplitParams,
+                            categorical_mask, feature_mask):
+    """The categorical candidates of one leaf, laid over ``gain`` in the
+    categorical columns (reference: feature_histogram.hpp ->
+    FindBestThresholdCategoricalInner).  Two families:
+      one-hot   (<= max_cat_to_onehot used bins): each bin alone vs rest;
+      many-vs-many: bins ordered by sum_g/(sum_h+cat_smooth), a prefix of
+        the order (scanned from both ends, bounded by max_cat_threshold)
+        goes left.  cat_l2 is added to lambda_l2 in the gain.
+    The missing bin is excluded from left subsets (NaN/unseen -> right),
+    matching Tree::CategoricalDecision's not-in-bitset => right.
+
+    Computed over ``params.cat_features`` alone where the caller stated them
+    (26 of Criteo's 39 columns), and with no sort: a bin's place in either
+    order is :func:`rank_in_order`, the bins are put in that order by a
+    one-hot product (exact: one term a sum), and the prefix sums are the
+    cumulative sums they always were, so every candidate is the old one bit
+    for bit.  Returns the plane and what ``select_from_plane`` needs."""
+    _, f, b = hist_nm.shape
+    bins_idx = jnp.arange(b, dtype=jnp.int32)
+    cols = params.cat_features
+    if cols is None:
+        slot_of = None  # a column is its own slot
+    else:
+        cols = list(cols)  # static: a tuple of Python ints
+        slot_of = np.zeros((f,), np.int32)
+        slot_of[cols] = np.arange(len(cols), dtype=np.int32)
+        hist_nm = hist_nm[:, cols, :]  # (3, Fc, B)
+        is_missing_bin = is_missing_bin[cols, :]
+    l2c = params.lambda_l2 + params.cat_l2
+
+    def cgain(g_, h_):
+        return _gain_l2(g_, h_, params.lambda_l1, l2c, params.max_delta_step)
+
+    gain_parent_cat = cgain(parent_g, parent_h)
+    used = (hist_nm[2] > 0) & ~is_missing_bin  # (Fc, B)
+    num_used = jnp.sum(used, axis=1)  # (Fc,)
+    ratio = jnp.where(
+        used,
+        hist_nm[0] / (hist_nm[1] + params.cat_smooth),
+        jnp.inf,
+    )
+
+    def cat_ok(l_c, r_c, l_h, r_h):
+        return (
+            (l_c >= params.min_data_in_leaf)
+            & (r_c >= params.min_data_in_leaf)
+            & (l_h >= params.min_sum_hessian_in_leaf)
+            & (r_h >= params.min_sum_hessian_in_leaf)
+        )
+
+    def eval_sorted(keys):
+        rank = rank_in_order(keys)  # (Fc, B): unused bins last, in bin order
+        at = rank[:, None, :] == bins_idx[None, :, None]  # (Fc, place, bin)
+        sh = jnp.sum(jnp.where(at[None], hist_nm[:, :, None, :], 0.0), axis=3)
+        cum = jnp.cumsum(sh, axis=2)  # prefix stats; index k-1 = prefix len k
+        k_len = bins_idx[None, :] + 1  # (1, B) prefix length at index b
+        lg_, lh_, lc_ = cum[0], cum[1], cum[2]
+        rg_, rh_, rc_ = parent_g - lg_, parent_h - lh_, parent_count - lc_
+        # reference additionally caps each scan direction at half the
+        # used bins ((used_bin + 1) / 2 in
+        # FindBestThresholdCategoricalInner), so both-direction scans
+        # never consider the same partition twice.
+        ok = (
+            (k_len <= params.max_cat_threshold)
+            & (k_len <= (num_used[:, None] + 1) // 2)
+            & (k_len < num_used[:, None])
+            & cat_ok(lc_, rc_, lh_, rh_)
+        )
+        g_ = cgain(lg_, lh_) + cgain(rg_, rh_) - gain_parent_cat
+        g_ = jnp.where(ok, g_, KMIN_SCORE)
+        return g_, rank, (lg_, lh_, lc_)
+
+    gain_asc, rank_asc, st_asc = eval_sorted(ratio)
+    gain_desc, rank_desc, st_desc = eval_sorted(
+        jnp.where(used, -ratio, jnp.inf)
+    )
+    # one-hot: bin b alone goes left
+    oh_l = hist_nm  # (3, Fc, B)
+    oh_ok = (
+        used
+        & cat_ok(
+            oh_l[2], parent_count - oh_l[2],
+            oh_l[1], parent_h - oh_l[1],
+        )
+    )
+    gain_oh = (
+        cgain(oh_l[0], oh_l[1])
+        + cgain(parent_g - oh_l[0], parent_h - oh_l[1])
+        - gain_parent_cat
+    )
+    gain_oh = jnp.where(oh_ok, gain_oh, KMIN_SCORE)
+
+    onehot_mode = (num_used <= params.max_cat_to_onehot)[:, None]  # (Fc, 1)
+    gain_mvm = jnp.maximum(gain_asc, gain_desc)
+    variant_mvm = jnp.where(gain_desc > gain_asc, 2, 1)
+    gain_cat = jnp.where(onehot_mode, gain_oh, gain_mvm)
+    variant = jnp.where(onehot_mode, 0, variant_mvm)  # (Fc, B)
+    cat_col = categorical_mask[:, None]
+    if feature_mask is not None:
+        cat_col = cat_col & feature_mask[:, None]
+    if slot_of is not None:
+        gain_cat = gain_cat[slot_of]  # (F, B); a numerical row is masked out
+    gain = jnp.where(cat_col, gain_cat, gain)
+    return gain, dict(
+        variant=variant, rank_asc=rank_asc, rank_desc=rank_desc,
+        st_asc=st_asc, st_desc=st_desc, oh_l=oh_l, cat_slot=slot_of,
+    )
+
+
+def winner_cat_mask(rank_asc, rank_desc, slot, v, best_t):
+    """The winning categorical candidate's bins-going-left, ``(B,)``: bin
+    ``best_t`` alone (``v`` 0), or the bins whose place in the ascending
+    (1) or descending (2) order is at most ``best_t``.
+
+    The column's two rank rows are taken by compare-and-select over the
+    columns and the family by an index into the three stacked masks, as
+    ``select_from_plane`` takes the sums.  Not ``rank[slot]`` and a ``where``
+    on ``v``: under the growers' vmap XLA:TPU (libtpu 0.0.34) fused those
+    gathers and the scalar select into the mask's consumer and read the
+    descending order's row where ``v`` said ascending, so rows were routed by
+    one order and counted by the other (PERF.md section 6, PR 36; no CPU
+    path shows it).  ``chip_smoke.leg_categorical`` holds this form to every
+    leaf's rows on the chip and keeps the faulty one as a reproducer."""
+    bins_idx = jnp.arange(rank_asc.shape[1], dtype=jnp.int32)
+    mine = jnp.arange(rank_asc.shape[0], dtype=jnp.int32)[:, None] == slot
+    row_asc = jnp.sum(jnp.where(mine, rank_asc, 0), axis=0)
+    row_desc = jnp.sum(jnp.where(mine, rank_desc, 0), axis=0)
+    masks = jnp.stack([bins_idx == best_t, row_asc <= best_t,
+                       row_desc <= best_t])
+    return masks[v]
 
 
 def select_from_plane(gain: jnp.ndarray, ctx: dict) -> BestSplit:
     """Materialize the argmax candidate of a gain plane into a BestSplit."""
     f, b = gain.shape
-    bins_idx = jnp.arange(b, dtype=jnp.int32)
     use_left = ctx["use_left"]
     stats_l, stats_r = ctx["stats_l"], ctx["stats_r"]
     parent_g, parent_h, parent_count = (
@@ -448,15 +526,16 @@ def select_from_plane(gain: jnp.ndarray, ctx: dict) -> BestSplit:
         variant, rank_asc, rank_desc = ctx["variant"], ctx["rank_asc"], ctx["rank_desc"]
         st_asc, st_desc, oh_l = ctx["st_asc"], ctx["st_desc"], ctx["oh_l"]
         best_is_cat = categorical_mask[best_f]
-        v = variant.reshape(-1)[best]
-        mask_oh = bins_idx == best_t
-        mask_asc = rank_asc[best_f] <= best_t
-        mask_desc = rank_desc[best_f] <= best_t
+        # the categorical arrays hold a row a categorical column where the
+        # caller stated the columns (SplitParams.cat_features)
+        slot = best_f if ctx["cat_slot"] is None else jnp.asarray(
+            ctx["cat_slot"])[best_f]
+        at = slot * b + best_t
+        v = variant.reshape(-1)[at]
         best_cat_mask = jnp.where(
             best_is_cat,
-            jnp.where(v == 0, mask_oh, jnp.where(v == 1, mask_asc, mask_desc)),
-            jnp.zeros((b,), bool),
-        )
+            winner_cat_mask(rank_asc, rank_desc, slot, v, best_t),
+            jnp.zeros((b,), bool))
 
         def pick_cat():
             stats = [
@@ -464,9 +543,9 @@ def select_from_plane(gain: jnp.ndarray, ctx: dict) -> BestSplit:
                 st_asc,
                 st_desc,
             ]
-            g_ = jnp.stack([s[0].reshape(-1)[best] for s in stats])[v]
-            h_ = jnp.stack([s[1].reshape(-1)[best] for s in stats])[v]
-            c_ = jnp.stack([s[2].reshape(-1)[best] for s in stats])[v]
+            g_ = jnp.stack([s[0].reshape(-1)[at] for s in stats])[v]
+            h_ = jnp.stack([s[1].reshape(-1)[at] for s in stats])[v]
+            c_ = jnp.stack([s[2].reshape(-1)[at] for s in stats])[v]
             return g_, h_, c_
 
         cg, ch, cc = pick_cat()

@@ -222,6 +222,9 @@ def grow_tree(
     hess = hess.astype(jnp.float32) * sample_weight
     L = num_leaves
     mode = parallel_mode if axis_name is not None else "serial"
+    if mode in ("feature", "voting"):  # a shard or an elected subset of the
+        # columns is searched: the stated indices are not its columns'
+        params = params._replace(cat_features=None)
     # CEGB lazy per-(row, feature) fetch charges (reference:
     # cost_effective_gradient_boosting.hpp — DeltaGain subtracts
     # penalty_feature_lazy[f] * #uncharged rows in the leaf; rows charge

@@ -199,8 +199,18 @@ def partition_rows(
     rows x 8 slots on a v5e, the HBM's rate, and 0.09 at 400k x 10 (PERF.md
     section 6, PR 31; tests/test_hist_pallas_mosaic.py holds the compile to
     that).  The (F, N) shadow with (N,) ids before it took a fusion a slot
-    on one sublane in eight: 4.8 and 0.20 ms."""
+    on one sublane in eight: 4.8 and 0.20 ms.
+
+    A categorical slot reads its split's bins-going-left as a bitset
+    (reference: Common::FindInBitset, elementwise): the (B,) mask packed
+    into 32-bit words once a slot, a row's word chosen by compare-and-select
+    on ``bin >> 5``, its bit by shift and mask.  No per-row gather: the
+    lookup ``cat_mask[leaf][bin]`` that stood here was a gather of N rows a
+    slot, whether or not the slot's split was categorical, and broke the one
+    fusion (PERF.md section 6, PR 36 has what it cost at 15.3M rows)."""
     leaf_id = lid
+    if categorical:
+        words = pack_bitset(s.cat_mask)  # (L, B/32) u32
     for r in range(leaf_tile):
         leaf_r = inv_rank[r]
         live = accept[leaf_r]  # rank r admitted?
@@ -211,10 +221,34 @@ def partition_rows(
         gl = jnp.where(miss_r, s.default_left[leaf_r],
                        fcol <= s.threshold_bin[leaf_r])
         if categorical:
-            gl = jnp.where(s.is_cat[leaf_r], s.cat_mask[leaf_r][fcol], gl)
+            gl = jnp.where(s.is_cat[leaf_r],
+                           in_bitset(words[leaf_r], fcol), gl)
         sel = live & (lid == leaf_r)
         leaf_id = jnp.where(sel & ~gl, right_of[leaf_r], leaf_id)
     return leaf_id
+
+
+def pack_bitset(mask: jnp.ndarray) -> jnp.ndarray:
+    """(..., B) bool -> (..., ceil(B / 32)) uint32, bit ``b & 31`` of word
+    ``b >> 5`` set where ``mask[..., b]``."""
+    b = mask.shape[-1]
+    n_words = -(-b // 32)
+    m = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, n_words * 32 - b)])
+    m = m.reshape(mask.shape[:-1] + (n_words, 32)).astype(jnp.uint32)
+    return jnp.sum(m << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                   dtype=jnp.uint32)
+
+
+def in_bitset(words: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Whether bit ``idx`` of the (W,) uint32 ``words`` is set, for every
+    element of the int32 ``idx`` (0 <= idx < 32 W): the word by an unrolled
+    compare-and-select on ``idx >> 5`` (W is 8 at 256 bins), the bit by shift
+    and mask."""
+    hi = idx >> 5
+    word = jnp.zeros(idx.shape, jnp.uint32)
+    for w in range(words.shape[0]):
+        word = jnp.where(hi == w, words[w], word)
+    return ((word >> (idx & 31).astype(jnp.uint32)) & 1).astype(bool)
 
 
 @functools.partial(

@@ -53,6 +53,9 @@ DEVICE_PHASES = (
     "hist.unpack",  # slice, hi + lo, transpose; unbundle and psum
     "grow.sibling",  # parent gather, subtraction, scatter into state.hist
     "grow.split_search",  # _batched_best and the merge into state.best
+    "grow.cat_search",  # inside it: the categorical candidates of the
+    # categorical columns (ops/split.py); an operation is booked under the
+    # innermost scope, so the two rows do not overlap
     "grow.leaf_values",  # renewal or leaf_output, the final TreeArrays
     "gbdt.score_update",  # row_delta and the score add
 )
@@ -282,8 +285,11 @@ def _instruction_name(event_name: str) -> str:
 
 
 def phase_of(op_name: str) -> Optional[str]:
-    """The innermost catalogued component of an ``op_name``."""
+    """The innermost catalogued component of an ``op_name``.  A scope entered
+    under a transformation is written inside it, ``vmap(grow.cat_search)``:
+    the search of a round's children is one vmapped call."""
     for part in reversed(op_name.split("/")):
+        part = part.rsplit("(", 1)[-1].rstrip(")")
         if part in _PHASE_SET:
             return part
     return None

@@ -5,6 +5,7 @@ executes them: tests/test_hist_strategies.py only eval_shapes)."""
 
 import json
 
+import jax
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
@@ -39,6 +40,7 @@ def test_last_stdout_line_is_the_contract_object(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "leg_predict_serve", lambda bst: {})
     monkeypatch.setattr(chip_smoke, "leg_multichip", lambda *a: {})
     monkeypatch.setattr(chip_smoke, "leg_probes", lambda: {})
+    monkeypatch.setattr(chip_smoke, "leg_categorical", lambda: {})
     monkeypatch.setattr(chip_smoke, "check_no_fallback", lambda legs: None)
     assert chip_smoke.main() == 0
     lines = capsys.readouterr().out.splitlines()
@@ -92,6 +94,38 @@ def test_probes_leg():
                                 matmul_steps=4)
     assert set(out) >= {"dispatch_enqueue_us", "ready_scalar_pull_us",
                         "block_until_ready_honest"}
+
+
+def test_categorical_leg():
+    """Both kinds of column at toy size: every leaf holds the rows it
+    counted, and so it does under the form of before PR 36, which is wrong
+    only as XLA:TPU compiled it."""
+    shapes = {"2+4": (2, (3, 24, 105, 255), chip_smoke.CLICK_CELL),
+              "3+2": (3, (4, 255), {})}
+    out = chip_smoke.leg_categorical(n_rows=8000, shapes=shapes, rounds=3,
+                                     num_leaves=15, **ROUNDS)
+    assert set(out) == {"2+4", "3+2", "gather_form_leaves_off"}
+    assert out["gather_form_leaves_off"] == 0
+    assert out["2+4"]["leaves_off_their_rows"] == 0
+    assert not out["2+4"]["flags"]["fused_built"]
+    json.dumps(out)
+
+
+def test_categorical_leg_fails_where_rows_go_by_the_other_order(monkeypatch):
+    """The fault PR 36 found on the chip, planted: the stored mask is the
+    other order's, the counts are not."""
+    sound = chip_smoke.split_mod.winner_cat_mask
+    monkeypatch.setattr(
+        chip_smoke.split_mod, "winner_cat_mask",
+        lambda asc, desc, slot, v, t: sound(desc, asc, slot, v, t))
+    jax.clear_caches()
+    try:
+        with pytest.raises(AssertionError, match="2\\+4"):
+            chip_smoke.leg_categorical(
+                n_rows=8000, rounds=3, num_leaves=15, **ROUNDS,
+                shapes={"2+4": (2, (3, 24, 105, 255), chip_smoke.CLICK_CELL)})
+    finally:
+        jax.clear_caches()
 
 
 def test_check_no_fallback_rejects_a_fired_net(narrow):

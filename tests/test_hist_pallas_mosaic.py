@@ -62,18 +62,24 @@ def test_mosaic_takes_the_kernel(one_chip, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_partition_over_the_shadow_is_one_pass(one_chip):
+@pytest.mark.parametrize("n,f,categorical", [
+    (10_500_000, 28, False), (15_280_205, 39, True)],
+    ids=["higgs-numerical", "criteo-categorical"])
+def test_partition_over_the_shadow_is_one_pass(one_chip, n, f, categorical):
     """The rounds grower's partition at Higgs' shape, over the feature-major
     shadow as ``Dataset.bins_device_t`` lays it out: one fusion reads the
     shadow, nothing is a row of one sublane, and XLA counts the round's
     eight columns and the ids once each way, with half as much to spare
-    (PERF.md section 6, PR 31: eight fusions and 588 MB before)."""
+    (PERF.md section 6, PR 31: eight fusions and 588 MB before).  And at
+    Criteo's shape with categorical slots, which read their bins-going-left
+    as a bitset: the same one fusion, the same bytes, and no gather (PR 36:
+    the lookup that stood there was a gather of every row in every slot)."""
     import re
 
     from lightgbm_tpu.ops.treegrow import _empty_best
     from lightgbm_tpu.ops.treegrow_fast import partition_rows
 
-    n, f, slots, leaves = 10_500_000, 28, 8, 255
+    slots, leaves = 8, 255
     tile = (-(-n // hp.ROW_TILE), hp.ROW_TILE // 128, 128)
 
     def s(shape, dt):
@@ -85,7 +91,7 @@ def test_partition_over_the_shadow_is_one_pass(one_chip):
 
     def partition(bins_t, lid, best, accept, inv_rank, right_of, missing):
         return partition_rows(bins_t, 0, lid, best, accept, inv_rank,
-                              right_of, missing, slots, False)
+                              right_of, missing, slots, categorical)
 
     compiled = jax.jit(partition).lower(
         s((f, *tile), jnp.int16), s(tile, jnp.int32), best,
@@ -94,7 +100,8 @@ def test_partition_over_the_shadow_is_one_pass(one_chip):
     text = compiled.as_text()
     readers = re.findall(r"= \S+ ([\w\-]+)\([^)]*%bins_t", text)
     assert readers == ["fusion"], readers
-    assert not re.search(r"\[(1,)?10500\d\d\d\]", text)  # all rows in a row
+    assert not re.search(r"\[(1,)?%d\d\d\d\]" % (n // 1000), text)  # all rows in a row
+    assert " gather(" not in text
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     rows = tile[0] * hp.ROW_TILE

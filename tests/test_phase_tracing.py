@@ -22,8 +22,10 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
+# grow.cat_search needs a categorical column: tests/test_categorical_route.py
 GROWER_PHASES = [p for p in profiling.DEVICE_PHASES
-                 if p.startswith(("grow.", "hist."))]
+                 if p.startswith(("grow.", "hist."))
+                 and p != "grow.cat_search"]
 
 
 def _toy(n=1500, f=5, seed=3):
