@@ -40,7 +40,8 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.obs import metrics as obs_metrics
 from lightgbm_tpu.obs import server as obs_server
 from lightgbm_tpu.ops import split as split_mod
-from lightgbm_tpu.ops.hist_pallas import (histogram_pallas_multi,
+from lightgbm_tpu.ops.hist_pallas import (bins_shadow,
+                                          histogram_pallas_multi,
                                           histogram_pallas_multi_quantized,
                                           recommended_leaf_tile)
 from lightgbm_tpu.parallel.mesh import make_mesh
@@ -155,33 +156,44 @@ def leg_kernels(n_rows: int = 4096, shapes=KERNEL_SHAPES) -> dict:
 
         tile = recommended_leaf_tile(b, f, leaves)
         leaf = rng.randint(0, tile, size=n_rows).astype(np.int32)
-        t0 = time.perf_counter()
-        got = np.asarray(histogram_pallas_multi(
-            jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h),
-            jnp.asarray(mask), jnp.asarray(leaf), 0, tile, b))
-        t_f32 = time.perf_counter() - t0
         want = hist_oracle(bins, [g * m, h * m, m], leaf, tile, b)
-        assert got.shape == (tile, 3, f, b), got.shape
-        # bf16x2-split products carry ~17 mantissa bits, f32 accumulation
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-        np.testing.assert_array_equal(got[:, 2], want[:, 2])  # counts exact
-
         tile_q = recommended_leaf_tile(b, f, leaves, quantized=True)
         leaf_q = rng.randint(0, tile_q, size=n_rows).astype(np.int32)
-        t0 = time.perf_counter()
-        got_q = np.asarray(histogram_pallas_multi_quantized(
-            jnp.asarray(bins), jnp.asarray(gq), jnp.asarray(hq),
-            jnp.asarray(mask), jnp.asarray(leaf_q), 0, tile_q, b))
-        t_q = time.perf_counter() - t0
         want_q = hist_oracle(bins, [gq * m, hq * m, m], leaf_q, tile_q, b)
-        assert got_q.dtype == np.int32 and got_q.shape == (tile_q, 3, f, b)
-        np.testing.assert_array_equal(got_q, want_q.astype(np.int64))
+        # the packed tile reads its bins feature-major: from the shadow the
+        # call builds of (N, F), and from one handed in as a grower hands
+        # Dataset.bins_device_t
+        first = None  # the times reported are the first calls', which compile
+        for shadow in (None, bins_shadow(jnp.asarray(bins))):
+            t0 = time.perf_counter()
+            got = np.asarray(histogram_pallas_multi(
+                jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h),
+                jnp.asarray(mask), jnp.asarray(leaf), 0, tile, b,
+                bins_t=shadow))
+            t_f32 = time.perf_counter() - t0
+            assert got.shape == (tile, 3, f, b), got.shape
+            # bf16x2-split products carry ~17 mantissa bits, f32 accumulation
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+            np.testing.assert_array_equal(got[:, 2], want[:, 2])  # counts
+
+            t0 = time.perf_counter()
+            got_q = np.asarray(histogram_pallas_multi_quantized(
+                jnp.asarray(bins), jnp.asarray(gq), jnp.asarray(hq),
+                jnp.asarray(mask), jnp.asarray(leaf_q), 0, tile_q, b,
+                bins_t=shadow))
+            t_q = time.perf_counter() - t0
+            assert got_q.dtype == np.int32
+            assert got_q.shape == (tile_q, 3, f, b)
+            np.testing.assert_array_equal(got_q, want_q.astype(np.int64))
+            first = first or (t_f32, t_q)
+        t_f32, t_q = first
 
         out[f"{f}x{b}"] = {"leaf_tile_f32": tile, "leaf_tile_int8": tile_q,
                            "f32_first_call_s": round(t_f32, 2),
                            "int8_first_call_s": round(t_q, 2)}
         say(f"kernels {f}x{b}: f32 tile {tile} {t_f32:.1f}s, "
-            f"int8 tile {tile_q} {t_q:.1f}s, both match the numpy oracle")
+            f"int8 tile {tile_q} {t_q:.1f}s, both match the numpy oracle, "
+            "the shadow built and handed in")
     out["wall_s"] = round(time.perf_counter() - t_leg, 2)
     return out
 

@@ -336,7 +336,9 @@ class GBDT:
                 self._models.append(tree)
                 if arrays.hist_passes is not None:
                     self._count_hist_passes(int(arrays.hist_passes),
-                                            int(arrays.hist_blocks), tree)
+                                            int(arrays.hist_blocks),
+                                            int(arrays.hist_blocks_packed),
+                                            tree)
                 self._count_rank_work()
                 # the tree's nodes, and those that split on a categorical
                 # column: from the host tree the flush has just built
@@ -344,7 +346,8 @@ class GBDT:
                     tree.num_leaves - 1)
                 _obs.counter("train_cat_split_nodes_total").inc(tree.num_cat)
 
-    def _count_hist_passes(self, passes: int, blocks: int, tree: Tree) -> None:
+    def _count_hist_passes(self, passes: int, blocks: int, packed: int,
+                           tree: Tree) -> None:
         """What the tree's histogram passes read against what the tree
         needed, from arrays the flush has on the host already (no pull on
         the hot path).  Every pass of the rounds grower streams all rows of
@@ -353,13 +356,15 @@ class GBDT:
         every split.  What the Pallas kernel put through its one-hot product
         lies between the two: sub-blocks of ``hist_pallas.SUB_BLOCK`` rows,
         as many as each row tile's rows in the pass fill (0 from a grower
-        route that does not run the kernel)."""
+        route that does not run the kernel); ``packed`` of them lay in tiles
+        that packed their rows, the others in tiles multiplied whole."""
         n_rows = int(self.train_set.num_data())
         _obs.counter("train_hist_passes_total").inc(passes)
         _obs.counter("train_hist_rows_streamed_total").inc(passes * n_rows)
         _obs.counter("train_hist_rows_needed_total").inc(
             n_rows + tree.smaller_child_rows())
         _obs.counter("train_hist_blocks_multiplied_total").inc(blocks)
+        _obs.counter("train_hist_blocks_packed_total").inc(packed)
 
     def _count_rank_work(self) -> None:
         """What a ranking objective's gradient step worked through for the

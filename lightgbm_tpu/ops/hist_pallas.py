@@ -27,34 +27,53 @@ a tree: it keeps an (N, F) int16 matrix feature-major on the device and
 copies it to the row-major layout this kernel's operand asks for.
 (4) One int32 COUNT per row tile and pass (``pass_counts``): how many of
 the tile's rows have a slot.  It arrives as a prefetched scalar and decides
-what the tile costs.
+what the tile costs.  (5) Since PR 37, up to 256 bins, the bins a second
+time, FEATURE-MAJOR on the kernel's own row tiles (``bins_shadow``: (F,
+row tiles, T / 128, 128); ``basic.Dataset.bins_device_t`` keeps it on the
+device for the partition, and the rounds grower hands that in): what the
+packed tiles read.  A dense tile reads the row-major block as before.
 
 The cost of a pass follows the rows in the pass, not N (since PR 29; before,
 every pass multiplied every row, 35 times a 255-leaf tree on 10.5M rows
 where 3.9 passes' worth were needed).  Per row tile: no row in the pass,
 the tile's DMA and nothing else; otherwise the tile's rows of the pass are
 packed to the front in VMEM (a 0/1 place matrix on the MXU moves bins,
-channels and slot) and the one-hot build and product run over whole
-SUB_BLOCK-row sub-blocks of packed rows only; a tile so full that packing
-would cost more takes the dense product over all its rows (``_tile_cost``:
-the root's pass, a one-leaf call, a window of grouped rows).  Above 256
-bins nothing is packed (bfloat16 would not hold the bins on the move):
-every tile with a row in the pass takes the dense product.
+channels and slot in one product: a lane group's bins from the shadow
+stacked under its channels and its slot, rows on the lanes in all three)
+and the one-hot build and product run over whole SUB_BLOCK-row sub-blocks
+of packed rows only, the one-hots with the bins on the sublanes; a tile so
+full that packing would cost more takes the dense product over all its
+rows (``_tile_cost``).  Above 256 bins nothing is packed (bfloat16 would
+not hold the bins on the move): every tile with a row in the pass takes
+the dense product, and the call takes no shadow.
 
-Measured on a v5e, the kernel alone, ms a pass by the share of rows in the
-pass, rows drawn at random (PERF.md section 6, PR 29; before: 70.5 and
+Measured on a v5e, the kernel alone (its own time in a trace), ms a pass by
+the share of rows in the pass, rows drawn at random (PERF.md section 6:
+PR 29's readings, then PR 37's, taken with the rule fitted to cost alone,
+which packs Epsilon's whole tiles too; as handed in a tile over three
+quarters full is dense, 69.7 and 189.5 at 100%; before PR 29: 70.5 and
 189.1 at every share):
 
-    share of rows      100%    50%    25%    12%     4%     1%
-    10.5M x 28, 8 x 6  71.3   71.3   42.5   23.3   12.8   12.9
-    400k x 2000, 10x6  190.2  129.1  69.5   35.7   17.1   17.0
+    share of rows       100%    50%    25%    12%     4%     1%
+    10.5M x 28, PR 29   71.3   71.3   42.5   23.3   12.8   12.9
+    10.5M x 28, PR 37   69.7   46.0   25.7   14.2    7.9    7.9
+    400k x 2000, PR 29  190.2  129.1  69.5   35.7   17.1   17.0
+    400k x 2000, PR 37  172.6  94.1   52.4   28.1   15.6   15.6
 
-What a tile pays before its first sub-block (the rank of its rows, the
-bins as bfloat16) and what a packed sub-block pays beyond its one-hots (a
-(SUB_BLOCK, row tile) place matrix, two small products) both grow with the
-row tile, and the rows a sparse tile rounds up to shrink with it: 2048 is
-where a 255-leaf tree's late passes (2 to 6% of the rows each) came out
-cheapest at 28 features, and no worse than 1024 or 4096 at 128.
+A 2,048-row tile pays 0.60 us before its first sub-block and 0.99 us a
+packed sub-block at 28 features (a dense sub-block 0.85), 2.35 and 3.33 us
+at 128 features (dense 3.78).  Until PR 37 a packed sub-block cost 1.65 and
+4.74 us: its bins were moved to (SUB_BLOCK, FB), rows on the sublanes, by a
+product of their own beside the channels', and every feature's one-hot took
+a column out of that block and spread it over the lanes.  That, and not the
+two products, was what a packed sub-block paid over a dense one: one product
+in their place alone saved 0.06 us; the packed block left feature-major,
+its one-hots formed from rows, saved 0.66 and 1.41.  Both the tile's floor
+(the rank of its rows, the stacks) and a packed sub-block's move (a
+(SUB_BLOCK, row tile) place matrix) grow with the row tile, and the rows a
+sparse tile rounds up to shrink with it: 2048 is where a 255-leaf tree's
+late passes (2 to 6% of the rows each) came out cheapest at 28 features in
+PR 29, and no worse than 1024 or 4096 at 128; not read again since.
 
 Design notes (*log*: in-jit fori_loop probes at N=1M F=28 over the remote
 link, before PR 26; methodology + numbers in docs/PERF_NOTES.md):
@@ -171,13 +190,15 @@ _PACK_MAX_BINS = 256  # bfloat16 holds a bin up to here: packed rows' bins
 SUB_BLOCK = 128  # rows of a packed sub-block: what a tile pays in whole
 
 
-def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
-                   n, tile, ncl, pack):
+def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
     """Grid (1, row_tiles): the accumulator lives across the row sweep.
-    ``chunk_ref`` is the scalar the bins' index map picked its 128 columns
-    by; the body has no use for it.  ``cnt_ref[i]`` is the number of rows of
-    row tile ``i`` that have a slot in this pass (or more: see
-    :func:`pass_counts`), and decides what the tile costs:
+    ``chunk_ref`` is the scalar the bins' index maps picked their 128
+    features by; the body has no use for it.  ``refs`` are the feature-major
+    bins ``shadow_ref`` (FB, 1, T / 128, 128), handed where tiles pack (up
+    to 256 bins), then ``base_ref``, ``slot_ref`` and ``out_ref``.
+    ``cnt_ref[i]`` is the number of rows of row tile ``i`` that have a slot
+    in this pass (or more: see :func:`pass_counts`), and decides what the
+    tile costs:
 
     * 0: the tile's DMA and nothing else.
     * so many that packing would cost more (:func:`_tile_cost`), or above
@@ -196,15 +217,20 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
       from one product with a 0/1 triangle, plus the groups before it); per
       sub-block a 0/1 matrix P (SUB_BLOCK, T) with ``P[s, t] = 1`` where
       row ``t`` takes place ``s`` moves the bins, the channels and the slot
-      on the MXU (exact: 0/1 times values that bfloat16 holds, bins up to
-      256 among them), and the same lanes and one-hots are then built from
-      the packed block.  Places past the tile's count hold zeros and add
-      nothing.  The sums of a tile are taken sub-block by sub-block, so a
-      float histogram need not equal the dense product's digit for digit.
+      on the MXU in one product a lane group, the group's bins (FB, 128)
+      from the shadow stacked under its base rows (exact: 0/1 times values
+      that bfloat16 holds, bins up to 256 among them, one nonzero term a
+      sum), and the same lanes are then built from the packed block, its
+      one-hots with the bins on the sublanes and the rows on the lanes, as
+      the packed block lies.  Places past the tile's count hold zeros and
+      add nothing.  The sums of a tile are taken sub-block by sub-block, so
+      a float histogram need not equal the dense product's digit for digit.
 
     Rows at or past ``n`` take slot -1 and their base is zeroed by a select
     before any product: what the ragged last block holds past the arrays'
     end never reaches the accumulator."""
+    *shadow_refs, base_ref, slot_ref, out_ref = refs
+    pack = bool(shadow_refs)
     i = pl.program_id(1)
 
     # the revisited output block IS the accumulator (a separate VMEM
@@ -242,9 +268,13 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
     spread = bf16((r - r // ncl * ncl == c) & (r < tile * ncl))  # (NC, K) 0/1
     leaf_of = jax.lax.broadcasted_iota(jnp.int32, (NC, 1), 0) // ncl
 
-    def accumulate(base, slot, bins_i32):
-        """base (K, W) bfloat16, slot (1, W), bins (W, FB) int32: the
-        one-hot product of W rows into the accumulator."""
+    def accumulate(base, slot, bins_i32, rows_on_lanes=False):
+        """base (K, W) bfloat16, slot (1, W), bins int32: the one-hot
+        product of W rows into the accumulator.  The bins lie (W, FB) as a
+        dense tile reads them, or (FB, W), rows on the lanes, as a packed
+        tile moves them: its one-hots then have the bins on the sublanes (a
+        feature's row against an iota, no column taken and spread over the
+        lanes) and the product contracts the lanes of both."""
         W = slot.shape[1]
         wide = jnp.dot(spread, base,
                        preferred_element_type=jnp.float32)  # (NC, W)
@@ -252,11 +282,19 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
         if dtype == jnp.int8:
             lane = lane.astype(jnp.int32)
         lane = lane.astype(dtype)
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (W, B), 1)  # hoisted
+        iota_b = jax.lax.broadcasted_iota(  # hoisted
+            jnp.int32, (B, W) if rows_on_lanes else (W, B),
+            0 if rows_on_lanes else 1)
         for f in range(FB):
-            oh = (bins_i32[:, f][:, None] == iota_b).astype(dtype)  # (W, B)
-            out_ref[f] += jnp.dot(lane, oh,
-                                  preferred_element_type=out_ref.dtype)
+            if rows_on_lanes:
+                oh = (bins_i32[f:f + 1, :] == iota_b).astype(dtype)  # (B, W)
+                out_ref[f] += jax.lax.dot_general(
+                    lane, oh, (((1,), (1,)), ((), ())),
+                    preferred_element_type=out_ref.dtype)
+            else:
+                oh = (bins_i32[:, f][:, None] == iota_b).astype(dtype)
+                out_ref[f] += jnp.dot(lane, oh,
+                                      preferred_element_type=out_ref.dtype)
 
     @pl.when(dense)
     def _():
@@ -282,8 +320,7 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
         slot, base = operands(0, T)
         # the slot rides in the base's last row, which no channel uses
         krow = jax.lax.broadcasted_iota(jnp.int32, (K, T), 0)
-        base = jnp.where(krow == K - 1, slot.astype(jnp.float32),
-                         base).astype(jnp.bfloat16)
+        base = jnp.where(krow == K - 1, slot.astype(jnp.float32), base)
         # place[g, j]: where row g * 128 + j goes among the packed rows
         taken = (slot >= 0).astype(jnp.float32)
         taken = jnp.concatenate(
@@ -299,23 +336,26 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref, *,
         before = jnp.dot(bf16(gj < gk), total.astype(jnp.bfloat16),
                          preferred_element_type=jnp.float32)  # groups before
         place = jnp.where(taken > 0, rank + before, -1.0).astype(jnp.int32)
-        bins_bf = bf16(bins_ref[...].astype(jnp.int32))  # (T, FB)
+        # a lane group's stack (K + FB, 128): the base's rows over the
+        # group's bins from the shadow, rows on the lanes in both
+        shadow_ref, = shadow_refs
+        bins_t = shadow_ref[:, 0].astype(jnp.int32).astype(jnp.float32)
+        stack = [
+            jnp.concatenate([base[:, g * 128:(g + 1) * 128], bins_t[:, g, :]],
+                            axis=0).astype(jnp.bfloat16)
+            for g in range(G)]
         iota_s = jax.lax.broadcasted_iota(jnp.int32, (S, 128), 0)
 
         def block(b, carry):
-            bins_c = jnp.zeros((S, FB), jnp.float32)
-            base_c = jnp.zeros((K, S), jnp.float32)
+            moved = jnp.zeros((K + FB, S), jnp.float32)
             for g in range(G):
-                cols = slice(g * 128, (g + 1) * 128)
                 p = bf16(place[g:g + 1, :] - b * S == iota_s)  # (S, 128)
-                bins_c += jnp.dot(p, bins_bf[cols, :],
-                                  preferred_element_type=jnp.float32)
-                base_c += jax.lax.dot_general(
-                    base[:, cols], p, (((1,), (1,)), ((), ())),
+                moved += jax.lax.dot_general(
+                    stack[g], p, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
-            accumulate(base_c.astype(jnp.bfloat16),
-                       base_c[K - 1:K, :].astype(jnp.int32),
-                       bins_c.astype(jnp.int32))
+            accumulate(moved[:K].astype(jnp.bfloat16),
+                       moved[K - 1:K].astype(jnp.int32),
+                       moved[K:].astype(jnp.int32), rows_on_lanes=True)
             return carry
 
         jax.lax.fori_loop(0, blocks, block, 0)
@@ -352,22 +392,45 @@ def _tile_cost(cnt, row_tile: int, feat_block: int, pack: bool):
     whole sub-blocks, whether it takes the dense product).  The kernel asks
     with its scalar, :func:`blocks_multiplied` with every tile's count.
 
-    Packing is taken where it is the cheaper of the two, in units of one
-    sub-block's one-hot product for one feature (v5e, 28 and 128 features,
-    PERF.md section 6, PR 29): a dense tile costs ``row_tile / SUB_BLOCK``
-    sub-blocks of ``feat_block`` features; a packed sub-block costs its
-    features and about two more for every 128-row lane group of the tile
-    (its share of the place matrix and of the two moves), and the tile
-    about three a group before its first sub-block.  Both constants were
-    read off tiles of one sub-block; at seven or eight a group costs nearer
-    1.5, so the rule turns dense a sub-block or two early at 28 features."""
+    Packing is taken where it is the cheaper of the two, in tenths of one
+    sub-block's dense one-hot product for one feature (v5e, PERF.md section
+    6, PR 37: the kernel's own time in a trace, 28 and 128 features,
+    2,048-row tiles of exactly 1, 2, 4, 6, 8, 10, 12 and 16 sub-blocks, a
+    straight line in both): a dense tile costs ``row_tile / SUB_BLOCK``
+    sub-blocks of ``feat_block`` features; a packed sub-block costs eight
+    tenths of its features (its one-hots take a feature's row and no
+    column) and six tenths for every 128-row lane group of the tile (its
+    share of the place matrix and of the move), and the tile six tenths a
+    feature and two a group before its first sub-block (the stacks read out
+    of the shadow, the rank).  By cost alone a 2,048-row tile would pack up
+    to 13 sub-blocks at 28 features, 14 at 39 and always at 128 (a full
+    tile packed costs 0.92 of the dense product there).
+
+    A tile more than three quarters full takes the dense product whatever
+    it costs: a packed tile's sums reach the float32 accumulator in pieces
+    of SUB_BLOCK rows, a dense tile's in pieces of ``_DENSE_ROWS``, and the
+    passes that hold such tiles (the root's, its children's) sum over most
+    of the rows.  With the root packed the ranking cell's ``root_hess_gap``
+    read ten times the dense root's (PERF.md section 6, PR 37)."""
     groups = row_tile // 128
+    whole = row_tile // SUB_BLOCK
     blocks = (cnt + SUB_BLOCK - 1) // SUB_BLOCK
     dense = cnt > 0
     if pack:
-        dense &= (blocks * (feat_block + 2 * groups) + 3 * groups
-                  >= row_tile // SUB_BLOCK * feat_block)
+        dense &= ((blocks * (8 * feat_block + 6 * groups)
+                   + 6 * feat_block + 2 * groups >= 10 * whole * feat_block)
+                  | (4 * blocks > 3 * whole))
     return blocks, dense
+
+
+def _pass_cost(counts, shape, num_bins, row_tile):
+    """:func:`_tile_cost` of every row tile of a pass over bins of ``shape``
+    (N, F) -> (the sub-blocks of a whole tile, each tile's own, whether each
+    takes the dense product)."""
+    t = _row_tile(shape[0], row_tile)
+    return (t // SUB_BLOCK,
+            *_tile_cost(counts, t, min(shape[1], _FEAT_BLOCK),
+                        num_bins <= _PACK_MAX_BINS))
 
 
 def blocks_multiplied(counts: jnp.ndarray, shape: tuple, num_bins: int,
@@ -376,16 +439,40 @@ def blocks_multiplied(counts: jnp.ndarray, shape: tuple, num_bins: int,
     :func:`pass_counts` over bins of ``shape`` (N, F) puts through the
     one-hot product of each 128-feature chunk: a packed tile its rows in
     whole sub-blocks, a dense tile all of its own."""
-    t = _row_tile(shape[0], row_tile)
-    blocks, dense = _tile_cost(counts, t, min(shape[1], _FEAT_BLOCK),
-                               num_bins <= _PACK_MAX_BINS)
-    return jnp.sum(jnp.where(dense, t // SUB_BLOCK, blocks))
+    whole, blocks, dense = _pass_cost(counts, shape, num_bins, row_tile)
+    return jnp.sum(jnp.where(dense, whole, blocks))
+
+
+def blocks_packed(counts: jnp.ndarray, shape: tuple, num_bins: int,
+                  row_tile: int = ROW_TILE) -> jnp.ndarray:
+    """int32 scalar: those of :func:`blocks_multiplied` that lie in packed
+    tiles, by the same rule: the sub-blocks that the place matrix's product
+    moved before they were multiplied."""
+    _, blocks, dense = _pass_cost(counts, shape, num_bins, row_tile)
+    return jnp.sum(jnp.where(dense, 0, blocks))
+
+
+def _shadow_shape(n: int, f: int, row_tile: int) -> tuple:
+    t = _row_tile(n, row_tile)
+    return f, pl.cdiv(n, t), t // 128, 128
+
+
+def bins_shadow(bins: jnp.ndarray, row_tile: int = ROW_TILE) -> jnp.ndarray:
+    """(N, F) bins -> (F, row_tiles, T / 128, 128), T the kernel's row tile:
+    the bins feature-major on the kernel's own tiles, the rows past N bin 0
+    (the kernel gives them slot -1 by row index).  What the packed tiles
+    read; at the default row tile it is what ``basic.Dataset.bins_device_t``
+    keeps on the device, and a caller that holds that hands it in."""
+    shape = _shadow_shape(*bins.shape, row_tile)
+    rows = shape[1] * shape[2] * 128
+    return jnp.pad(bins.T, ((0, 0), (0, rows - bins.shape[0]))).reshape(shape)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_tile", "tile", "ncl"))
 def _hist_pallas_raw(
     bins: jnp.ndarray,  # (N, F) int16/int32, as it lies in HBM
+    bins_t: jnp.ndarray,  # bins_shadow(bins, row_tile); None above 256 bins
     base: jnp.ndarray,  # (_BASE_ROWS, N) f32 or int8: the per-tree channels
     slot: jnp.ndarray,  # (1, N) int32: the row's leaf of this pass, or -1
     chunk: jnp.ndarray,  # (1,) int32: which block of _FEAT_BLOCK features
@@ -400,48 +487,46 @@ def _hist_pallas_raw(
     ``f`` holds channel ``c`` of the rows in slot ``l``, by bin.  Nothing
     N-sized is padded, sliced or copied on the way in: the ragged last row
     tile is masked in the kernel, and the call takes the whole bin matrix
-    and picks its 128 columns in its index map.  The chunk is a prefetched
-    scalar and not a static, so that the sixteen calls of a pass at
-    F = 2000 are one traced and lowered function: with sixteen index maps
-    Epsilon's first ``update()`` took 95 s instead of 20.  ``row_tile`` is
-    rounded to whole 128-row groups (``_row_tile``)."""
+    and picks its 128 columns in its index map, and the shadow's 128 features
+    likewise.  The chunk is a prefetched scalar and not a static, so that the
+    sixteen calls of a pass at F = 2000 are one traced and lowered function:
+    with sixteen index maps Epsilon's first ``update()`` took 95 s instead of
+    20.  ``row_tile`` is rounded to whole 128-row groups (``_row_tile``)."""
     n, f = bins.shape
     B = _round_up(max(num_bins, 8), 8)
     quantized = base.dtype == jnp.int8
     nc = _round_up(tile * ncl, 32 if quantized else 8)
     FB = min(f, _FEAT_BLOCK)
     row_tile = _row_tile(n, row_tile)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    shadow = [] if bins_t is None else [bins_t]
 
     # no scope and no name= here: XLA names the custom call after the
     # innermost component of its op_name, and the benchmark's kernel metrics
     # find it in a device trace as ``_hist_pallas_raw.N`` (_leaf_histograms)
     return pl.pallas_call(
-        functools.partial(_direct_kernel, n=n, tile=tile, ncl=ncl,
-                          pack=num_bins <= _PACK_MAX_BINS),
+        functools.partial(_direct_kernel, n=n, tile=tile, ncl=ncl),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1, pl.cdiv(n, row_tile)),
             in_specs=[
-                pl.BlockSpec((row_tile, FB), lambda _, i, c, k: (i, c[0]),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((_BASE_ROWS, row_tile),
-                             lambda _, i, c, k: (0, i),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, row_tile), lambda _, i, c, k: (0, i),
-                             memory_space=pltpu.VMEM),
+                vmem((row_tile, FB), lambda _, i, c, k: (i, c[0])),
+                *[vmem((FB, 1, row_tile // 128, 128),
+                       lambda _, i, c, k: (c[0], i, 0, 0)) for _ in shadow],
+                vmem((_BASE_ROWS, row_tile), lambda _, i, c, k: (0, i)),
+                vmem((1, row_tile), lambda _, i, c, k: (0, i)),
             ],
-            out_specs=pl.BlockSpec((FB, nc, B), lambda j, i, c, k: (j, 0, 0),
-                                   memory_space=pltpu.VMEM),
+            out_specs=vmem((FB, nc, B), lambda j, i, c, k: (j, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(
             (FB, nc, B), jnp.int32 if quantized else jnp.float32),
         cost_estimate=pl.CostEstimate(
             flops=2 * n * FB * B * nc,
-            bytes_accessed=n * (FB * bins.dtype.itemsize
+            bytes_accessed=n * (FB * bins.dtype.itemsize * (1 + len(shadow))
                                 + _BASE_ROWS * base.dtype.itemsize + 4),
             transcendentals=0,
         ),
-    )(chunk, counts, bins, base, slot)
+    )(chunk, counts, bins, *shadow, base, slot)
 
 
 def _split_bf16x2(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -494,11 +579,20 @@ def payload_base_quantized(grad_q: jnp.ndarray, hess_q: jnp.ndarray,
 
 
 def _leaf_histograms(bins, base, mask, leaf_id, leaf_base, tile, num_bins,
-                     ncl, row_tile, counts=None):
+                     ncl, row_tile, counts=None, bins_t=None):
     """One pass of the kernel -> (tile, ncl, F, B) in the accumulator's
     dtype: channel ``c`` of the rows that ``mask`` keeps and that sit in
     leaf ``leaf_base + l``.  ``counts``: :func:`pass_counts` of ``mask`` at
-    this ``row_tile``, from a caller that made them already."""
+    this ``row_tile``, from a caller that made them already.  ``bins_t``:
+    :func:`bins_shadow` of ``bins`` at this ``row_tile``, from a caller that
+    holds it; one on other tiles (a ``Dataset``'s, for fewer rows than its
+    row tile) is built anew like none."""
+    if num_bins > _PACK_MAX_BINS:
+        bins_t = None  # no tile packs
+    elif bins_t is None or bins_t.shape != _shadow_shape(*bins.shape,
+                                                         row_tile):
+        with phase_scope("hist.payload"):
+            bins_t = bins_shadow(bins, row_tile)
     with phase_scope("grow.slots"):
         slot = jnp.where(mask.astype(bool),
                          leaf_id.astype(jnp.int32) - leaf_base, -1)[None, :]
@@ -519,9 +613,10 @@ def _leaf_histograms(bins, base, mask, leaf_id, leaf_base, tile, num_bins,
     # in a trace stays ``_hist_pallas_raw.N``
     with phase_scope("hist.kernel"):
         outs = [
-            _hist_pallas_raw(bins, base, slot, jnp.full((1,), j, jnp.int32),
-                             counts, num_bins=num_bins, row_tile=row_tile,
-                             tile=tile, ncl=ncl)
+            _hist_pallas_raw(bins, bins_t, base, slot,
+                             jnp.full((1,), j, jnp.int32), counts,
+                             num_bins=num_bins, row_tile=row_tile, tile=tile,
+                             ncl=ncl)
             for j in range(pl.cdiv(f, _FEAT_BLOCK))]
     with phase_scope("hist.unpack"):
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
@@ -544,6 +639,7 @@ def histogram_pallas_multi(
     row_tile: int = ROW_TILE,
     base: jnp.ndarray = None,  # payload_base(grad, hess, m, precision), m >= mask
     counts: jnp.ndarray = None,  # pass_counts(mask, row_tile)
+    bins_t: jnp.ndarray = None,  # bins_shadow(bins, row_tile)
 ) -> jnp.ndarray:
     """Per-leaf histograms for a tile of leaves in ONE data pass.
 
@@ -552,7 +648,8 @@ def histogram_pallas_multi(
     the kernel from ``base`` and the rows' slots.  A caller with many
     passes over the same gradients builds ``base`` once and hands it in;
     without it, it is built here.  Likewise ``counts``, for a caller that
-    keeps them (the rounds grower counts what the kernel multiplied).
+    keeps them (the rounds grower counts what the kernel multiplied), and
+    ``bins_t``, the feature-major bins that the packed tiles read.
     This is the TPU replacement for per-leaf row-index histogramming
     (reference: Dataset::ConstructHistograms over DataPartition indices).
     """
@@ -561,8 +658,8 @@ def histogram_pallas_multi(
             base = payload_base(grad, hess, mask, precision)
     out = _leaf_histograms(
         bins, base, mask, leaf_id, leaf_base, num_leaves_tile, num_bins,
-        payload_channels(precision, False), row_tile,
-        counts)  # (L_tile, ncl, F, B)
+        payload_channels(precision, False), row_tile, counts,
+        bins_t)  # (L_tile, ncl, F, B)
     if precision == "f32":
         with phase_scope("hist.unpack"):
             out = jnp.stack([out[:, 0] + out[:, 3], out[:, 1] + out[:, 4],
@@ -600,6 +697,7 @@ def histogram_pallas_multi_quantized(
     row_tile: int = ROW_TILE,
     base: jnp.ndarray = None,  # payload_base_quantized(grad_q, hess_q, m)
     counts: jnp.ndarray = None,  # pass_counts(mask, row_tile)
+    bins_t: jnp.ndarray = None,  # bins_shadow(bins, row_tile)
 ) -> jnp.ndarray:
     """Quantized per-leaf histograms for a tile of leaves in one pass ->
     (L_tile, 3, F, B) int32: exact integer accumulation on the int8 MXU
@@ -609,7 +707,8 @@ def histogram_pallas_multi_quantized(
         with phase_scope("hist.payload"):
             base = payload_base_quantized(grad_q, hess_q, mask)
     return _leaf_histograms(bins, base, mask, leaf_id, leaf_base,
-                            num_leaves_tile, num_bins, 3, row_tile, counts)
+                            num_leaves_tile, num_bins, 3, row_tile, counts,
+                            bins_t)
 
 
 def histogram_pallas_quantized(

@@ -255,6 +255,7 @@ def histogram_multi(
     precision: str = "f32",
     base: jnp.ndarray = None,  # hist_pallas.payload_base, built once a tree
     counts: jnp.ndarray = None,  # hist_pallas.pass_counts(mask)
+    bins_t: jnp.ndarray = None,  # hist_pallas.bins_shadow(bins)
 ) -> jnp.ndarray:
     """Multi-leaf histogram DISPATCHER for the Pallas-eligible growers ->
     (L_tile, 3, F, B).
@@ -273,7 +274,8 @@ def histogram_multi(
 
         return histogram_pallas_multi(
             bins, grad, hess, mask, leaf_id, leaf_base, num_leaves_tile,
-            num_bins, precision=precision, base=base, counts=counts)
+            num_bins, precision=precision, base=base, counts=counts,
+            bins_t=bins_t)
 
     return _degrade.run_with_fallback(
         _degrade.HIST, _pallas,
@@ -295,6 +297,7 @@ def histogram_multi_quantized(
     *,
     base: jnp.ndarray = None,  # hist_pallas.payload_base_quantized
     counts: jnp.ndarray = None,  # hist_pallas.pass_counts(mask)
+    bins_t: jnp.ndarray = None,  # hist_pallas.bins_shadow(bins)
 ) -> jnp.ndarray:
     """Quantized sibling of :func:`histogram_multi` — same
     catch-once/degrade-forever dispatch over the int8 kernels."""
@@ -305,7 +308,8 @@ def histogram_multi_quantized(
 
         return histogram_pallas_multi_quantized(
             bins, grad_q, hess_q, mask, leaf_id, leaf_base,
-            num_leaves_tile, num_bins, base=base, counts=counts)
+            num_leaves_tile, num_bins, base=base, counts=counts,
+            bins_t=bins_t)
 
     return _degrade.run_with_fallback(
         _degrade.HIST, _pallas,

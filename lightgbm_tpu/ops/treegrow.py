@@ -66,6 +66,8 @@ class TreeArrays(NamedTuple):
     # the rows this tree took (the rounds grower counts them; others: None)
     hist_blocks: Optional[jnp.ndarray] = None  # i32 scalar — sub-blocks of
     # hist_pallas.SUB_BLOCK rows the Pallas kernel multiplied in those passes
+    hist_blocks_packed: Optional[jnp.ndarray] = None  # i32 scalar — those of
+    # them that lay in a packed tile: what the kernel's move served
 
 
 class GrowState(NamedTuple):

@@ -46,8 +46,8 @@ import jax.numpy as jnp
 
 from ..utils import degrade as _degrade
 from ..utils.profiling import phase_scope
-from .hist_pallas import (blocks_multiplied, pass_counts, payload_base,
-                          payload_base_quantized)
+from .hist_pallas import (bins_shadow, blocks_multiplied, blocks_packed,
+                          pass_counts, payload_base, payload_base_quantized)
 from .histogram import (histogram, histogram_multi, histogram_multi_quantized,
                         histogram_onehot_multi,
                         histogram_onehot_multi_quantized, unbundle_hists)
@@ -118,8 +118,9 @@ class FastState(NamedTuple):
     progress: jnp.ndarray  # bool — this round applied at least one split
     hist_passes: jnp.ndarray  # i32 — full passes over the rows so far: the
     # root's, plus one for every hist_and_eval taken
-    hist_blocks: jnp.ndarray  # i32 — sub-blocks of rows the Pallas kernel put
-    # through its one-hot product in those passes (0 on the other routes)
+    hist_blocks: jnp.ndarray  # (2,) i32 — sub-blocks of rows the Pallas kernel
+    # put through its one-hot product in those passes, and those of them that
+    # were a packed tile's (0 on the other routes)
     tree: TreeArrays
     anc: jnp.ndarray = False  # (L, L-1) bool ancestor masks, or () placeholder
     aside: jnp.ndarray = False  # (L, L-1) bool — leaf on the RIGHT side of m
@@ -378,23 +379,30 @@ def _grow_fast_impl(
     # channels depend on grad, hess and row_mask alone, so they are laid out
     # once here and every pass of the tree, the root's too, takes the same
     # array.  XLA does not hoist a loop-invariant N-sized build by itself.
-    hist_base = None
+    # Likewise the feature-major bins that the kernel's packed tiles read:
+    # the shadow where it is the shadow of the matrix the kernel reads, else
+    # built here, once a tree.
+    hist_base = hist_bins_t = None
     if use_pallas and num_bins > 64:
         with phase_scope("hist.payload"):
             hist_base = (payload_base_quantized(gq, hq, row_mask)
                          if quantize_bins else
                          payload_base(grad, hess, row_mask, hist_precision))
+            hist_bins_t = (bins_t if bins_t is not None and efb_bins is None
+                           else bins_shadow(hist_bins))
 
     def multi_hist(leaf_slot, tile):
         """A slot a row (as the leaf ids lie) -> (tile, 3, F, B) f32:
         per-slot histograms, one pass; and the sub-blocks the Pallas kernel
         multiplied for it."""
         keep = mask_t & (leaf_slot >= 0)
-        counts, blocks = None, jnp.asarray(0, jnp.int32)
+        counts, blocks = None, jnp.zeros((2,), jnp.int32)
         with phase_scope("grow.slots"):
             if hist_base is not None:  # once a pass, for every chunk
                 counts = pass_counts(keep)
-                blocks = blocks_multiplied(counts, hist_bins.shape, num_bins)
+                blocks = jnp.stack([
+                    blocks_multiplied(counts, hist_bins.shape, num_bins),
+                    blocks_packed(counts, hist_bins.shape, num_bins)])
             # the histogram routes take a row a row
             keep, leaf_slot = to_rows(keep), to_rows(leaf_slot)
         if use_pallas and quantize_bins:
@@ -410,7 +418,7 @@ def _grow_fast_impl(
                 h = histogram_multi_quantized(
                     hist_bins, gq, hq, keep,
                     jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
-                    base=hist_base, counts=counts,
+                    base=hist_base, counts=counts, bins_t=hist_bins_t,
                 )
         elif use_pallas and num_bins <= 64:
             # measured strategy selection (ops/histogram.py docstring): at
@@ -425,6 +433,7 @@ def _grow_fast_impl(
                 hist_bins, grad, hess, keep,
                 jnp.maximum(leaf_slot, 0), 0, tile, num_bins,
                 precision=hist_precision, base=hist_base, counts=counts,
+                bins_t=hist_bins_t,
             )
         else:
             # CPU/test fallback: per-slot masked scatter histograms (uses the
@@ -1053,7 +1062,8 @@ def _grow_fast_impl(
             leaf_depth=state.leaf_depth,
             path_features=(state.used_features if track_path else None),
             hist_passes=state.hist_passes,
-            hist_blocks=state.hist_blocks,
+            hist_blocks=state.hist_blocks[0],
+            hist_blocks_packed=state.hist_blocks[1],
         )
     if use_lazy:
         # hand the cross-tree charge state back (reference: the

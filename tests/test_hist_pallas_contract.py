@@ -130,11 +130,12 @@ def test_rows_past_n_never_reach_the_accumulator(quantized):
     chunk = jnp.zeros((1,), jnp.int32)
     with pltpu.force_tpu_interpret_mode():
         counts = hp.pass_counts(jnp.asarray(slot[0, :N] >= 0), 1024)
+        shadow = hp.bins_shadow(jnp.asarray(bins), 1024)
         long = np.asarray(hp._hist_pallas_raw(
-            jnp.asarray(bins), jnp.asarray(base), jnp.asarray(slot), chunk,
-            counts, **kw))
+            jnp.asarray(bins), shadow, jnp.asarray(base), jnp.asarray(slot),
+            chunk, counts, **kw))
         exact = np.asarray(hp._hist_pallas_raw(
-            jnp.asarray(bins), jnp.asarray(base[:, :N]),
+            jnp.asarray(bins), shadow, jnp.asarray(base[:, :N]),
             jnp.asarray(slot[:, :N]), chunk, counts, **kw))
     assert np.isfinite(long.astype(np.float64)).all()
     np.testing.assert_array_equal(long, exact)

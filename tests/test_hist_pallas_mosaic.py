@@ -42,23 +42,32 @@ SHAPES = {
     "over_256_bins_dense_alone": (1_000_000, 28, 8, 6, jnp.float32, 300),
     "epsilon_float_one_chunk_of_sixteen": (400_000, 2000, 10, 6, jnp.float32,
                                            255),
+    "epsilon_int8_the_default_there": (400_000, 2000, 20, 3, jnp.int8, 255),
+    "istella_float_one_chunk_of_two": (4_883_750, 220, 10, 6, jnp.float32,
+                                       255),
+    "criteo_float": (15_280_205, 39, 8, 6, jnp.float32, 255),
 }
 
 
 @pytest.mark.parametrize("case", sorted(SHAPES))
 def test_mosaic_takes_the_kernel(one_chip, case):
+    """With the feature-major shadow beside the bins wherever tiles pack:
+    at 128 features a chunk the packed branch's stacks and the shadow's two
+    buffers lie beside an 8.4 MB accumulator under the 16 MB scope."""
     n, f, tile, ncl, dtype, num_bins = SHAPES[case]
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    tiles = -(-n // hp._row_tile(n, hp.ROW_TILE))
+    shadow = hp._shadow_shape(n, f, hp.ROW_TILE)
     compiled = jax.jit(
         lambda *a: hp._hist_pallas_raw(*a, num_bins=num_bins,
                                        row_tile=hp.ROW_TILE, tile=tile,
                                        ncl=ncl)).lower(
-            s((n, f), jnp.int16), s((8, n), dtype), s((1, n), jnp.int32),
-            s((1,), jnp.int32), s((tiles,), jnp.int32)).compile()
+            s((n, f), jnp.int16),
+            s(shadow, jnp.int16) if num_bins <= 256 else None,
+            s((8, n), dtype), s((1, n), jnp.int32), s((1,), jnp.int32),
+            s((shadow[1],), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

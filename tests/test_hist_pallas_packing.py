@@ -20,9 +20,10 @@ PACKING_CASES = {
     "counts_that_are_multiples_of_the_sub_block": [256, 128, 128],
     "counts_one_over_a_multiple": [257, 129, 1],
     "a_count_that_needs_every_sub_block": [897, 1023, 5],
-    # at a 1024-row tile packing stops paying past 4 sub-blocks at 28
-    # features and past 6 at 128 (hist_pallas._tile_cost)
-    "counts_either_side_of_where_packing_stops_paying": [512, 513, 768, 769,
+    # at a 1024-row tile packing would stop paying past 7 sub-blocks at 28
+    # features and never at 128; a tile more than three quarters full, past
+    # 6 sub-blocks, is dense at either width (hist_pallas._tile_cost)
+    "counts_either_side_of_where_packing_stops_paying": [640, 641, 768, 769,
                                                          100],
     "a_ragged_last_tile_packed_to_its_end": [500, 309],
 }
@@ -60,10 +61,11 @@ def _check_against_oracle(bins, slot, tile, num_bins, precision, **kw):
     want = _oracle(bins, chans, slot, tile, num_bins)
     if precision == "int8":
         np.testing.assert_array_equal(got, want.astype(np.int64))
-        return
+        return got
     rtol = 1e-4 if precision == "f32" else 2e-2
     np.testing.assert_allclose(got[:, :2], want[:, :2], rtol=rtol, atol=rtol)
     np.testing.assert_array_equal(got[:, 2], want[:, 2])  # counts exact
+    return got
 
 
 @pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
@@ -74,6 +76,46 @@ def test_packed_tiles_match_the_oracle(case, f, precision):
     sub-block and the tile, 255 bins, four leaves a pass."""
     n, bins, slot = _packing_data(PACKING_CASES[case], f, 4, 255)
     _check_against_oracle(bins, slot, 4, 255, precision, row_tile=1024)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("f", [28, 130])
+def test_a_dataset_s_shadow_handed_in_is_the_shadow_built(f, precision):
+    """The packed tiles read their bins from the feature-major shadow.  A
+    call that holds ``(N, F)`` alone builds it; a grower on the chip hands
+    in what ``basic.Dataset.bins_device_t`` keeps: whole ROW_TILE tiles, the
+    rows past a ragged N bin 0.  One histogram, digit for digit, from a
+    dense tile, two packed ones and the ragged end; int8 the oracle's."""
+    from test_partition_shadow import shadow_of
+
+    n = 2 * hp.ROW_TILE + 437
+    rng = np.random.RandomState(f)
+    bins = rng.randint(0, 255, size=(n, f)).astype(np.int16)
+    slot = rng.randint(0, 4, size=n).astype(np.int32)
+    slot[hp.ROW_TILE:] = np.where(rng.rand(n - hp.ROW_TILE) < 0.15,
+                                  slot[hp.ROW_TILE:], -1)
+    counts = [int((slot[i:i + hp.ROW_TILE] >= 0).sum())
+              for i in range(0, n, hp.ROW_TILE)]
+    assert counts[0] == hp.ROW_TILE and 128 < counts[1] < 512 > counts[2] > 0
+    shadow = shadow_of(bins)
+    assert shadow.shape == (f, 3, hp.ROW_TILE // 128, 128)
+    built = _check_against_oracle(bins, slot, 4, 255, precision)
+    handed = _check_against_oracle(bins, slot, 4, 255, precision,
+                                   bins_t=shadow)
+    np.testing.assert_array_equal(handed, built)
+
+
+def test_a_shadow_on_other_tiles_is_built_anew():
+    """Fewer rows than a ``Dataset``'s row tile: its shadow is one 2,048-row
+    tile, the kernel's tile is the rows in whole lane groups, and the call
+    reads the shadow it builds."""
+    from test_partition_shadow import shadow_of
+
+    n, bins, slot = _packing_data([309], 28, 4, 255)
+    shadow = shadow_of(bins)
+    assert shadow.shape[1:] == (1, 16, 128) != hp.bins_shadow(
+        jnp.asarray(bins)).shape[1:]
+    _check_against_oracle(bins, slot, 4, 255, "f32", bins_t=shadow)
 
 
 @pytest.mark.parametrize("precision", ["f32", "int8"])
@@ -99,19 +141,22 @@ def test_counts_too_high_multiply_empty_places_and_change_nothing(row_tile):
 def _blocks_in_numpy(slot, f, num_bins, row_tile):
     """The sub-blocks a pass multiplies, from the slots: a tile's rows in
     the pass in whole sub-blocks, or the whole tile where the dense product
-    is the cheaper (the rule of hist_pallas._tile_cost, written out)."""
+    is the cheaper (the rule of hist_pallas._tile_cost, written out); and
+    those of them that lie in packed tiles."""
     n = len(slot)
     t = min(row_tile, -(-n // 128) * 128)
-    groups, fb, total = t // 128, min(f, 128), 0
+    groups, fb, total, packed = t // 128, min(f, 128), 0, 0
     for i in range(0, n, t):
         cnt = int((slot[i:i + t] >= 0).sum())
         blocks = -(-cnt // hp.SUB_BLOCK)
         dense = cnt > 0 and (
             num_bins > 256
-            or blocks * (fb + 2 * groups) + 3 * groups
-            >= t // hp.SUB_BLOCK * fb)
+            or blocks * (8 * fb + 6 * groups) + 6 * fb + 2 * groups
+            >= 10 * (t // hp.SUB_BLOCK) * fb
+            or 4 * blocks > 3 * (t // hp.SUB_BLOCK))
         total += t // hp.SUB_BLOCK if dense else blocks
-    return total
+        packed += 0 if dense else blocks
+    return total, packed
 
 
 @pytest.mark.parametrize("f, num_bins", [(28, 255), (130, 255), (28, 300)])
@@ -125,10 +170,28 @@ def test_blocks_multiplied_is_what_the_tiles_round_up_to(case, f, num_bins):
     np.testing.assert_array_equal(np.asarray(got), counts)
     blocks = hp.blocks_multiplied(got, (n, f), num_bins, 1024)
     assert blocks.dtype == jnp.int32
-    assert int(blocks) == _blocks_in_numpy(slot, f, num_bins, 1024)
+    assert int(blocks) == _blocks_in_numpy(slot, f, num_bins, 1024)[0]
     # the least a pass can multiply: its rows in whole sub-blocks, tile by
     # tile; the most: every tile that holds a row of it, whole
     least = sum(-(-c // hp.SUB_BLOCK) for c in counts)
     assert least <= int(blocks) <= 8 * sum(c > 0 for c in counts)
-    if f == 130 and num_bins == 255 and max(counts) <= 768:
+    if num_bins == 255 and max(counts) <= 768:  # no tile is dense
         assert int(blocks) == least
+
+
+@pytest.mark.parametrize("f, num_bins", [(28, 255), (130, 255), (28, 300)])
+@pytest.mark.parametrize("case", sorted(PACKING_CASES))
+def test_blocks_packed_is_the_packed_tiles_share_of_them(case, f, num_bins):
+    """What ``train_hist_blocks_packed_total`` adds up: the sub-blocks of
+    the tiles that pack, by the same rule, against numpy's from the slots;
+    the rest of the blocks multiplied are whole dense tiles."""
+    n, _, slot = _packing_data(PACKING_CASES[case], 1, 4, 8)
+    counts = hp.pass_counts(jnp.asarray(slot >= 0), 1024)
+    packed = hp.blocks_packed(counts, (n, f), num_bins, 1024)
+    assert packed.dtype == jnp.int32
+    total, want = _blocks_in_numpy(slot, f, num_bins, 1024)
+    assert int(packed) == want
+    assert (total - want) % (1024 // hp.SUB_BLOCK) == 0
+    assert int(hp.blocks_multiplied(counts, (n, f), num_bins, 1024)) == total
+    if num_bins > 256:
+        assert want == 0

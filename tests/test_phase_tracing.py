@@ -192,12 +192,22 @@ def test_hist_blocks_counts_the_sub_blocks_the_kernel_multiplied():
 
     every, packed = blocks(np.ones(n, bool)), blocks(small)
     assert int(tree.hist_blocks) == every + packed
+    # the root's two whole tiles took the dense product and its ragged
+    # third packed its 300 rows; every tile of the second pass packed
+
+    def in_packed_tiles(rows):
+        return int(hp.blocks_packed(
+            hp.pass_counts(jnp.asarray(rows)), bins.shape, 128))
+
+    assert in_packed_tiles(np.ones(n, bool)) == 3
+    assert in_packed_tiles(small) == packed
+    assert int(tree.hist_blocks_packed) == 3 + packed
     assert packed == sum(
         -(-int(small[i:i + hp.ROW_TILE].sum()) // hp.SUB_BLOCK)
         for i in range(0, n, hp.ROW_TILE)) < every / 3
     # the other routes run no kernel and count none
     tree, _ = _grow(*_toy(), num_leaves=2)
-    assert int(tree.hist_blocks) == 0
+    assert int(tree.hist_blocks) == int(tree.hist_blocks_packed) == 0
 
 
 def _booster(mode, n=4000):
@@ -211,7 +221,8 @@ def _booster(mode, n=4000):
 
 COUNTERS = ("train_hist_passes_total", "train_hist_rows_streamed_total",
             "train_hist_rows_needed_total",
-            "train_hist_blocks_multiplied_total")
+            "train_hist_blocks_multiplied_total",
+            "train_hist_blocks_packed_total")
 
 
 def test_three_updates_and_a_flush_move_the_three_counters():
@@ -234,6 +245,8 @@ def test_three_updates_and_a_flush_move_the_three_counters():
     # the CPU's route runs no kernel: the counter is there and reads 0
     assert got["train_hist_blocks_multiplied_total"] == sum(
         int(arrays.hist_blocks) for arrays, _, _ in pending) == 0
+    assert got["train_hist_blocks_packed_total"] == sum(
+        int(arrays.hist_blocks_packed) for arrays, _, _ in pending) == 0
     assert got["train_hist_rows_needed_total"] == sum(
         work.tree_rows(t, n) for t in trees)
     assert obs.counter("train_boost_rounds_total").value == 3
