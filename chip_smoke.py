@@ -49,9 +49,11 @@ from lightgbm_tpu.utils import degrade
 from lightgbm_tpu.utils.compile_cache import use_compile_cache
 from lightgbm_tpu.utils.sanitizer import CompileCounter
 
-# (features, bins, leaves): the narrow block of the primary shape and the
-# Epsilon block (fifteen 128-feature kernel calls plus a ragged 80)
-KERNEL_SHAPES = ((28, 256, 31), (2000, 256, 255))
+# (features, bins, leaves): the narrow block of the primary shape, the
+# Epsilon block (fifteen 128-feature kernel calls plus a ragged 80), and the
+# narrow block over 256 bins, where no tile packs and every tile with a row
+# in the pass takes the dense product
+KERNEL_SHAPES = ((28, 256, 31), (2000, 256, 255), (28, 300, 31))
 NARROW_ROWS = 1_000_000
 NARROW_FEATURES = 28
 # host binning is ~0.4 us per value: 400k x 2000 would take minutes, so the
@@ -150,6 +152,7 @@ def leg_kernels(n_rows: int = 4096, shapes=KERNEL_SHAPES) -> dict:
         hq = rng.randint(0, 17, size=n_rows).astype(np.int8)
         # nine rows in ten of the first half, one in ten of the second: a
         # row tile that takes the kernel's dense product and one that packs
+        # (up to 256 bins; above, both take the dense product)
         mask = rng.rand(n_rows) < np.where(
             np.arange(n_rows) < n_rows // 2, 0.9, 0.1)
         m = mask.astype(np.float64)
@@ -160,9 +163,9 @@ def leg_kernels(n_rows: int = 4096, shapes=KERNEL_SHAPES) -> dict:
         tile_q = recommended_leaf_tile(b, f, leaves, quantized=True)
         leaf_q = rng.randint(0, tile_q, size=n_rows).astype(np.int32)
         want_q = hist_oracle(bins, [gq * m, hq * m, m], leaf_q, tile_q, b)
-        # the packed tile reads its bins feature-major: from the shadow the
-        # call builds of (N, F), and from one handed in as a grower hands
-        # Dataset.bins_device_t
+        # the kernel reads its bins feature-major, dense and packed tiles
+        # alike: from the shadow the call builds of (N, F), and from one
+        # handed in as a grower hands Dataset.bins_device_t
         first = None  # the times reported are the first calls', which compile
         for shadow in (None, bins_shadow(jnp.asarray(bins))):
             t0 = time.perf_counter()

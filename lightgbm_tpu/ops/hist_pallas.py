@@ -9,9 +9,12 @@ across a sequential row-tile grid (SURVEY.md §10.1 strategy 2): per feature,
 onehot(bin) in {0,1}^(T,B) is contracted against an (NC, T) payload.
 
 The kernel's input contract: it is handed what already lies in HBM and
-forms the rest in VMEM.  (1) The bin matrix (N, F), unpadded and unsliced:
-the ragged last row tile is masked by row index in the kernel, and at
-F > 128 each 128-feature chunk's call picks its columns in its index map.
+forms the rest in VMEM.  (1) The bins once, FEATURE-MAJOR on the kernel's
+own row tiles, the SHADOW (``bins_shadow``: (F, row tiles, T / 128, 128);
+``basic.Dataset.bins_device_t`` keeps it on the device for the partition,
+and the rounds grower hands that in), and nothing N-sized copied: the
+ragged last row tile is masked by row index in the kernel, and at F > 128
+each 128-feature chunk's call picks its features in its index map.
 (2) A per-tree BASE, channel-first (8, N): every row's payload channels
 (``payload_base``: g_hi, h_hi, m, g_lo, h_lo for 'f32'; g, h, m for 'bf16';
 ``payload_base_quantized``: gq, hq, m int8) with m the in-bag mask.  It
@@ -22,30 +25,24 @@ over the leaf lanes with a 0/1 matrix on the MXU and keeps each row's own
 leaf by a select: ``lane[l*ncl + c, t] = base[c, t] if slot[t] == l else
 0``.  Nothing N-sized is built, padded or copied per pass (before PR 26 a
 lane-expanded (N, tile x ncl) payload and row-padded bins were, every pass:
-39.6% of a Higgs tree's device time).  What XLA still does by itself, once
-a tree: it keeps an (N, F) int16 matrix feature-major on the device and
-copies it to the row-major layout this kernel's operand asks for.
-(4) One int32 COUNT per row tile and pass (``pass_counts``): how many of
-the tile's rows have a slot.  It arrives as a prefetched scalar and decides
-what the tile costs.  (5) Since PR 37, up to 256 bins, the bins a second
-time, FEATURE-MAJOR on the kernel's own row tiles (``bins_shadow``: (F,
-row tiles, T / 128, 128); ``basic.Dataset.bins_device_t`` keeps it on the
-device for the partition, and the rounds grower hands that in): what the
-packed tiles read.  A dense tile reads the row-major block as before.
+39.6% of a Higgs tree's device time).  (4) One int32 COUNT per row tile
+and pass (``pass_counts``): how many of the tile's rows have a slot.  It
+arrives as a prefetched scalar and decides what the tile costs.
 
 The cost of a pass follows the rows in the pass, not N (since PR 29; before,
 every pass multiplied every row, 35 times a 255-leaf tree on 10.5M rows
 where 3.9 passes' worth were needed).  Per row tile: no row in the pass,
 the tile's DMA and nothing else; otherwise the tile's rows of the pass are
 packed to the front in VMEM (a 0/1 place matrix on the MXU moves bins,
-channels and slot in one product: a lane group's bins from the shadow
-stacked under its channels and its slot, rows on the lanes in all three)
-and the one-hot build and product run over whole SUB_BLOCK-row sub-blocks
-of packed rows only, the one-hots with the bins on the sublanes; a tile so
-full that packing would cost more takes the dense product over all its
-rows (``_tile_cost``).  Above 256 bins nothing is packed (bfloat16 would
-not hold the bins on the move): every tile with a row in the pass takes
-the dense product, and the call takes no shadow.
+channels and slot in one product: a lane group's bins stacked under its
+channels and its slot, rows on the lanes in all three) and the one-hot
+build and product run over whole SUB_BLOCK-row sub-blocks of packed rows
+only; a tile so full that packing would cost more takes the dense product
+over all its rows (``_tile_cost``).  Dense or packed, a feature's one-hot
+is its row of bins against an iota over the sublanes, and the product
+contracts the lanes of both.  Above 256 bins nothing is packed (bfloat16
+would not hold the bins on the move): every tile with a row in the pass
+takes the dense product.
 
 Measured on a v5e, the kernel alone (its own time in a trace), ms a pass by
 the share of rows in the pass, rows drawn at random (PERF.md section 6:
@@ -59,11 +56,19 @@ quarters full is dense, 69.7 and 189.5 at 100%; before PR 29: 70.5 and
     10.5M x 28, PR 37   69.7   46.0   25.7   14.2    7.9    7.9
     400k x 2000, PR 29  190.2  129.1  69.5   35.7   17.1   17.0
     400k x 2000, PR 37  172.6  94.1   52.4   28.1   15.6   15.6
+    10.5M x 28, dense
+      from the shadow   51.3      -      -      -    7.7      -
+    400k x 2000, dense
+      from the shadow  140.7      -      -      -   15.5      -
 
-A 2,048-row tile pays 0.60 us before its first sub-block and 0.99 us a
-packed sub-block at 28 features (a dense sub-block 0.85), 2.35 and 3.33 us
-at 128 features (dense 3.78).  Until PR 37 a packed sub-block cost 1.65 and
-4.74 us: its bins were moved to (SUB_BLOCK, FB), rows on the sublanes, by a
+(the last two rows every tile at 100% dense, its one-hots lane-major from
+the shadow, where it had taken them from the row-major block's columns at
+0.85 us a sub-block at 28 features and 3.75 at 128, and without the
+row-major block's DMA at 4%).  A 2,048-row tile pays 0.60 us before its
+first sub-block and 0.99 us a packed sub-block at 28 features (a dense
+sub-block 0.625), 2.35 and 3.33 us at 128 features (dense 2.81).  Before
+the stacked move a packed sub-block cost 1.65 and 4.74 us: its bins were
+moved to (SUB_BLOCK, FB), rows on the sublanes, by a
 product of their own beside the channels', and every feature's one-hot took
 a column out of that block and spread it over the lanes.  That, and not the
 two products, was what a packed sub-block paid over a dense one: one product
@@ -190,26 +195,29 @@ _PACK_MAX_BINS = 256  # bfloat16 holds a bin up to here: packed rows' bins
 SUB_BLOCK = 128  # rows of a packed sub-block: what a tile pays in whole
 
 
-def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
+def _direct_kernel(chunk_ref, cnt_ref, bins_ref, base_ref, slot_ref, out_ref,
+                   *, n, tile, ncl, pack):
     """Grid (1, row_tiles): the accumulator lives across the row sweep.
-    ``chunk_ref`` is the scalar the bins' index maps picked their 128
-    features by; the body has no use for it.  ``refs`` are the feature-major
-    bins ``shadow_ref`` (FB, 1, T / 128, 128), handed where tiles pack (up
-    to 256 bins), then ``base_ref``, ``slot_ref`` and ``out_ref``.
-    ``cnt_ref[i]`` is the number of rows of row tile ``i`` that have a slot
-    in this pass (or more: see :func:`pass_counts`), and decides what the
-    tile costs:
+    ``chunk_ref`` is the scalar the bins' index map picked its 128 features
+    by; the body has no use for it.  ``bins_ref`` is the tile's block of the
+    feature-major shadow, (FB, 1, T / 128, 128): a feature's rows lie on the
+    lanes, 128 to a lane group.  ``pack`` (static): whether tiles may pack,
+    up to 256 bins.  ``cnt_ref[i]`` is the number of rows of row tile ``i``
+    that have a slot in this pass (or more: see :func:`pass_counts`), and
+    decides what the tile costs:
 
     * 0: the tile's DMA and nothing else.
     * so many that packing would cost more (:func:`_tile_cost`), or above
-      256 bins: the dense product.  The dot's (NC, T) operand is formed from
-      the base block (8, T) and the slot ids (1, T),
-      ``lane[l * ncl + c, t] = base[c, t] if slot[t] == l else 0``: the
-      channels are spread over the leaves by a 0/1 matrix on the MXU (exact:
-      every channel is bfloat16-exact or an int8, or is rounded to bfloat16
-      here as it would be at the dot), then a select by slot keeps each
-      row's own leaf; per feature a (T, B) one-hot of the bins is contracted
-      against it.
+      256 bins: the dense product, in pieces of ``_DENSE_ROWS`` rows.  The
+      dot's (NC, D) operand is formed from the base block (8, D) and the
+      slot ids (1, D), ``lane[l * ncl + c, t] = base[c, t] if slot[t] == l
+      else 0``: the channels are spread over the leaves by a 0/1 matrix on
+      the MXU (exact: every channel is bfloat16-exact or an int8, or is
+      rounded to bfloat16 here as it would be at the dot), then a select by
+      slot keeps each row's own leaf.  A feature's bins of the piece are its
+      lane groups of the block laid side by side, (1, D); its one-hot has
+      the bins on the sublanes, (B, D), and the product contracts the lanes
+      of both: one dot sums the piece's D rows.
     * otherwise the tile's rows that have a slot are PACKED to the front
       and only ``ceil(cnt / SUB_BLOCK)`` sub-blocks of SUB_BLOCK rows go
       through the one-hot product.  A row's place among the packed rows is
@@ -218,19 +226,16 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
       sub-block a 0/1 matrix P (SUB_BLOCK, T) with ``P[s, t] = 1`` where
       row ``t`` takes place ``s`` moves the bins, the channels and the slot
       on the MXU in one product a lane group, the group's bins (FB, 128)
-      from the shadow stacked under its base rows (exact: 0/1 times values
-      that bfloat16 holds, bins up to 256 among them, one nonzero term a
-      sum), and the same lanes are then built from the packed block, its
-      one-hots with the bins on the sublanes and the rows on the lanes, as
-      the packed block lies.  Places past the tile's count hold zeros and
-      add nothing.  The sums of a tile are taken sub-block by sub-block, so
-      a float histogram need not equal the dense product's digit for digit.
+      stacked under its base rows (exact: 0/1 times values that bfloat16
+      holds, bins up to 256 among them, one nonzero term a sum), and the
+      same one-hot product as a dense piece's then runs over the packed
+      block.  Places past the tile's count hold zeros and add nothing.  The
+      sums of a tile are taken sub-block by sub-block, so a float histogram
+      need not equal the dense product's digit for digit.
 
     Rows at or past ``n`` take slot -1 and their base is zeroed by a select
     before any product: what the ragged last block holds past the arrays'
     end never reaches the accumulator."""
-    *shadow_refs, base_ref, slot_ref, out_ref = refs
-    pack = bool(shadow_refs)
     i = pl.program_id(1)
 
     # the revisited output block IS the accumulator (a separate VMEM
@@ -268,13 +273,12 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
     spread = bf16((r - r // ncl * ncl == c) & (r < tile * ncl))  # (NC, K) 0/1
     leaf_of = jax.lax.broadcasted_iota(jnp.int32, (NC, 1), 0) // ncl
 
-    def accumulate(base, slot, bins_i32, rows_on_lanes=False):
-        """base (K, W) bfloat16, slot (1, W), bins int32: the one-hot
-        product of W rows into the accumulator.  The bins lie (W, FB) as a
-        dense tile reads them, or (FB, W), rows on the lanes, as a packed
-        tile moves them: its one-hots then have the bins on the sublanes (a
-        feature's row against an iota, no column taken and spread over the
-        lanes) and the product contracts the lanes of both."""
+    def accumulate(base, slot, bins_i32):
+        """base (K, W) bfloat16, slot (1, W), bins (FB, W) int32, the rows
+        on the lanes in all three: the one-hot product of W rows into the
+        accumulator.  A feature's one-hot is its row of bins against an iota
+        over the sublanes, (B, W), and the product contracts the lanes of
+        both."""
         W = slot.shape[1]
         wide = jnp.dot(spread, base,
                        preferred_element_type=jnp.float32)  # (NC, W)
@@ -282,31 +286,26 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
         if dtype == jnp.int8:
             lane = lane.astype(jnp.int32)
         lane = lane.astype(dtype)
-        iota_b = jax.lax.broadcasted_iota(  # hoisted
-            jnp.int32, (B, W) if rows_on_lanes else (W, B),
-            0 if rows_on_lanes else 1)
+        iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, W), 0)  # hoisted
         for f in range(FB):
-            if rows_on_lanes:
-                oh = (bins_i32[f:f + 1, :] == iota_b).astype(dtype)  # (B, W)
-                out_ref[f] += jax.lax.dot_general(
-                    lane, oh, (((1,), (1,)), ((), ())),
-                    preferred_element_type=out_ref.dtype)
-            else:
-                oh = (bins_i32[:, f][:, None] == iota_b).astype(dtype)
-                out_ref[f] += jnp.dot(lane, oh,
-                                      preferred_element_type=out_ref.dtype)
+            oh = (bins_i32[f:f + 1, :] == iota_b).astype(dtype)  # (B, W)
+            out_ref[f] += jax.lax.dot_general(
+                lane, oh, (((1,), (1,)), ((), ())),
+                preferred_element_type=out_ref.dtype)
 
     @pl.when(dense)
     def _():
         # in pieces of _DENSE_ROWS, so that the unrolled one-hots (and the
         # time Mosaic takes to compile them) do not grow with the row tile
         D = _DENSE_ROWS if T % _DENSE_ROWS == 0 else T
+        GD = D // 128
 
         def piece(p, carry):
-            r0 = pl.multiple_of(p * D, D)
-            slot, base = operands(r0, D)
-            accumulate(base.astype(jnp.bfloat16), slot,
-                       bins_ref[pl.ds(r0, D), :].astype(jnp.int32))
+            slot, base = operands(pl.multiple_of(p * D, D), D)
+            groups = bins_ref[:, 0, pl.ds(pl.multiple_of(p * GD, GD), GD),
+                              :].astype(jnp.int32)  # (FB, GD, 128)
+            accumulate(base.astype(jnp.bfloat16), slot, jnp.concatenate(
+                [groups[:, g, :] for g in range(GD)], axis=1))
             return carry
 
         jax.lax.fori_loop(0, T // D, piece, 0)
@@ -337,9 +336,8 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
                          preferred_element_type=jnp.float32)  # groups before
         place = jnp.where(taken > 0, rank + before, -1.0).astype(jnp.int32)
         # a lane group's stack (K + FB, 128): the base's rows over the
-        # group's bins from the shadow, rows on the lanes in both
-        shadow_ref, = shadow_refs
-        bins_t = shadow_ref[:, 0].astype(jnp.int32).astype(jnp.float32)
+        # group's bins, rows on the lanes in both
+        bins_t = bins_ref[:, 0].astype(jnp.int32).astype(jnp.float32)
         stack = [
             jnp.concatenate([base[:, g * 128:(g + 1) * 128], bins_t[:, g, :]],
                             axis=0).astype(jnp.bfloat16)
@@ -355,7 +353,7 @@ def _direct_kernel(chunk_ref, cnt_ref, bins_ref, *refs, n, tile, ncl):
                     preferred_element_type=jnp.float32)
             accumulate(moved[:K].astype(jnp.bfloat16),
                        moved[K - 1:K].astype(jnp.int32),
-                       moved[K:].astype(jnp.int32), rows_on_lanes=True)
+                       moved[K:].astype(jnp.int32))
             return carry
 
         jax.lax.fori_loop(0, blocks, block, 0)
@@ -392,19 +390,21 @@ def _tile_cost(cnt, row_tile: int, feat_block: int, pack: bool):
     whole sub-blocks, whether it takes the dense product).  The kernel asks
     with its scalar, :func:`blocks_multiplied` with every tile's count.
 
-    Packing is taken where it is the cheaper of the two, in tenths of one
-    sub-block's dense one-hot product for one feature (v5e, PERF.md section
-    6, PR 37: the kernel's own time in a trace, 28 and 128 features,
-    2,048-row tiles of exactly 1, 2, 4, 6, 8, 10, 12 and 16 sub-blocks, a
-    straight line in both): a dense tile costs ``row_tile / SUB_BLOCK``
-    sub-blocks of ``feat_block`` features; a packed sub-block costs eight
-    tenths of its features (its one-hots take a feature's row and no
-    column) and six tenths for every 128-row lane group of the tile (its
-    share of the place matrix and of the move), and the tile six tenths a
-    feature and two a group before its first sub-block (the stacks read out
-    of the shadow, the rank).  By cost alone a 2,048-row tile would pack up
-    to 13 sub-blocks at 28 features, 14 at 39 and always at 128 (a full
-    tile packed costs 0.92 of the dense product there).
+    Packing is taken where it is the cheaper of the two, in units of about
+    3 ns, a tenth of what one feature of a sub-block cost the dense product
+    while it took its one-hots from columns (0.85 us a sub-block at 28
+    features, 3.78 at 128; v5e, the kernel's own time in a trace, PERF.md
+    section 6): a packed sub-block costs 8 a feature (its one-hots) and 6
+    for every 128-row lane group of the tile (its share of the place matrix
+    and of the move), and the tile 6 a feature and 2 a group before its
+    first sub-block (the stacks, the rank), fitted to 2,048-row tiles of
+    exactly 1, 2, 4, 6, 8, 10, 12 and 16 sub-blocks at 28 and 128 features,
+    a straight line in both; a dense tile costs ``row_tile / SUB_BLOCK``
+    sub-blocks of ``feat_block`` features at 7.4 a feature, its one-hots
+    formed from the shadow's rows (0.625 us a sub-block at 28 features,
+    2.81 at 128, a whole tile's rows in the pass).  By cost alone a
+    2,048-row tile packs up to 9 sub-blocks at 28 features, 10 at 39 and
+    12 at 128.
 
     A tile more than three quarters full takes the dense product whatever
     it costs: a packed tile's sums reach the float32 accumulator in pieces
@@ -417,8 +417,9 @@ def _tile_cost(cnt, row_tile: int, feat_block: int, pack: bool):
     blocks = (cnt + SUB_BLOCK - 1) // SUB_BLOCK
     dense = cnt > 0
     if pack:
-        dense &= ((blocks * (8 * feat_block + 6 * groups)
-                   + 6 * feat_block + 2 * groups >= 10 * whole * feat_block)
+        dense &= ((10 * (blocks * (8 * feat_block + 6 * groups)
+                         + 6 * feat_block + 2 * groups)
+                   >= 74 * whole * feat_block)
                   | (4 * blocks > 3 * whole))
     return blocks, dense
 
@@ -460,59 +461,55 @@ def _shadow_shape(n: int, f: int, row_tile: int) -> tuple:
 def bins_shadow(bins: jnp.ndarray, row_tile: int = ROW_TILE) -> jnp.ndarray:
     """(N, F) bins -> (F, row_tiles, T / 128, 128), T the kernel's row tile:
     the bins feature-major on the kernel's own tiles, the rows past N bin 0
-    (the kernel gives them slot -1 by row index).  What the packed tiles
-    read; at the default row tile it is what ``basic.Dataset.bins_device_t``
+    (the kernel gives them slot -1 by row index).  What the kernel reads;
+    at the default row tile it is what ``basic.Dataset.bins_device_t``
     keeps on the device, and a caller that holds that hands it in."""
     shape = _shadow_shape(*bins.shape, row_tile)
     rows = shape[1] * shape[2] * 128
     return jnp.pad(bins.T, ((0, 0), (0, rows - bins.shape[0]))).reshape(shape)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "row_tile", "tile", "ncl"))
+@functools.partial(jax.jit, static_argnames=("num_bins", "tile", "ncl"))
 def _hist_pallas_raw(
-    bins: jnp.ndarray,  # (N, F) int16/int32, as it lies in HBM
-    bins_t: jnp.ndarray,  # bins_shadow(bins, row_tile); None above 256 bins
+    bins_t: jnp.ndarray,  # (F, row_tiles, T / 128, 128): bins_shadow(bins, T)
     base: jnp.ndarray,  # (_BASE_ROWS, N) f32 or int8: the per-tree channels
     slot: jnp.ndarray,  # (1, N) int32: the row's leaf of this pass, or -1
     chunk: jnp.ndarray,  # (1,) int32: which block of _FEAT_BLOCK features
     counts: jnp.ndarray,  # (row_tiles,) int32: pass_counts of slot >= 0
     *,
     num_bins: int,
-    row_tile: int,
     tile: int,
     ncl: int,
 ):
     """-> (min(F, 128), NC, B): lane ``l * ncl + c`` of the chunk's feature
-    ``f`` holds channel ``c`` of the rows in slot ``l``, by bin.  Nothing
-    N-sized is padded, sliced or copied on the way in: the ragged last row
-    tile is masked in the kernel, and the call takes the whole bin matrix
-    and picks its 128 columns in its index map, and the shadow's 128 features
-    likewise.  The chunk is a prefetched scalar and not a static, so that the
-    sixteen calls of a pass at F = 2000 are one traced and lowered function:
-    with sixteen index maps Epsilon's first ``update()`` took 95 s instead of
-    20.  ``row_tile`` is rounded to whole 128-row groups (``_row_tile``)."""
-    n, f = bins.shape
+    ``f`` holds channel ``c`` of the rows in slot ``l``, by bin.  The bins
+    are one operand, the feature-major shadow, whose tiles are the kernel's
+    row tiles; N is the base's.  Nothing N-sized is padded, sliced or copied
+    on the way in: the ragged last row tile is masked in the kernel, and the
+    call takes the whole shadow and picks its 128 features in its index map.
+    The chunk is a prefetched scalar and not a static, so that the sixteen
+    calls of a pass at F = 2000 are one traced and lowered function: with
+    sixteen index maps Epsilon's first ``update()`` took 95 s instead of 20."""
+    f, tiles, groups, _ = bins_t.shape
+    n = base.shape[1]
     B = _round_up(max(num_bins, 8), 8)
     quantized = base.dtype == jnp.int8
     nc = _round_up(tile * ncl, 32 if quantized else 8)
     FB = min(f, _FEAT_BLOCK)
-    row_tile = _row_tile(n, row_tile)
+    row_tile = groups * 128
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    shadow = [] if bins_t is None else [bins_t]
 
     # no scope and no name= here: XLA names the custom call after the
     # innermost component of its op_name, and the benchmark's kernel metrics
     # find it in a device trace as ``_hist_pallas_raw.N`` (_leaf_histograms)
     return pl.pallas_call(
-        functools.partial(_direct_kernel, n=n, tile=tile, ncl=ncl),
+        functools.partial(_direct_kernel, n=n, tile=tile, ncl=ncl,
+                          pack=num_bins <= _PACK_MAX_BINS),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(1, pl.cdiv(n, row_tile)),
+            grid=(1, tiles),
             in_specs=[
-                vmem((row_tile, FB), lambda _, i, c, k: (i, c[0])),
-                *[vmem((FB, 1, row_tile // 128, 128),
-                       lambda _, i, c, k: (c[0], i, 0, 0)) for _ in shadow],
+                vmem((FB, 1, groups, 128), lambda _, i, c, k: (c[0], i, 0, 0)),
                 vmem((_BASE_ROWS, row_tile), lambda _, i, c, k: (0, i)),
                 vmem((1, row_tile), lambda _, i, c, k: (0, i)),
             ],
@@ -522,11 +519,11 @@ def _hist_pallas_raw(
             (FB, nc, B), jnp.int32 if quantized else jnp.float32),
         cost_estimate=pl.CostEstimate(
             flops=2 * n * FB * B * nc,
-            bytes_accessed=n * (FB * bins.dtype.itemsize * (1 + len(shadow))
+            bytes_accessed=n * (FB * bins_t.dtype.itemsize
                                 + _BASE_ROWS * base.dtype.itemsize + 4),
             transcendentals=0,
         ),
-    )(chunk, counts, bins, *shadow, base, slot)
+    )(chunk, counts, bins_t, base, slot)
 
 
 def _split_bf16x2(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -585,12 +582,10 @@ def _leaf_histograms(bins, base, mask, leaf_id, leaf_base, tile, num_bins,
     leaf ``leaf_base + l``.  ``counts``: :func:`pass_counts` of ``mask`` at
     this ``row_tile``, from a caller that made them already.  ``bins_t``:
     :func:`bins_shadow` of ``bins`` at this ``row_tile``, from a caller that
-    holds it; one on other tiles (a ``Dataset``'s, for fewer rows than its
-    row tile) is built anew like none."""
-    if num_bins > _PACK_MAX_BINS:
-        bins_t = None  # no tile packs
-    elif bins_t is None or bins_t.shape != _shadow_shape(*bins.shape,
-                                                         row_tile):
+    holds it, what the kernel reads; none, or one on other tiles (a
+    ``Dataset``'s, for fewer rows than its row tile), is built here from
+    ``bins``, which the kernel does not read."""
+    if bins_t is None or bins_t.shape != _shadow_shape(*bins.shape, row_tile):
         with phase_scope("hist.payload"):
             bins_t = bins_shadow(bins, row_tile)
     with phase_scope("grow.slots"):
@@ -613,10 +608,9 @@ def _leaf_histograms(bins, base, mask, leaf_id, leaf_base, tile, num_bins,
     # in a trace stays ``_hist_pallas_raw.N``
     with phase_scope("hist.kernel"):
         outs = [
-            _hist_pallas_raw(bins, bins_t, base, slot,
+            _hist_pallas_raw(bins_t, base, slot,
                              jnp.full((1,), j, jnp.int32), counts,
-                             num_bins=num_bins, row_tile=row_tile, tile=tile,
-                             ncl=ncl)
+                             num_bins=num_bins, tile=tile, ncl=ncl)
             for j in range(pl.cdiv(f, _FEAT_BLOCK))]
     with phase_scope("hist.unpack"):
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
@@ -649,7 +643,8 @@ def histogram_pallas_multi(
     passes over the same gradients builds ``base`` once and hands it in;
     without it, it is built here.  Likewise ``counts``, for a caller that
     keeps them (the rounds grower counts what the kernel multiplied), and
-    ``bins_t``, the feature-major bins that the packed tiles read.
+    ``bins_t``, the feature-major bins that the kernel reads (without it
+    they are built here from ``bins``, which the kernel does not read).
     This is the TPU replacement for per-leaf row-index histogramming
     (reference: Dataset::ConstructHistograms over DataPartition indices).
     """

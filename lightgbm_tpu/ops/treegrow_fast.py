@@ -379,9 +379,9 @@ def _grow_fast_impl(
     # channels depend on grad, hess and row_mask alone, so they are laid out
     # once here and every pass of the tree, the root's too, takes the same
     # array.  XLA does not hoist a loop-invariant N-sized build by itself.
-    # Likewise the feature-major bins that the kernel's packed tiles read:
-    # the shadow where it is the shadow of the matrix the kernel reads, else
-    # built here, once a tree.
+    # Likewise the bins as the kernel reads them, feature-major: the shadow
+    # where it is the shadow of the matrix histogrammed, else built here,
+    # once a tree.  The kernel reads no (N, F) matrix.
     hist_base = hist_bins_t = None
     if use_pallas and num_bins > 64:
         with phase_scope("hist.payload"):

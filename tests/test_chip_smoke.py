@@ -52,9 +52,11 @@ def test_last_stdout_line_is_the_contract_object(monkeypatch, capsys):
 def test_kernels_match_oracle_in_interpret_mode():
     with pltpu.force_tpu_interpret_mode():
         out = chip_smoke.leg_kernels(
-            n_rows=512, shapes=((28, 256, 31), (130, 64, 255)))
+            n_rows=512, shapes=((28, 256, 31), (130, 64, 255), (28, 300, 31)))
     assert out["28x256"]["leaf_tile_f32"] == 8
     assert out["130x64"]["leaf_tile_int8"] == 20
+    assert out["28x300"]["leaf_tile_f32"] == 8
+    assert chip_smoke.KERNEL_SHAPES[-1][1] > 256  # a shape where no tile packs
 
 
 def test_train_narrow_leg(narrow):

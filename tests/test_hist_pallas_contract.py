@@ -109,37 +109,73 @@ def test_a_base_built_once_serves_every_pass_of_a_tree(precision):
 
 @pytest.mark.parametrize("quantized", [False, True])
 def test_rows_past_n_never_reach_the_accumulator(quantized):
-    """The last row tile is read to its end: what lies there (here NaN, or
-    127s, in a live slot) is held out by the select on the row's index."""
+    """The last row tile is read to its end.  What lies there is held out by
+    the select on the row's index: the shadow's rows past N (here the
+    largest bin, where ``bins_shadow`` puts bin 0) and the ragged blocks of
+    the base and the slots (the interpreter reads NaN, or 127s, and the
+    largest int32, a slot >= 0, past an array's end)."""
     tile, ncl, f = 2, 3 if quantized else 6, 28
     bins, grad, hess, inbag, leaf = _data(f, tile)
-    n_read = 2048  # what two 1024-row tiles cover
-    slot = np.zeros((1, n_read), np.int32)
-    slot[0, :N] = np.where(inbag, np.maximum(leaf, 0), -1)
+    slot = np.where(inbag, np.maximum(leaf, 0), -1).astype(np.int32)[None]
     if quantized:
-        base = np.full((8, n_read), 127, np.int8)
-        base[:, :N] = np.asarray(hp.payload_base_quantized(
-            jnp.asarray(np.round(grad * 20).astype(np.int8)),
-            jnp.asarray(np.round(hess * 20).astype(np.int8)),
-            jnp.asarray(inbag)))
+        gq = np.round(grad * 20).astype(np.int8)
+        hq = np.round(hess * 20).astype(np.int8)
+        base = hp.payload_base_quantized(jnp.asarray(gq), jnp.asarray(hq),
+                                         jnp.asarray(inbag))
+        chans = [gq.astype(np.float64), hq.astype(np.float64)]
     else:
-        base = np.full((8, n_read), np.nan, np.float32)
-        base[:, :N] = np.asarray(hp.payload_base(
-            jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(inbag)))
-    kw = dict(num_bins=B, row_tile=1024, tile=tile, ncl=ncl)
+        base = hp.payload_base(jnp.asarray(grad), jnp.asarray(hess),
+                               jnp.asarray(inbag))
+        chans = [grad.astype(np.float64), hess.astype(np.float64)]
+    shadow = np.asarray(hp.bins_shadow(jnp.asarray(bins), 1024))
+    assert shadow.shape == (f, 2, 8, 128)
+    filled = shadow.reshape(f, -1).copy()
+    filled[:, N:] = B - 1
+    kw = dict(num_bins=B, tile=tile, ncl=ncl)
     chunk = jnp.zeros((1,), jnp.int32)
     with pltpu.force_tpu_interpret_mode():
-        counts = hp.pass_counts(jnp.asarray(slot[0, :N] >= 0), 1024)
-        shadow = hp.bins_shadow(jnp.asarray(bins), 1024)
-        long = np.asarray(hp._hist_pallas_raw(
-            jnp.asarray(bins), shadow, jnp.asarray(base), jnp.asarray(slot),
-            chunk, counts, **kw))
-        exact = np.asarray(hp._hist_pallas_raw(
-            jnp.asarray(bins), shadow, jnp.asarray(base[:, :N]),
-            jnp.asarray(slot[:, :N]), chunk, counts, **kw))
-    assert np.isfinite(long.astype(np.float64)).all()
-    np.testing.assert_array_equal(long, exact)
-    assert long[:, :tile * ncl].any()
+        counts = hp.pass_counts(jnp.asarray(slot[0] >= 0), 1024)
+        got = [np.asarray(hp._hist_pallas_raw(
+            jnp.asarray(s.reshape(shadow.shape)), base, jnp.asarray(slot),
+            chunk, counts, **kw)) for s in (shadow, filled)]
+    assert np.isfinite(got[0].astype(np.float64)).all()
+    np.testing.assert_array_equal(got[1], got[0])
+    want = _oracle(bins, chans + [np.ones(N)], slot[0], tile)
+    lanes = got[0][:, :tile * ncl, :B].reshape(f, tile, ncl, B)
+    if quantized:
+        np.testing.assert_array_equal(lanes.transpose(1, 2, 0, 3),
+                                      want.astype(np.int64))
+    else:  # the channels hi + lo
+        sums = lanes[:, :, [0, 1, 2]] + np.concatenate(
+            [lanes[:, :, [3, 4]], np.zeros_like(lanes[:, :, :1])], axis=2)
+        np.testing.assert_allclose(sums.transpose(1, 2, 0, 3), want,
+                                   rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("num_bins", [255, 300])
+def test_the_kernel_takes_the_shadow_and_no_row_major_bins(num_bins):
+    """The bins reach the kernel once, feature-major, at every bin count:
+    ``_hist_pallas_raw`` has no ``(N, F)`` parameter, and in a call made
+    with ``(N, F)`` bins the one ``int16`` operand of the ``pallas_call``
+    is the shadow built of them."""
+    import inspect
+
+    params = list(inspect.signature(hp._hist_pallas_raw).parameters)
+    assert params[:5] == ["bins_t", "base", "slot", "chunk", "counts"]
+    bins, grad, hess, inbag, leaf = _data(28, 4)
+    with pltpu.force_tpu_interpret_mode():
+        jaxpr = jax.make_jaxpr(
+            lambda *a: hp.histogram_pallas_multi(*a, 0, 4, num_bins,
+                                                 row_tile=1024))(
+            *[jnp.asarray(a) for a in (bins, grad, hess, inbag, leaf)])
+    calls = [e for e in jaxpr.eqns
+             if e.params.get("name") == "_hist_pallas_raw"]
+    assert len(calls) == 1
+    shapes = [(v.aval.shape, v.aval.dtype) for v in calls[0].invars]
+    assert [s for s, dt in shapes if dt == jnp.int16] == [(28, 2, 8, 128)]
+    # the shadow, the base, the slots, the chunk, a count a row tile
+    assert sorted(s for s, _ in shapes) == sorted(
+        [hp._shadow_shape(N, 28, 1024), (8, N), (1, N), (1,), (2,)])
 
 
 SPLIT_CASES = {

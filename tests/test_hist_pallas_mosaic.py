@@ -51,9 +51,10 @@ SHAPES = {
 
 @pytest.mark.parametrize("case", sorted(SHAPES))
 def test_mosaic_takes_the_kernel(one_chip, case):
-    """With the feature-major shadow beside the bins wherever tiles pack:
-    at 128 features a chunk the packed branch's stacks and the shadow's two
-    buffers lie beside an 8.4 MB accumulator under the 16 MB scope."""
+    """The bins as one operand, the feature-major shadow, at every bin
+    count: at 128 features a chunk the packed branch's stacks, the dense
+    pieces' lane-major one-hots and the shadow's two buffers lie beside an
+    8.4 MB accumulator under the 16 MB scope."""
     n, f, tile, ncl, dtype, num_bins = SHAPES[case]
 
     def s(shape, dt):
@@ -61,13 +62,10 @@ def test_mosaic_takes_the_kernel(one_chip, case):
 
     shadow = hp._shadow_shape(n, f, hp.ROW_TILE)
     compiled = jax.jit(
-        lambda *a: hp._hist_pallas_raw(*a, num_bins=num_bins,
-                                       row_tile=hp.ROW_TILE, tile=tile,
+        lambda *a: hp._hist_pallas_raw(*a, num_bins=num_bins, tile=tile,
                                        ncl=ncl)).lower(
-            s((n, f), jnp.int16),
-            s(shadow, jnp.int16) if num_bins <= 256 else None,
-            s((8, n), dtype), s((1, n), jnp.int32), s((1,), jnp.int32),
-            s((shadow[1],), jnp.int32)).compile()
+            s(shadow, jnp.int16), s((8, n), dtype), s((1, n), jnp.int32),
+            s((1,), jnp.int32), s((shadow[1],), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

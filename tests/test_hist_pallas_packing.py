@@ -20,9 +20,9 @@ PACKING_CASES = {
     "counts_that_are_multiples_of_the_sub_block": [256, 128, 128],
     "counts_one_over_a_multiple": [257, 129, 1],
     "a_count_that_needs_every_sub_block": [897, 1023, 5],
-    # at a 1024-row tile packing would stop paying past 7 sub-blocks at 28
-    # features and never at 128; a tile more than three quarters full, past
-    # 6 sub-blocks, is dense at either width (hist_pallas._tile_cost)
+    # at a 1024-row tile packing stops paying past 5 sub-blocks at 28
+    # features and past 6 at 128, where a tile more than three quarters
+    # full is dense at either width (hist_pallas._tile_cost)
     "counts_either_side_of_where_packing_stops_paying": [640, 641, 768, 769,
                                                          100],
     "a_ragged_last_tile_packed_to_its_end": [500, 309],
@@ -119,6 +119,25 @@ def test_a_shadow_on_other_tiles_is_built_anew():
 
 
 @pytest.mark.parametrize("precision", ["f32", "int8"])
+@pytest.mark.parametrize("f, num_bins", [(28, 255), (39, 255), (220, 255),
+                                         (28, 300)])
+def test_whole_dense_tiles_read_the_shadow(f, num_bins, precision):
+    """Every row of every tile in the pass, the last tile ragged: each tile
+    takes the dense product, a feature's bins out of the shadow's lane
+    groups, its one-hots with the bins on the sublanes; at 220 features the
+    second chunk holds 92 of its 128, and at 300 bins no tile could pack."""
+    n = 2 * 1024 + 900
+    rng = np.random.RandomState(f + num_bins)
+    bins = rng.randint(0, num_bins, size=(n, f)).astype(np.int16)
+    bins[:8] = num_bins - 1  # the largest bin is met
+    slot = rng.randint(0, 4, size=n).astype(np.int32)
+    counts = hp.pass_counts(jnp.asarray(slot >= 0), 1024)
+    np.testing.assert_array_equal(np.asarray(counts), [1024, 1024, 900])
+    assert np.asarray(hp._pass_cost(counts, (n, f), num_bins, 1024)[2]).all()
+    _check_against_oracle(bins, slot, 4, num_bins, precision, row_tile=1024)
+
+
+@pytest.mark.parametrize("precision", ["f32", "int8"])
 def test_over_256_bins_every_tile_takes_the_dense_product(precision):
     """bfloat16 holds the bins up to 256 and no further, so such a call
     packs nothing; sparse and empty tiles all the same."""
@@ -151,8 +170,8 @@ def _blocks_in_numpy(slot, f, num_bins, row_tile):
         blocks = -(-cnt // hp.SUB_BLOCK)
         dense = cnt > 0 and (
             num_bins > 256
-            or blocks * (8 * fb + 6 * groups) + 6 * fb + 2 * groups
-            >= 10 * (t // hp.SUB_BLOCK) * fb
+            or 10 * (blocks * (8 * fb + 6 * groups) + 6 * fb + 2 * groups)
+            >= 74 * (t // hp.SUB_BLOCK) * fb
             or 4 * blocks > 3 * (t // hp.SUB_BLOCK))
         total += t // hp.SUB_BLOCK if dense else blocks
         packed += 0 if dense else blocks
