@@ -109,7 +109,7 @@ class FastState(NamedTuple):
     small_slot: jnp.ndarray  # (L,) i32 — pass slot of each fresh SMALL child, -1 otherwise
     slot_left: jnp.ndarray  # (tile,) i32 — left-child leaf per pass slot (-1
     # inactive).  The parent's hist lives in the LEFT child's state slot
-    # (left keeps the parent's leaf id), so the pass can gather parents and
+    # (left keeps the parent's leaf id), so the pass can read parents and
     # do the sibling subtraction on COMPACT (tile,...) arrays instead of
     # the full (L,...) state (measured 57 ms/round of full-state
     # scatter+subtract at Epsilon shape — benchmarks/probe_r5_fixed.py)
@@ -227,6 +227,57 @@ def partition_rows(
         sel = live & (lid == leaf_r)
         leaf_id = jnp.where(sel & ~gl, right_of[leaf_r], leaf_id)
     return leaf_id
+
+
+def sibling_step(
+    hist: jnp.ndarray,  # (L, 3, F, B) f32: every leaf's histograms
+    fresh: jnp.ndarray,  # (tile, 3, F, B) f32: the pass's small children
+    slot_left: jnp.ndarray,  # (tile,) i32: left child (the parent's id), -1 idle
+    slot_right: jnp.ndarray,  # (tile,) i32: right child's id
+    slot_small_left: jnp.ndarray,  # (tile,) bool: the small child is the left
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Sibling recovery of one pass: each active slot's bigger child is its
+    parent's histograms less the fresh small child's, and both children are
+    written into ``hist``, the left over its parent (the left child keeps
+    the parent's id).  -> (hist, children): the (2 tile, 3, F, B) children,
+    the slots' left ones first, then their right ones.
+
+    Reads and writes ``hist`` a leaf at a time, in place: a dynamic slice per
+    parent, a dynamic update per child, the slots unrolled (the writes by a
+    loop unrolled whole, which is traced once).  An idle slot
+    writes back what its (clamped) leaf holds at that moment, so it changes
+    nothing, even where an active slot has just written that leaf.  No
+    gather: XLA:TPU lowers a gather of the parents from a large state as
+    slices of the whole operand (at (255, 3, 2000, 255), 1.56 GB, pieces of
+    384 features), a read and a write of all of it every pass (PERF.md
+    section 6, the sibling step)."""
+    L, tile = hist.shape[0], fresh.shape[0]
+    active = slot_left >= 0
+    sl = jnp.clip(slot_left, 0, L - 1)
+    sr = jnp.clip(slot_right, 0, L - 1)
+    small = slot_small_left[:, None, None, None]
+
+    def leaf(x, i):  # (1, ...) at i, which is in range
+        return jax.lax.dynamic_slice_in_dim(x, i, 1, allow_negative_indices=False)
+
+    lefts, rights = [], []
+    for r in range(tile):
+        one, sml = fresh[r:r + 1], small[r:r + 1]
+        big = leaf(hist, sl[r]) - one
+        lefts.append(jnp.where(sml, one, big))
+        rights.append(jnp.where(sml, big, one))
+    # the children whole before the first write: XLA would otherwise fuse a
+    # parent's read into the writes and keep a copy of the state to read from
+    children = jax.lax.optimization_barrier(jnp.concatenate(lefts + rights))
+
+    def write(r, hist):
+        for pos, child in ((sl[r], r), (sr[r], tile + r)):
+            new = jnp.where(active[r], leaf(children, child), leaf(hist, pos))
+            hist = jax.lax.dynamic_update_slice_in_dim(
+                hist, new, pos, 0, allow_negative_indices=False)
+        return hist
+
+    return jax.lax.fori_loop(0, tile, write, hist, unroll=True), children
 
 
 def pack_bitset(mask: jnp.ndarray) -> jnp.ndarray:
@@ -526,7 +577,7 @@ def _grow_fast_impl(
     with phase_scope("grow.root"):  # the state the loop carries
         state = FastState(
             leaf_id=jnp.zeros(mask_t.shape, jnp.int32),
-            hist=jnp.zeros((L, 3, f, num_bins), jnp.float32).at[0].set(hist0),
+            hist=jnp.pad(hist0[None], ((0, L - 1), (0, 0), (0, 0), (0, 0))),
             best=best0,
             leaf_sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
             leaf_sum_h=jnp.zeros((L,), jnp.float32).at[0].set(h0),
@@ -877,24 +928,9 @@ def _grow_fast_impl(
                 leaf_slot = jnp.where(exists & (lid == leaf_r), r, leaf_slot)
         fresh_hists, blocks = multi_hist(leaf_slot, leaf_tile)  # (tile, 3, F, B)
         with phase_scope("grow.sibling"):
-            idx = jnp.arange(L, dtype=jnp.int32)
-            # COMPACT sibling recovery (round 5): parent hists live in the left
-            # children's slots; gather the <= tile parents, subtract, and
-            # scatter both children once — O(tile) state traffic instead of the
-            # full-(L,...) scatter/subtract/where chain (measured 57 ms/round
-            # at Epsilon shape, round 5)
-            active = state.slot_left >= 0  # (tile,)
-            sl = jnp.clip(state.slot_left, 0, L - 1)
-            sr = jnp.clip(state.slot_right, 0, L - 1)
-            parent_hists = state.hist[sl]  # (tile, 3, F, B)
-            big_hists = parent_hists - fresh_hists
-            sml = state.slot_small_left[:, None, None, None]
-            left_hists = jnp.where(sml, fresh_hists, big_hists)
-            right_hists = jnp.where(sml, big_hists, fresh_hists)
-            lpos = jnp.where(active, sl, 2 * L)
-            rpos = jnp.where(active, sr, 2 * L)
-            hist = state.hist.at[lpos].set(left_hists, mode="drop").at[rpos].set(
-                right_hists, mode="drop")
+            hist, cand_hists = sibling_step(
+                state.hist, fresh_hists, state.slot_left, state.slot_right,
+                state.slot_small_left)
 
         # ---------- phase 3: evaluate fresh leaves (one vmapped search) ----------
         with phase_scope("grow.split_search"):
@@ -922,7 +958,7 @@ def _grow_fast_impl(
                     lazy_pen=cegb_lazy_penalty if use_lazy else None,
                     lazy_counts=state.lazy_counts if use_lazy else None,
                 )
-                live = idx < state.num_leaves_cur
+                live = jnp.arange(L, dtype=jnp.int32) < state.num_leaves_cur
                 best = bb._replace(gain=jnp.where(live, bb.gain, KMIN_SCORE))
                 return state._replace(
                     hist=hist, best=best,
@@ -934,12 +970,14 @@ def _grow_fast_impl(
                     slot_right=jnp.full((leaf_tile,), -1, jnp.int32),
                     slot_small_left=jnp.zeros((leaf_tile,), bool))
             # only the fresh children need evaluation, and their hists are
-            # ALREADY compact (left_hists/right_hists above): feed the search
+            # ALREADY compact (the sibling step's children): feed the search
             # directly instead of re-gathering (2*tile, 3, F, B) from the state
             # (that gather measured 18 ms/round at Epsilon shape)
+            active = state.slot_left >= 0  # (tile,)
+            sl = jnp.clip(state.slot_left, 0, L - 1)
+            sr = jnp.clip(state.slot_right, 0, L - 1)
             cand = jnp.concatenate([sl, sr])  # (2*tile,) candidate leaf ids
             cand_ok = jnp.concatenate([active, active])
-            cand_hists = jnp.concatenate([left_hists, right_hists], axis=0)
             ci = jnp.where(cand_ok, cand, 0)
             bb = _batched_best(
                 cand_hists, state.leaf_sum_g[ci], state.leaf_sum_h[ci],

@@ -52,7 +52,8 @@ DEVICE_PHASES = (
     "hist.rowpad",  # the einsum route's pads to its row tile
     "hist.kernel",  # the Pallas kernel (the one-hot einsum at <= 64 bins)
     "hist.unpack",  # slice, hi + lo, transpose; unbundle and psum
-    "grow.sibling",  # parent gather, subtraction, scatter into state.hist
+    "grow.sibling",  # per-slot parent slices, subtraction, in-place
+    # updates of state.hist a leaf at a time
     "grow.split_search",  # _batched_best and the merge into state.best
     "grow.cat_search",  # inside it: the categorical candidates of the
     # categorical columns (ops/split.py); an operation is booked under the

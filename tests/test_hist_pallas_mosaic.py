@@ -160,3 +160,38 @@ def test_rank_step_keeps_every_array_under_a_gigabyte_at_istella_s_shape(
     text = compiled.as_text()
     assert re.search(r"f32\[\d+,30,2048\]", text)
     assert not re.search(r"\[\d+,2048,2048\]", text)
+
+
+def test_rounds_grower_keeps_epsilon_s_state_in_place(one_chip):
+    """The rounds grower at Epsilon's per-leaf state, (255, 3, 2000, 255)
+    float32 (1.56 GB), ten slots a pass, compiled for the chip with few
+    rows (the state does not depend on them): the sibling step reads and
+    writes the state a leaf at a time, so XLA neither copies the whole state
+    nor slices it for a gather ("mini-gather", a read and a write of all of
+    it every pass, 139 ms of a tree on a v5e: PERF.md section 6), and the
+    program's temporaries stay under two states (4.57 GB with the gather,
+    2.24 GB without)."""
+    import re
+
+    from lightgbm_tpu.ops.split import SplitParams
+    from lightgbm_tpu.ops.treegrow_fast import _grow_fast_impl
+
+    n, f, leaves, bins = 16_384, 2000, 255, 255
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = _grow_fast_impl.lower(
+        s((n, f), jnp.int16), s((n,), jnp.float32), s((n,), jnp.float32),
+        s((n,), jnp.bool_), s((n,), jnp.float32), s((f,), jnp.bool_),
+        s((f,), jnp.int32), s((f,), jnp.int32),
+        bins_t=s(hp._shadow_shape(n, f, hp.ROW_TILE), jnp.int16),
+        num_leaves=leaves, num_bins=bins, params=SplitParams(),
+        leaf_tile=10, hist_precision="f32", use_pallas=True).compile()
+    text = compiled.as_text()
+    state = rf"f32\[{leaves},3,{f},{bins}\]"
+    assert re.search(state, text)
+    assert not re.search(state + r"\{[^}]*\} copy\(", text)
+    assert "mini-gather" not in text
+    state_bytes = leaves * 3 * f * bins * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * state_bytes
